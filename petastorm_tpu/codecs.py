@@ -313,10 +313,13 @@ class CompressedImageCodec(DataframeColumnCodec):
             return out
         blobs = [bytes(values[i]) for i in idx]
         planes = None
+        from petastorm_tpu.trace import profiler_span
+
         try:
             from petastorm_tpu.ops.jpeg import entropy_decode_jpeg_batch
 
-            planes = entropy_decode_jpeg_batch(blobs)
+            with profiler_span("ptpu.pool.stage1", rows=len(blobs)):
+                planes = entropy_decode_jpeg_batch(blobs)
         except (ValueError, RuntimeError):
             planes = None
         if planes is None:
@@ -349,7 +352,8 @@ class CompressedImageCodec(DataframeColumnCodec):
             raise NotImplementedError("on-device decode is only available for jpeg")
         import jax.numpy as jnp
 
-        from petastorm_tpu.ops.jpeg import JpegPlanes, decode_jpeg_batch
+        from petastorm_tpu.ops.jpeg import (JpegPlanes, decode_jpeg_batch,
+                                            to_stored_order)
 
         staged = list(staged)
         plane_idx = [i for i, s in enumerate(staged) if isinstance(s, JpegPlanes)]
@@ -362,7 +366,7 @@ class CompressedImageCodec(DataframeColumnCodec):
         if plane_idx:
             img = decode_jpeg_batch([staged[i] for i in plane_idx],
                                     resize_to=resize_to, sharding=sharding)
-            img = img[..., 0] if grayscale else img[..., ::-1]
+            img = to_stored_order(img, grayscale)
             parts.append(img)
             order.extend(plane_idx)
         if host_idx:

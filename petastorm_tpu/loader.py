@@ -37,6 +37,7 @@ import numpy as np
 from petastorm_tpu.io.lease import (LeasedBatch, attach_leases, count_copy,
                                     take_leases)
 from petastorm_tpu.shuffle import BatchedRandomShufflingBuffer
+from petastorm_tpu.trace import phase, profiler_span, set_phase_sink
 from petastorm_tpu.utils import stack_as_column
 
 logger = logging.getLogger(__name__)
@@ -46,6 +47,13 @@ _SENTINEL = object()
 #: per-process pipeline ids for health-scope namespacing: loaders SHARING one
 #: HealthMonitor must not share heartbeat slots (itertools.count is GIL-atomic)
 _pipeline_seq = itertools.count()
+
+
+#: the phases of decode dispatch and H2D (``trace.phase`` names) -> their
+#: ``PipelineStats`` totals
+_PHASES = {"decode.pack": "decode_pack_s", "decode.put": "decode_put_s",
+           "decode.launch": "decode_launch_s", "h2d.stage": "h2d_stage_s",
+           "h2d.put": "h2d_put_s", "h2d.wait": "h2d_wait_s"}
 
 
 class PipelineStats:
@@ -65,6 +73,13 @@ class PipelineStats:
     - ``device_queue_wait_s``: consumer time starved waiting on the device-batch queue
       (the end-user-visible starvation — nonzero means the pipeline cannot keep the
       accelerator fed)
+
+    The phases of those two (``trace.phase`` spans) split them: ``decode_pack_s``
+    (stage-2 input stacked and narrowed on the host), ``decode_put_s`` (its
+    ``device_put``), ``decode_launch_s`` (the stage-2 call, the flip, a reshard);
+    ``h2d_stage_s`` (the pinned staging copy), ``h2d_put_s`` (the columns'
+    ``device_put``s), ``h2d_wait_s`` (the wait for the transfers before host
+    buffers go back to their rings). ``h2d_bytes`` counts what both puts shipped.
 
     ``decode_unsharded_batches`` counts staged-decode dispatches that ran on a
     SINGLE device although the configured sharding cuts the batch axis across
@@ -92,44 +107,26 @@ class PipelineStats:
                  "decode_s", "h2d_s",
                  "queue_wait_s", "device_queue_wait_s", "decode_unsharded_batches",
                  "shm_slabs_in_flight", "shm_bytes", "shm_fallbacks",
-                 "shm_acquire_wait_s")
+                 "shm_acquire_wait_s") + tuple(_PHASES.values()) + ("h2d_bytes",)
 
     def __init__(self):
         self.reset()
 
     def reset(self):
-        self.rows = 0
-        self.batches = 0
-        self.read_s = 0.0
-        self.batch_s = 0.0
-        self.put_wait_s = 0.0
-        self.decode_s = 0.0
-        self.h2d_s = 0.0
-        self.queue_wait_s = 0.0
-        self.device_queue_wait_s = 0.0
-        self.decode_unsharded_batches = 0
-        self.shm_slabs_in_flight = 0
-        self.shm_bytes = 0
-        self.shm_fallbacks = 0
-        self.shm_acquire_wait_s = 0.0
+        for field in self.__slots__:  # the ``*_s`` fields are seconds, the rest counts
+            setattr(self, field, 0.0 if field.endswith("_s") else 0)
+
+    def add_phase(self, name, seconds, nbytes=0):
+        """A ``trace.phase`` of a stage ended: add it to its total (phases
+        without one, e.g. the pool's, only count their bytes)."""
+        field = _PHASES.get(name)
+        if field is not None:
+            setattr(self, field, getattr(self, field) + seconds)
+        self.h2d_bytes += nbytes
 
     def snapshot(self):
-        return {
-            "rows": self.rows,
-            "batches": self.batches,
-            "read_s": round(self.read_s, 4),
-            "batch_s": round(self.batch_s, 4),
-            "put_wait_s": round(self.put_wait_s, 4),
-            "decode_s": round(self.decode_s, 4),
-            "h2d_s": round(self.h2d_s, 4),
-            "queue_wait_s": round(self.queue_wait_s, 4),
-            "device_queue_wait_s": round(self.device_queue_wait_s, 4),
-            "decode_unsharded_batches": self.decode_unsharded_batches,
-            "shm_slabs_in_flight": self.shm_slabs_in_flight,
-            "shm_bytes": self.shm_bytes,
-            "shm_fallbacks": self.shm_fallbacks,
-            "shm_acquire_wait_s": round(self.shm_acquire_wait_s, 4),
-        }
+        return {field: round(getattr(self, field), 4) if field.endswith("_s")
+                else getattr(self, field) for field in self.__slots__}
 
     def update_wire(self, wire_stats):
         """Fold the pool's shm gauges (``Reader.wire_stats()`` dict) in."""
@@ -141,10 +138,70 @@ class PipelineStats:
         self.shm_acquire_wait_s = wire_stats.get("shm_acquire_wait_s", 0.0)
 
 
-#: per-span stage keys for the loader's latency histograms (the trace span
-#: names map 1:1: reader.next -> read, batch.form -> batch, ...)
-_OBS_STAGES = ("read", "batch", "host_queue_put", "host_queue_wait", "decode",
-               "device_inflate", "h2d", "device_queue_wait")
+#: the loader's stages: name -> (PipelineStats field, latency-histogram stage,
+#: provenance site, tenant resource). One vocabulary for the ``trace=`` dump,
+#: the histograms, provenance, chaos sites and the profiler (``ptpu.<name>``)
+_STAGES = {
+    "reader.next": ("read_s", "read", None, None),
+    "batch.form": ("batch_s", "batch", None, None),
+    "wait.host_queue_put": ("put_wait_s", "host_queue_put", "loader.host_queue_put",
+                            None),
+    "wait.host_queue": ("queue_wait_s", "host_queue_wait", None, None),
+    "decode.dispatch": ("decode_s", "decode", "loader.decode", "decode_s"),
+    "decode.device_inflate": (None, "device_inflate", "decode.device_inflate", None),
+    "h2d.transfer": ("h2d_s", "h2d", "loader.h2d", None),
+    "wait.device_queue": ("device_queue_wait_s", "device_queue_wait", None, None),
+}
+_OBS_STAGES = tuple(v[1] for v in _STAGES.values())
+
+
+class _Stage:
+    """One occurrence of a loader stage, timed by one ``perf_counter`` pair
+    (``t0``, ``dt``) that reaches every sink the loader has on: its
+    ``PipelineStats`` field, the ``trace=`` recorder, the stage histogram, the
+    flight ring, provenance (on ``bp`` when given, else on the batch in
+    transfer), the tenant ledger, and ``ptpu.<name>`` on the JAX profiler's
+    clock with ``args`` (``batch=<host-batch number>``) as its metadata. While
+    open, the ``trace.phase`` spans on its thread add to the stats' phases."""
+
+    __slots__ = ("loader", "name", "bp", "span", "t0", "dt")
+
+    def __init__(self, loader, name, bp=None, **args):
+        self.loader, self.name, self.bp = loader, name, bp
+        self.span = profiler_span("ptpu." + name, **args)
+
+    def __enter__(self):
+        self.span.__enter__()
+        set_phase_sink(self.loader.stats)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.dt = dt = time.perf_counter() - self.t0
+        set_phase_sink(None)
+        self.span.__exit__(*exc)
+        loader, name = self.loader, self.name
+        field, stage, site, resource = _STAGES[name]
+        if field is not None:
+            setattr(loader.stats, field, getattr(loader.stats, field) + dt)
+        if loader._trace is not None:
+            loader._trace.add(name, self.t0, dt)
+        if loader._obs is not None:
+            loader._obs.observe(stage, dt)
+        scope = loader._health_scope
+        if scope is not None:
+            scope.flight.record("span", name=name, dur_s=round(dt, 6))
+        rec = loader._prov_rec
+        if rec is not None and site is not None:
+            if self.bp is not None:
+                rec.batch_span(self.bp, site, self.t0, dt)
+            else:
+                rec.transfer_span(site, self.t0, dt)
+        if resource is not None and loader.tenant_context is not None:
+            from petastorm_tpu.obs import tenant as _tenant_mod
+
+            _tenant_mod.charge(resource, dt, label=loader.tenant_context.tenant)
+        return False
 
 
 class _LoaderObs:
@@ -826,6 +883,9 @@ class DataLoader:
         #: an old iterator cannot kill the pipeline a newer __iter__ armed
         self._generation = 0
         self.stats = PipelineStats()
+        #: host batches the producer has put this iteration, and the number of
+        #: the one in transfer: the ``batch=`` of every stage's profiler span
+        self._batches_put = self._batch_seq = 0
         self._warned_unsharded_decode = False
         # consumer-watermark checkpointing (see state_dict): producer logs
         # (cumulative-delivered-rows, reader state) per delivery; the consumer
@@ -1044,9 +1104,9 @@ class DataLoader:
                                self._seed)
         stats = self.stats
         # health wiring (ISSUE 5): one heartbeat for this producer thread,
-        # stamped at the existing trace/obs sites (disabled = hb is None, one
-        # check per site); the flight ring gets per-delivery span edges.
-        # Registration goes through the SCOPE (namespaced on shared monitors).
+        # stamped at the stage sites (disabled = hb is None, one check per
+        # site); _Stage gives the flight ring its span edges. Registration
+        # goes through the SCOPE (namespaced on shared monitors).
         scope = self._health_scope
         hb = None
         flight = None
@@ -1080,16 +1140,8 @@ class DataLoader:
             while True:
                 if hb is not None:
                     hb.beat("read")
-                t0 = time.perf_counter()
-                item = next(it, _SENTINEL)
-                dt = time.perf_counter() - t0
-                stats.read_s += dt
-                if self._trace is not None:
-                    self._trace.add("reader.next", t0, dt)
-                if self._obs is not None:
-                    self._obs.observe("read", dt)
-                if flight is not None:
-                    flight.record("span", name="read", dur_s=round(dt, 6))
+                with _Stage(self, "reader.next", batch=self._batches_put):
+                    item = next(it, _SENTINEL)
                 if item is _SENTINEL:
                     # final snapshot: the all-delivered state must be reachable
                     # even when the throttle skipped the tail deliveries
@@ -1126,37 +1178,28 @@ class DataLoader:
                     stats.update_wire(wire_stats_fn())
                 if hb is not None:
                     hb.beat("batch")
-                t0 = time.perf_counter()
-                if self._pad_shapes:
-                    columns = _pad_ragged_columns(columns, self._pad_shapes)
-                if self._shuffling_queue_capacity:
-                    # the shuffling buffer permutes ROWS — compressed pages
-                    # cannot be row-permuted without decoding, so this seam
-                    # inflates on host (counted; pagedec=auto never pairs
-                    # with a host shuffle on purpose — the HBM ring shuffle
-                    # is the pass-through-compatible one)
-                    columns = _materialize_passthrough(
-                        columns, cause="pagedec_host_inflate")
-                    # rows linger in the shuffling buffer across row groups: staged
-                    # payloads that are views into a row group's stacked buffers must be
-                    # detached or one straggler row pins its whole group's memory
-                    for name in getattr(self.reader, "device_decode_fields", ()):
-                        col = columns.get(name)
-                        if col is not None and col.dtype == object:
-                            for i, v in enumerate(col):
-                                if hasattr(v, "detach"):
-                                    col[i] = v.detach()
-                ready = batcher.add(columns, lease)
-                dt = time.perf_counter() - t0
-                stats.batch_s += dt
-                collate_span = (t0, dt)
-                if self._trace is not None:
-                    self._trace.add("batch.form", t0, dt)
-                if self._obs is not None:
-                    self._obs.observe("batch", dt)
-                if flight is not None:
-                    flight.record("span", name="batch", dur_s=round(dt, 6),
-                                  ready=len(ready))
+                with _Stage(self, "batch.form", batch=self._batches_put) as st:
+                    if self._pad_shapes:
+                        columns = _pad_ragged_columns(columns, self._pad_shapes)
+                    if self._shuffling_queue_capacity:
+                        # the shuffling buffer permutes ROWS — compressed pages
+                        # cannot be row-permuted without decoding, so this seam
+                        # inflates on host (counted; pagedec=auto never pairs
+                        # with a host shuffle on purpose — the HBM ring shuffle
+                        # is the pass-through-compatible one)
+                        columns = _materialize_passthrough(
+                            columns, cause="pagedec_host_inflate")
+                        # rows linger in the shuffling buffer across row groups: staged
+                        # payloads that are views into a row group's stacked buffers must be
+                        # detached or one straggler row pins its whole group's memory
+                        for name in getattr(self.reader, "device_decode_fields", ()):
+                            col = columns.get(name)
+                            if col is not None and col.dtype == object:
+                                for i, v in enumerate(col):
+                                    if hasattr(v, "detach"):
+                                        col[i] = v.detach()
+                    ready = batcher.add(columns, lease)
+                collate_span = (st.t0, st.dt)
                 if self._ckpt_enabled:
                     ckpt_cum += _batch_row_count(columns)
                     # Snapshot at delivery boundaries (batched reader items ≈ row
@@ -1208,16 +1251,9 @@ class DataLoader:
         (a full queue means the consumer is the slow one, not this thread)."""
         if hb is not None:
             hb.wait("host_queue_put")
-        t0 = time.perf_counter()
-        ok = _put_with_stop(q, batch, self._stop)
-        dt = time.perf_counter() - t0
-        self.stats.put_wait_s += dt
-        if self._trace is not None:
-            self._trace.add("wait.host_queue_put", t0, dt)
-        if self._obs is not None:
-            self._obs.observe("host_queue_put", dt)
-        if bp is not None:
-            self._prov_rec.batch_span(bp, "loader.host_queue_put", t0, dt)
+        with _Stage(self, "wait.host_queue_put", bp, batch=self._batches_put):
+            ok = _put_with_stop(q, batch, self._stop)
+        self._batches_put += ok
         if hb is not None:
             hb.beat("batch")
         return ok
@@ -1359,6 +1395,7 @@ class DataLoader:
                 raise pending
         self._producer_error = None
         self._producer_error_delivered = True
+        self._batches_put = 0
         self.stats.reset()
         if self._obs is not None:
             # percentiles re-anchor with the totals: bottleneck_report() must
@@ -1380,18 +1417,12 @@ class DataLoader:
 
     def _host_batches(self, q):
         stats = self.stats
-        while True:
+        for seq in itertools.count():  # host batches leave the queue in put order
             hb = self._hb_transfer  # live only while the transfer thread runs
             if hb is not None:
                 hb.wait("host_queue")  # starvation = upstream's problem
-            t0 = time.perf_counter()
-            item = q.get()
-            dt = time.perf_counter() - t0
-            stats.queue_wait_s += dt
-            if self._trace is not None:
-                self._trace.add("wait.host_queue", t0, dt)
-            if self._obs is not None:
-                self._obs.observe("host_queue_wait", dt)
+            with _Stage(self, "wait.host_queue", batch=seq):
+                item = q.get()
             if item is _SENTINEL:
                 if self._producer_error is not None:
                     self._producer_error_delivered = True
@@ -1399,6 +1430,7 @@ class DataLoader:
                 return
             stats.batches += 1
             stats.rows += len(next(iter(item.values()))) if item else 0
+            self._batch_seq = seq
             yield item
 
     def _decode_staged(self, batch):
@@ -1515,7 +1547,8 @@ class DataLoader:
                         # the two-stage split exists to avoid (VERDICT r2 #3).
                         out = jax.make_array_from_process_local_data(s, out)
                     else:
-                        out = jax.device_put(out, s)
+                        with phase("decode.launch"):  # the reshard
+                            out = jax.device_put(out, s)
             decoded[name] = out
         return batch, decoded
 
@@ -1556,46 +1589,38 @@ class DataLoader:
         from petastorm_tpu.ops import pagedec_kernels as pk
 
         counters = pagedec_counters()
-        rec = self._prov_rec
-        t0 = time.perf_counter()
-        if _chaos.ACTIVE is not None:
-            _chaos.ACTIVE.hit("decode.device_inflate")
-        # sharded delivery keeps the host path for now: the decoded array
-        # goes through the same sharded device_put as any other column
-        # (per-shard device inflate is the ROADMAP item-2 follow-up)
-        use_device = self.sharding is None and (
-            jax.default_backend() != "cpu"
-            or os.environ.get("PTPU_PAGEDEC_DEVICE", "") not in ("", "0"))
-        decoded = {}
-        for name in names:
-            col = batch.pop(name)
-            counters["pages"].inc(sum(
-                (p1 - p0) + (1 if c.dict_page is not None else 0)
-                for c, s, t in col.parts
-                for p0, p1, _base in (c.covering_pages(s, t),)))
-            shipped = col.shipped_nbytes
-            counters["bytes_compressed"].inc(shipped)
-            counters["bytes_saved"].inc(max(0, col.raw_nbytes - shipped))
-            arr = None
-            if use_device:
-                try:
-                    arr = pk.inflate_column(col)
-                except pk.DeviceInflateError:
-                    arr = None  # host twin below validates + raises if corrupt
-            if arr is None:
-                # CPU fallback / kernel bail: reference decode, normal H2D
-                counters["host_inflate_columns"].inc()
-                batch[name] = col.materialize()
-            else:
-                decoded[name] = arr
-        dt = time.perf_counter() - t0
-        counters["inflate_seconds"].observe(dt)
-        if self._trace is not None:
-            self._trace.add("decode.device_inflate", t0, dt)
-        if self._obs is not None:
-            self._obs.observe("device_inflate", dt)
-        if rec is not None:
-            rec.transfer_span("decode.device_inflate", t0, dt)
+        with _Stage(self, "decode.device_inflate", batch=self._batch_seq) as st:
+            if _chaos.ACTIVE is not None:
+                _chaos.ACTIVE.hit("decode.device_inflate")
+            # sharded delivery keeps the host path for now: the decoded array
+            # goes through the same sharded device_put as any other column
+            # (per-shard device inflate is the ROADMAP item-2 follow-up)
+            use_device = self.sharding is None and (
+                jax.default_backend() != "cpu"
+                or os.environ.get("PTPU_PAGEDEC_DEVICE", "") not in ("", "0"))
+            decoded = {}
+            for name in names:
+                col = batch.pop(name)
+                counters["pages"].inc(sum(
+                    (p1 - p0) + (1 if c.dict_page is not None else 0)
+                    for c, s, t in col.parts
+                    for p0, p1, _base in (c.covering_pages(s, t),)))
+                shipped = col.shipped_nbytes
+                counters["bytes_compressed"].inc(shipped)
+                counters["bytes_saved"].inc(max(0, col.raw_nbytes - shipped))
+                arr = None
+                if use_device:
+                    try:
+                        arr = pk.inflate_column(col)
+                    except pk.DeviceInflateError:
+                        arr = None  # host twin below validates + raises if corrupt
+                if arr is None:
+                    # CPU fallback / kernel bail: reference decode, normal H2D
+                    counters["host_inflate_columns"].inc()
+                    batch[name] = col.materialize()
+                else:
+                    decoded[name] = arr
+        counters["inflate_seconds"].observe(st.dt)
         return batch, decoded
 
     def _ensure_staging(self, device):
@@ -1647,103 +1672,83 @@ class DataLoader:
         import jax
 
         hb = self._hb_transfer
-        rec = self._prov_rec
-        if rec is not None:
+        seq = self._batch_seq
+        if self._prov_rec is not None:
             # host batches flow to this thread strictly FIFO: advance the
             # recorder's transfer pointer to this batch's provenance
-            rec.transfer_next()
+            self._prov_rec.transfer_next()
         batch, inflated = self._inflate_passthrough(batch)
         if hb is not None:
             hb.beat("decode")
-        t0 = time.perf_counter()
-        batch, staged = self._decode_staged(batch)
-        dt = time.perf_counter() - t0
-        self.stats.decode_s += dt
-        if self.tenant_context is not None:
-            from petastorm_tpu.obs import tenant as _tenant_mod
-
-            _tenant_mod.charge("decode_s", dt,
-                               label=self.tenant_context.tenant)
-        if self._trace is not None:
-            self._trace.add("decode.dispatch", t0, dt)
-        if self._obs is not None:
-            self._obs.observe("decode", dt)
-        if rec is not None:
-            rec.transfer_span("loader.decode", t0, dt)
+        with _Stage(self, "decode.dispatch", batch=seq):
+            batch, staged = self._decode_staged(batch)
         if hb is not None:
             hb.beat("h2d")
-        t0 = time.perf_counter()
-        leases = take_leases(batch)
-        device = {k: v for k, v in batch.items() if _is_device_dtype(v)}
-        host = {k: v for k, v in batch.items() if k not in device}
-        for name, arr in host.items():
-            if isinstance(arr, np.ndarray) and arr.dtype == object and len(arr) \
-                    and isinstance(arr[0], (np.ndarray, list, tuple)):
-                raise ValueError(
-                    "Field %r holds ragged tensors and cannot reach the device with a "
-                    "static shape. Declare DataLoader(pad_shapes={%r: (max_dims...)}) "
-                    "to zero-pad it (a %s__mask column marks the valid region)."
-                    % (name, name, name)
-                )
-        if host:
-            logger.debug("Fields kept host-side (non-device dtypes): %s", sorted(host))
-            if leases:
-                # host columns outlive this thread (they ride to the consumer
-                # past the lease release below) — copy them out of the slabs
-                host = _detach_slab_views(host)
-        staging_lease = None
-        pool = self._ensure_staging(device) if device else None
-        if pool is not None:
-            # one copy into a page-locked slab; device_put below DMAs straight
-            # from it (and the original — possibly leased — buffers are done)
-            device, staging_lease = pool.stage(device)
-        elif leases:
-            from petastorm_tpu.io.staging import device_put_aliases_host
+        with _Stage(self, "h2d.transfer", batch=seq):
+            leases = take_leases(batch)
+            device = {k: v for k, v in batch.items() if _is_device_dtype(v)}
+            host = {k: v for k, v in batch.items() if k not in device}
+            for name, arr in host.items():
+                if isinstance(arr, np.ndarray) and arr.dtype == object and len(arr) \
+                        and isinstance(arr[0], (np.ndarray, list, tuple)):
+                    raise ValueError(
+                        "Field %r holds ragged tensors and cannot reach the device "
+                        "with a static shape. Declare DataLoader(pad_shapes={%r: "
+                        "(max_dims...)}) to zero-pad it (a %s__mask column marks the "
+                        "valid region)." % (name, name, name)
+                    )
+            if host:
+                logger.debug("Fields kept host-side (non-device dtypes): %s", sorted(host))
+                if leases:
+                    # host columns outlive this thread (they ride to the consumer
+                    # past the lease release below) — copy them out of the slabs
+                    host = _detach_slab_views(host)
+            staging_lease = None
+            pool = self._ensure_staging(device) if device else None
+            if pool is not None:
+                # one copy into a page-locked slab; device_put below DMAs straight
+                # from it (and the original — possibly leased — buffers are done)
+                with phase("h2d.stage", batch=seq):
+                    device, staging_lease = pool.stage(device)
+            elif leases:
+                from petastorm_tpu.io.staging import device_put_aliases_host
 
-            if device_put_aliases_host():
-                # this backend's device_put ALIASES host numpy: transferring the
-                # leased slab views directly would hand the consumer arrays into
-                # memory the release below recycles — copy them owned first
-                copied = 0
-                for name, arr in list(device.items()):
-                    if isinstance(arr, np.ndarray) and not arr.flags.writeable:
-                        device[name] = arr.copy()
-                        copied += arr.nbytes
-                count_copy("h2d_owned_copy", copied)
-        if self.sharding is None:
-            arrays = jax.device_put(device)
-        else:
-            import jax.sharding as jsh
-
-            arrays = {}
-            for name, arr in device.items():
-                s = self.sharding.get(name) if isinstance(self.sharding, dict) \
-                    else _matching_sharding(self.sharding, arr)
-                if s is None:  # field without an explicit sharding (e.g. __valid__)
-                    arrays[name] = jax.device_put(arr)
-                    continue
-                if jax.process_count() > 1:
-                    arrays[name] = jax.make_array_from_process_local_data(s, arr)
+                if device_put_aliases_host():
+                    # this backend's device_put ALIASES host numpy: transferring the
+                    # leased slab views directly would hand the consumer arrays into
+                    # memory the release below recycles — copy them owned first
+                    copied = 0
+                    for name, arr in list(device.items()):
+                        if isinstance(arr, np.ndarray) and not arr.flags.writeable:
+                            device[name] = arr.copy()
+                            copied += arr.nbytes
+                    count_copy("h2d_owned_copy", copied)
+            nbytes = sum(getattr(v, "nbytes", 0) for v in device.values())
+            with phase("h2d.put", batch=seq, bytes=nbytes):
+                if self.sharding is None:
+                    arrays = jax.device_put(device)
                 else:
-                    arrays[name] = jax.device_put(arr, s)
-        arrays.update(staged)
-        arrays.update(inflated)
-        if staging_lease is not None or leases:
-            # the H2D copy may still be reading the source buffers (device_put
-            # is async): wait for it before the slabs go back to their rings
-            jax.block_until_ready(arrays)
-            if staging_lease is not None:
-                staging_lease.release()
-            for lease in leases:
-                lease.release()
-        dt = time.perf_counter() - t0
-        self.stats.h2d_s += dt
-        if self._trace is not None:
-            self._trace.add("h2d.transfer", t0, dt)
-        if self._obs is not None:
-            self._obs.observe("h2d", dt)
-        if rec is not None:
-            rec.transfer_span("loader.h2d", t0, dt)
+                    arrays = {}
+                    for name, arr in device.items():
+                        s = self.sharding.get(name) if isinstance(self.sharding, dict) \
+                            else _matching_sharding(self.sharding, arr)
+                        if s is None:  # a field without a sharding (e.g. __valid__)
+                            arrays[name] = jax.device_put(arr)
+                        elif jax.process_count() > 1:
+                            arrays[name] = jax.make_array_from_process_local_data(s, arr)
+                        else:
+                            arrays[name] = jax.device_put(arr, s)
+            arrays.update(staged)
+            arrays.update(inflated)
+            if staging_lease is not None or leases:
+                # the H2D copy may still be reading the source buffers (device_put
+                # is async): wait for it before the slabs go back to their rings
+                with phase("h2d.wait", batch=seq):
+                    jax.block_until_ready(arrays)
+                if staging_lease is not None:
+                    staging_lease.release()
+                for lease in leases:
+                    lease.release()
         return arrays, host
 
     def _apply_device_transform(self, arrays):
@@ -1902,31 +1907,24 @@ class DataLoader:
         self._transfer_thread = threading.Thread(
             target=_transfer, name="ptpu-transfer", daemon=True)
         self._transfer_thread.start()
-        stats = self.stats
         finished = False
         try:
-            while True:
-                t0 = time.perf_counter()
+            for seq in itertools.count():  # device batches arrive in host-batch order
                 # bounded wait (GL-R001): the transfer thread's finally puts a
                 # sentinel on every exit path, but a thread that died without
                 # one (killed hard mid-put, interpreter teardown race) used to
                 # hang this consumer forever — re-check liveness each second
                 # and surface the stored error / end the epoch instead
-                while True:
-                    try:
-                        item = dev_q.get(timeout=1.0)
-                        break
-                    except queue.Empty:
-                        t_thread = self._transfer_thread
-                        if t_thread is None or not t_thread.is_alive():
-                            item = _SENTINEL
+                with _Stage(self, "wait.device_queue", batch=seq):
+                    while True:
+                        try:
+                            item = dev_q.get(timeout=1.0)
                             break
-                dt = time.perf_counter() - t0
-                stats.device_queue_wait_s += dt
-                if self._trace is not None:
-                    self._trace.add("wait.device_queue", t0, dt)
-                if self._obs is not None:
-                    self._obs.observe("device_queue_wait", dt)
+                        except queue.Empty:
+                            t_thread = self._transfer_thread
+                            if t_thread is None or not t_thread.is_alive():
+                                item = _SENTINEL
+                                break
                 if item is _SENTINEL:
                     finished = True
                     if transfer_error:

@@ -574,7 +574,9 @@ def _batched_stage2(layout, ks=None, packed=None, split=None, spmd=None):
         pair = pair - ((pair & 0x8) << 1)  # sign-extend 4-bit
         return pair.reshape(u8.shape[0], u8.shape[1], -1)
 
-    def fn(coeffs, qtabs):
+    # the program's name in a profile (``jit_ptpu_jpeg_stage2``), and its
+    # phases as named scopes: unpack, dequant_idct, upsample, color
+    def ptpu_jpeg_stage2(coeffs, qtabs):
         n = (coeffs[0][0] if isinstance(coeffs[0], tuple) else coeffs[0]).shape[0]
         planes = []
         for ci, ((h_samp, v_samp, by, bx), coef, qtab) in enumerate(
@@ -584,48 +586,55 @@ def _batched_stage2(layout, ks=None, packed=None, split=None, spmd=None):
             # pack of either, or the split-pack slab tuple; qtab: (n, 64) int32
             # (per-image: quality may vary)
             k_ship = ks[ci] if ks is not None else 64
-            if split is not None and split[ci] is not None:
-                head, mid, tail = coef
-                parts = []
-                if head.shape[-1]:
-                    parts.append(unpack12(head))
-                if mid.shape[-1]:
-                    parts.append(mid.astype(jnp.int32))
-                if tail.shape[-1]:
-                    parts.append(unpack4(tail))
-                coef = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
-                if k_ship < 64:
-                    coef = jnp.pad(coef, ((0, 0), (0, 0), (0, 64 - k_ship)))
-                coef = jnp.take(coef, unzig, axis=-1)
-            else:
-                if packed is not None and packed[ci]:
-                    coef = unpack12(coef)
-                if ks is not None and ks[ci] < 64:
-                    coef = jnp.pad(coef, ((0, 0), (0, 0), (0, 64 - ks[ci])))
+            with jax.named_scope("unpack"):
+                if split is not None and split[ci] is not None:
+                    head, mid, tail = coef
+                    parts = []
+                    if head.shape[-1]:
+                        parts.append(unpack12(head))
+                    if mid.shape[-1]:
+                        parts.append(mid.astype(jnp.int32))
+                    if tail.shape[-1]:
+                        parts.append(unpack4(tail))
+                    coef = parts[0] if len(parts) == 1 \
+                        else jnp.concatenate(parts, axis=-1)
+                    if k_ship < 64:
+                        coef = jnp.pad(coef, ((0, 0), (0, 0), (0, 64 - k_ship)))
                     coef = jnp.take(coef, unzig, axis=-1)
-            scaled = coef.astype(jnp.float32) * qtab.astype(jnp.float32)[:, None, :]
-            pix = _idct_scaled(scaled.reshape(n * by * bx, 64))
-            pix = jnp.clip(jnp.round(pix), 0.0, 255.0)  # libjpeg range-limits at IDCT out
-            plane = pix.reshape(n, by, bx, 8, 8)
-            plane = jnp.transpose(plane, (0, 1, 3, 2, 4)).reshape(n, by * 8, bx * 8)
-            ry, rx = vmax // v_samp, hmax // h_samp
-            for axis, r in ((1, ry), (2, rx)):
-                if r == 2:
-                    plane = _fancy_upsample2(plane, axis)
-                elif r > 1:
-                    plane = jnp.repeat(plane, r, axis=axis)
-            planes.append(plane[:, :height, :width])
-        if len(planes) == 1:
-            y = jnp.clip(planes[0], 0, 255).astype(jnp.uint8)
-            return jnp.stack([y, y, y], axis=-1)
-        rgb = ycbcr_to_rgb(planes[0], planes[1], planes[2])
-        return jnp.clip(jnp.round(rgb), 0, 255).astype(jnp.uint8)
+                else:
+                    if packed is not None and packed[ci]:
+                        coef = unpack12(coef)
+                    if ks is not None and ks[ci] < 64:
+                        coef = jnp.pad(coef, ((0, 0), (0, 0), (0, 64 - ks[ci])))
+                        coef = jnp.take(coef, unzig, axis=-1)
+            with jax.named_scope("dequant_idct"):
+                scaled = coef.astype(jnp.float32) * qtab.astype(jnp.float32)[:, None, :]
+                pix = _idct_scaled(scaled.reshape(n * by * bx, 64))
+                # libjpeg range-limits at the IDCT's output
+                pix = jnp.clip(jnp.round(pix), 0.0, 255.0)
+            with jax.named_scope("upsample"):
+                plane = pix.reshape(n, by, bx, 8, 8)
+                plane = jnp.transpose(plane, (0, 1, 3, 2, 4)).reshape(n, by * 8, bx * 8)
+                ry, rx = vmax // v_samp, hmax // h_samp
+                for axis, r in ((1, ry), (2, rx)):
+                    if r == 2:
+                        plane = _fancy_upsample2(plane, axis)
+                    elif r > 1:
+                        plane = jnp.repeat(plane, r, axis=axis)
+                planes.append(plane[:, :height, :width])
+        with jax.named_scope("color"):
+            if len(planes) == 1:
+                y = jnp.clip(planes[0], 0, 255).astype(jnp.uint8)
+                return jnp.stack([y, y, y], axis=-1)
+            rgb = ycbcr_to_rgb(planes[0], planes[1], planes[2])
+            return jnp.clip(jnp.round(rgb), 0, 255).astype(jnp.uint8)
 
     # NOTE on donation (VERDICT r3 #4 asked to try it): the slab buffers cannot
     # alias into the (n, h, w, 3) uint8 output — XLA input-output aliasing needs
     # size-compatible pairs — so donate_argnums only produces "donated buffers were
     # not usable" warnings on TPU (measured; no perf or memory change). The real
     # dispatch win is the explicit async device_put in ``_stage_inputs``.
+    fn = ptpu_jpeg_stage2
     if spmd is not None:
         batch = jax.sharding.PartitionSpec(spmd.spec[0])
         # check_vma off: the Pallas IDCT's output declares no varying axes,
@@ -700,6 +709,25 @@ def resize_image_batch(img, target):
         img.astype(jnp.float32), (img.shape[0], h, w, img.shape[3]),
         method="linear", antialias=False)
     return jnp.clip(jnp.round(out), 0, 255).astype(jnp.uint8)
+
+
+@functools.lru_cache(maxsize=None)
+def _to_bgr_program():
+    import jax
+
+    def ptpu_jpeg_to_bgr(img, grayscale):  # its name in a profile
+        return img[..., 0] if grayscale else img[..., ::-1]
+
+    return jax.jit(ptpu_jpeg_to_bgr, static_argnums=1)
+
+
+def to_stored_order(img, grayscale):
+    """Stage-2's RGB ``(n, h, w, 3)`` batch → cv2's stored order: BGR, or the one
+    plane of a grayscale field. A ``ptpu.decode.launch`` of its own."""
+    from petastorm_tpu.trace import phase
+
+    with phase("decode.launch"):
+        return _to_bgr_program()(img, grayscale)
 
 
 def decode_jpeg_batch(planes_list, resize_to=None, sharding=None):
@@ -922,17 +950,40 @@ def _stage_inputs(tree, sharding, n):
 
 
 def _decode_group(layout, group, sharding=None):
-    """One same-layout group → device decode. Transfer narrowing, exact and
-    composable: (a) ship only the zigzag prefix when the batch's kmax says the rest
-    of the spectrum is zero; (b) split what ships into per-position bit widths from
-    the row group's measured spectral ranges (12-bit head / int8 mid / 4-bit tail);
-    (c) 12-bit-pack components the split can't help. Sharp photographic content
-    defeats (a) (kmax ≈ 63) but (b) still halves the 12-bit bytes — high zigzag
-    positions are heavily quantized; smooth content composes (a)+(b).
+    """One same-layout group → device decode, in three phases on the profiler's
+    clock: ``ptpu.decode.pack`` (stack and narrow on the host, :func:`_pack_group`),
+    ``ptpu.decode.put`` (the coefficient H2D, ``bytes=`` shipped) and
+    ``ptpu.decode.launch`` (the stage-2 call, ``new_variant=1`` when it built a
+    new program: the call then compiles or loads it).
 
     ``sharding``: optional batch-axis sharding; staged inputs are placed across its
     devices so dequant+IDCT+upsample+color runs SPMD (one shard of the batch per
     device) instead of on the default device only."""
+    from petastorm_tpu.trace import phase
+
+    with phase("decode.pack"):
+        variant, shipped, qtabs, shipped_bytes = _pack_group(layout, group)
+    with phase("decode.put", bytes=shipped_bytes):
+        (shipped, qtabs), spmd = _stage_inputs((shipped, qtabs), sharding, len(group))
+    with phase("decode.launch") as span:
+        misses = _batched_stage2.cache_info().misses
+        program = _batched_stage2(layout, *variant, spmd)
+        if span is not None and _batched_stage2.cache_info().misses != misses:
+            span.set_metadata(new_variant=1)
+        return program(shipped, qtabs)
+
+
+def _pack_group(layout, group):
+    """Stack a same-layout group and narrow what ships. Transfer narrowing, exact
+    and composable: (a) ship only the zigzag prefix when the batch's kmax says the
+    rest of the spectrum is zero; (b) split what ships into per-position bit widths
+    from the row group's measured spectral ranges (12-bit head / int8 mid / 4-bit
+    tail); (c) 12-bit-pack components the split can't help. Sharp photographic
+    content defeats (a) (kmax ≈ 63) but (b) still halves the 12-bit bytes — high
+    zigzag positions are heavily quantized; smooth content composes (a)+(b).
+
+    Returns ``(variant, shipped, qtabs, shipped_bytes)``: ``variant`` is the
+    ``(ks, packed, split)`` of the stage-2 program that reads ``shipped``."""
     coeffs, qtabs = stack_jpeg_coefficients(group)
     from petastorm_tpu.ops import native
 
@@ -942,9 +993,7 @@ def _decode_group(layout, group, sharding=None):
         with _STICKY_KS_LOCK:
             _TRANSFER_BYTES["raw"] += full
             _TRANSFER_BYTES["shipped"] += full
-        (coeffs, qtabs), spmd = _stage_inputs((coeffs, qtabs), sharding,
-                                              coeffs[0].shape[0])
-        return _batched_stage2(layout, spmd=spmd)(coeffs, qtabs)
+        return (None, None, None), coeffs, qtabs, full
     ks = _truncation_ks(group, layout)
     if ks is not None:
         coeffs = tuple(
@@ -1005,6 +1054,4 @@ def _decode_group(layout, group, sharding=None):
     with _STICKY_KS_LOCK:
         _TRANSFER_BYTES["raw"] += raw_bytes
         _TRANSFER_BYTES["shipped"] += shipped_bytes
-    (shipped, qtabs), spmd = _stage_inputs((tuple(shipped), qtabs), sharding, n)
-    return _batched_stage2(layout, ks, tuple(packed), tuple(split), spmd)(
-        shipped, qtabs)
+    return (ks, tuple(packed), tuple(split)), tuple(shipped), qtabs, shipped_bytes

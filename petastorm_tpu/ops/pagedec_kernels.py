@@ -149,11 +149,11 @@ def _snappy_pages_fn(n_pages, src_cap, out_cap):
     """The jitted batch inflate for one (padded) page-table shape."""
     import jax
 
-    def fn(comp, meta):
+    def ptpu_pagedec_snappy(comp, meta):  # its name in a profile
         return jax.vmap(lambda c, m: _snappy_page(c, m[0], m[1], out_cap))(
             comp, meta)
 
-    return jax.jit(fn)
+    return jax.jit(ptpu_pagedec_snappy)
 
 
 def snappy_inflate_pages(comp, meta, out_cap):
@@ -365,7 +365,8 @@ def _data_page_fn(out_cap, dtype_str, num_values, bit_width):
     RLE-dictionary page whose dictionary is row 0. Offsets and lengths are
     traced, so pages of one size class share the program.
 
-    ``fn(raw, row, off, data_len, dict_n)`` → ``(values (num_values,), ok)``:
+    ``ptpu_pagedec_data_page(raw, row, off, data_len, dict_n)`` →
+    ``(values (num_values,), ok)``:
     ``off`` is where the values section starts in the page (after the level
     block; for dictionary pages, after the bit-width byte too)."""
     import jax
@@ -375,7 +376,7 @@ def _data_page_fn(out_cap, dtype_str, num_values, bit_width):
     dtype = np.dtype(dtype_str)
     need = num_values * dtype.itemsize
 
-    def fn(raw, row, off, data_len, dict_n):
+    def ptpu_pagedec_data_page(raw, row, off, data_len, dict_n):
         body = jnp.pad(raw[row], (0, out_cap))  # a slice at off never clamps
         if bit_width is None:
             return bitcast_values(lax.dynamic_slice(body, (off,), (need,)),
@@ -389,7 +390,7 @@ def _data_page_fn(out_cap, dtype_str, num_values, bit_width):
         dict_vals = bitcast_values(raw[0], dtype)
         return dict_vals[jnp.clip(idx, 0, dict_vals.shape[0] - 1)], ok
 
-    return jax.jit(fn)
+    return jax.jit(ptpu_pagedec_data_page)
 
 
 def inflate_window(chunk, skip, take):

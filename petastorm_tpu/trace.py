@@ -29,14 +29,71 @@ shows driver threads AND worker processes on distinct pid lanes.
             with tracer.span("train.step"):
                 step(batch)
     tracer.dump("pipeline_trace.json")
+
+The same stages also land on the JAX profiler's own clock, as ``ptpu.<stage>``
+host events (:func:`profiler_span`), beside the device's ops in a
+``jax.profiler.trace`` capture; the parts of a stage are :class:`phase` spans.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
+
+
+def profiler_span(name, **args):
+    """``jax.profiler.TraceAnnotation(name, **args)``: a host event on the JAX
+    profiler's clock, with ``args`` as its metadata (more can follow through
+    the returned span's ``set_metadata``). About half a microsecond when no
+    trace is being captured. In a process that has not imported JAX there is
+    no profiler to write to: the span is then a null context whose ``as``
+    target is None."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation(name, **args)
+
+
+#: per thread, the totals the open loader stage's phases add to (``sink``)
+_stage_sink = threading.local()
+
+
+def set_phase_sink(sink):
+    """Route this thread's :class:`phase` timings to ``sink.add_phase(name,
+    seconds, nbytes)`` (a loader's ``PipelineStats``) until it is set again;
+    None stops the routing."""
+    _stage_sink.sink = sink
+
+
+class phase:
+    """A part of a loader stage: ``ptpu.<name>`` on the profiler's clock (its
+    ``args`` as metadata; ``as`` gives the span, for ``set_metadata``, or None
+    where JAX is not imported), and its seconds and ``bytes`` arg added to the
+    stage's totals when a loader stage is open on this thread. Code below the
+    loader (the codecs) marks its phases without knowing the loader."""
+
+    __slots__ = ("name", "nbytes", "span", "t0")
+
+    def __init__(self, name, **args):
+        self.name, self.nbytes = name, args.get("bytes", 0)
+        self.span = profiler_span("ptpu." + name, **args)
+
+    def __enter__(self):
+        span = self.span.__enter__()
+        self.t0 = time.perf_counter()
+        return span
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t0
+        self.span.__exit__(*exc)
+        sink = getattr(_stage_sink, "sink", None)
+        if sink is not None:
+            sink.add_phase(self.name, dt, self.nbytes)
+        return False
 
 
 class TraceRecorder:

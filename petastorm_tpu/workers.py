@@ -29,6 +29,7 @@ from petastorm_tpu import chaos as _chaos
 from petastorm_tpu.obs import provenance as _prov
 from petastorm_tpu.errors import TimeoutWaitingForResultError, WorkerDiedError
 from petastorm_tpu.recovery import QuarantinedItem, RecoveryOptions
+from petastorm_tpu.trace import profiler_span
 
 logger = logging.getLogger(__name__)
 
@@ -36,6 +37,17 @@ _DONE = object()
 
 
 _steal_counter = None
+
+
+def _result_rows(result):
+    """Rows in a worker's result: a columnar dict's column length, or the
+    length of a row list (0 for anything else), in the payload of a result
+    the reader tagged ``(epoch, ordinal, payload)``."""
+    if isinstance(result, tuple) and len(result) == 3:
+        result = result[2]
+    if isinstance(result, dict):
+        return len(next(iter(result.values()))) if result else 0
+    return len(result) if isinstance(result, list) else 0
 
 
 def _count_steal():
@@ -528,7 +540,10 @@ class ThreadExecutor(ExecutorBase):
                             if _chaos.ACTIVE is not None:
                                 _chaos.ACTIVE.hit("worker.item",
                                                   key=_chaos.item_key(item))
-                            result = worker(item)
+                            with profiler_span("ptpu.pool.item") as span:
+                                result = worker(item)
+                                if span is not None:
+                                    span.set_metadata(rows=_result_rows(result))
                         except Exception as e:  # noqa: BLE001 — classified
                             attempts += 1
                             if not recovery.quarantine:

@@ -319,6 +319,41 @@ def test_loader_exports_metric_families(scalar_dataset):
     assert 'ptpu_pipeline_stage_seconds{stage="read"}' in registry.snapshot()
 
 
+def test_every_stage_reaches_every_sink_once(scalar_dataset):
+    """One timing per stage occurrence feeds every sink that is on: the
+    ``trace=`` recorder, the stage histogram (``_STAGES``' histogram key), the
+    flight ring and ``PipelineStats`` count each occurrence once, under one
+    vocabulary of stage names."""
+    from petastorm_tpu.loader import _STAGES
+    from petastorm_tpu.obs.health import HealthOptions
+    from petastorm_tpu.trace import TraceRecorder
+
+    registry, tracer = MetricsRegistry(), TraceRecorder()
+    reader = make_batch_reader(scalar_dataset.url, num_epochs=1,
+                               shuffle_row_groups=False, workers_count=1)
+    with DataLoader(reader, 8, metrics=registry, trace=tracer,
+                    health=HealthOptions(stall_threshold_s=60)) as loader:
+        n = sum(1 for _ in loader)
+        snap = registry.snapshot()
+        flight = [e["name"] for e in loader._health.flight.events()
+                  if e["kind"] == "span"]
+        stats = {f: getattr(loader.stats, f) for f in type(loader.stats).__slots__}
+    recorded = [e["name"] for e in tracer.events()]
+    assert n == stats["batches"] > 0
+    seen = [name for name in _STAGES if name in recorded]
+    assert {"reader.next", "batch.form", "wait.host_queue_put", "wait.host_queue",
+            "decode.dispatch", "h2d.transfer", "wait.device_queue"} <= set(seen)
+    for name in seen:
+        field, hist_key = _STAGES[name][:2]
+        hist = snap['ptpu_pipeline_stage_seconds{stage="%s"}' % hist_key]
+        assert hist["count"] == recorded.count(name) == flight.count(name), name
+        if field is not None:  # the stats total is the sum of the same timings
+            assert stats[field] == pytest.approx(sum(
+                e["duration_s"] for e in tracer.events() if e["name"] == name)), name
+    # the stages of every batch: one decode dispatch and one transfer each
+    assert recorded.count("decode.dispatch") == recorded.count("h2d.transfer") == n
+
+
 def test_collectors_go_quiet_when_loader_is_garbage_collected(scalar_dataset):
     """A loader torn down WITHOUT the context manager (stop/join only) must not
     be pinned alive by the registry, and its collectors must stop exporting

@@ -789,9 +789,13 @@ def test_attribution_names_device_inflate_when_slow(tmp_path):
                                        latency_s=0.05)], seed=3),
                   propagate=False)
         try:
+            # readahead off: a background read that runs ahead of the worker
+            # charges the injected read latency to io.readahead, and whether
+            # it runs ahead depends on how busy the host is
             with make_batch_reader(url, reader_pool_type="thread",
                                    workers_count=1, shuffle_row_groups=False,
-                                   io_options={"pagedec": "on"},
+                                   io_options={"pagedec": "on",
+                                               "readahead": False},
                                    provenance=True) as r:
                 with DataLoader(r, 300, to_device=True) as loader:
                     for _ in loader:

@@ -4,6 +4,7 @@ import json
 import threading
 
 import numpy as np
+import pytest
 
 from petastorm_tpu.loader import DataLoader
 from petastorm_tpu.reader import make_batch_reader
@@ -176,3 +177,137 @@ def test_child_spans_discarded_without_a_recorder():
     with ProcessExecutor(workers_count=1, results_queue_size=4) as ex:
         ex.start(_negate, EpochPlan([1, 2, 3], num_epochs=1))
         assert sorted(ex.results()) == [-3, -2, -1]
+
+
+# -- the same stages on the JAX profiler's clock -----------------------------------------
+
+#: the loader's stages, each a ``ptpu.<name>`` host event carrying ``batch=``
+_LOADER_STAGES = ("reader.next", "batch.form", "wait.host_queue_put", "wait.host_queue",
+                  "decode.dispatch", "h2d.transfer", "wait.device_queue")
+#: phase spans and the stage each lies inside, on the same thread
+_PHASES = {"decode.pack": "decode.dispatch", "decode.put": "decode.dispatch",
+           "decode.launch": "decode.dispatch", "h2d.stage": "h2d.transfer",
+           "h2d.put": "h2d.transfer", "h2d.wait": "h2d.transfer"}
+
+
+def _profiled(tmp_path, run):
+    """Run ``run()`` under ``jax.profiler`` and return its result and the host's
+    ``ptpu.*`` events as ``(thread line, name, start_ns, end_ns, args)``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    log_dir = str(tmp_path / "profile")
+    jax.profiler.start_trace(log_dir)
+    try:
+        out = run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(log_dir + "/plugins/profile/*/*.xplane.pb")
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("ptpu."):
+                        events.append((line.name, ev.name[len("ptpu."):], ev.start_ns,
+                                       ev.end_ns, dict(ev.stats)))
+    return out, events
+
+
+def test_stages_on_the_profiler_clock_with_batch_numbers(tmp_path, monkeypatch):
+    """A device-decode loader traced by ``jax.profiler``: every stage is a
+    ``ptpu.<stage>`` host event with its host-batch number, the decode and H2D
+    phases nest inside their stage on its thread, the pool's spans are there,
+    and ``trace=``, ``PipelineStats`` and the histograms see the same stages."""
+    pytest.importorskip("cv2")
+    from petastorm_tpu.io import staging
+    from petastorm_tpu.obs.metrics import MetricsRegistry
+    from test_common import create_test_jpeg_dataset
+
+    # the CPU backend's device_put aliases host memory, so the pinned staging
+    # copy (and its h2d.stage and h2d.wait phases) is refused there; this test
+    # reads the spans, not the delivered bytes
+    monkeypatch.setattr(staging, "device_put_aliases_host", lambda: False)
+
+    ds = create_test_jpeg_dataset("file://" + str(tmp_path / "ds"), num_rows=24)
+    tracer, registry = TraceRecorder(), MetricsRegistry()
+
+    def run():
+        reader = make_batch_reader(ds.url, decode_on_device=True, num_epochs=1,
+                                   shuffle_row_groups=False, workers_count=1)
+        with DataLoader(reader, 8, trace=tracer, metrics=registry,
+                        staging=True) as loader:
+            n = sum(1 for _ in loader)
+            return n, loader.stats.snapshot(), registry.snapshot()
+
+    (n, stats, snap), events = _profiled(tmp_path, run)
+    assert n == 3
+    names = {e[1] for e in events}
+    assert set(_LOADER_STAGES) | set(_PHASES) | {"pool.item", "pool.stage1"} <= names
+    for line, name, _s, _e, args in events:
+        if name in _LOADER_STAGES:
+            assert isinstance(args.get("batch"), int), (name, args)
+    # one batch's producer, transfer and consumer spans share its number
+    for stage in ("wait.host_queue_put", "decode.dispatch", "h2d.transfer"):
+        assert sorted(a["batch"] for _l, nm, _s, _e, a in events if nm == stage) \
+            == [0, 1, 2], stage
+    assert {a["batch"] for _l, nm, _s, _e, a in events
+            if nm == "wait.device_queue"} >= {0, 1, 2}
+    for line, name, s, e, _a in events:
+        if name in _PHASES:
+            assert any(pl == line and pn == _PHASES[name] and ps <= s and e <= pe
+                       for pl, pn, ps, pe, _ in events), name
+    put_bytes = [a.get("bytes") for _l, nm, _s, _e, a in events if nm == "decode.put"]
+    assert put_bytes and all(b > 0 for b in put_bytes)
+    assert all(a.get("rows", 0) > 0 for _l, nm, _s, _e, a in events if nm == "pool.item")
+    # the other sinks got the same occurrences from the same clock pair
+    recorded = [e["name"] for e in tracer.events()]
+    for stage, field in (("decode.dispatch", "decode_s"), ("h2d.transfer", "h2d_s")):
+        spans = [(e - s) * 1e-9 for _l, nm, s, e, _a in events if nm == stage]
+        assert recorded.count(stage) == len(spans) == 3
+        assert sum(spans) == pytest.approx(stats[field], abs=0.01, rel=0.05)
+    # the phase totals are the phase spans' durations, and the bytes they shipped
+    from petastorm_tpu.loader import _PHASES as phase_fields
+
+    for name, field in phase_fields.items():
+        spans = [(e - s) * 1e-9 for _l, nm, s, e, _a in events if nm == name]
+        assert sum(spans) == pytest.approx(stats[field], abs=0.01, rel=0.05), name
+    assert stats["h2d_bytes"] == sum(a.get("bytes", 0) for _l, nm, _s, _e, a in events
+                                     if nm in ("decode.put", "h2d.put")) > 0
+    for stage, hist in (("reader.next", "read"), ("decode.dispatch", "decode"),
+                        ("h2d.transfer", "h2d"), ("wait.device_queue",
+                                                  "device_queue_wait")):
+        count = snap['ptpu_pipeline_stage_seconds{stage="%s"}' % hist]["count"]
+        assert count == recorded.count(stage) \
+            == sum(1 for e in events if e[1] == stage), stage
+
+
+def test_device_inflate_stage_on_the_profiler_clock(tmp_path):
+    """A pass-through store's inflate stage lands on the profiler's clock too,
+    tagged with the batch it inflates."""
+    import os
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    root = str(tmp_path / "pagedec")
+    os.makedirs(root)
+    n = 1200
+    pq.write_table(pa.table({"feat": np.repeat(np.arange(30, dtype=np.float32), 40),
+                             "cat": np.arange(n, dtype=np.int64) % 7}),
+                   os.path.join(root, "part-0.parquet"), compression="snappy",
+                   row_group_size=400)
+
+    def run():
+        with make_batch_reader("file://" + root, workers_count=1,
+                               shuffle_row_groups=False,
+                               io_options={"pagedec": "on"}) as reader:
+            with DataLoader(reader, 400) as loader:
+                return sum(1 for _ in loader)
+
+    n_batches, events = _profiled(tmp_path, run)
+    inflates = [a for _l, nm, _s, _e, a in events if nm == "decode.device_inflate"]
+    assert n_batches == 3
+    assert sorted(a["batch"] for a in inflates) == [0, 1, 2]
