@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
 import struct
 import threading
 
@@ -49,8 +50,9 @@ class JpegPlanes:
     components: list        # [Y, Cb, Cr] or [Y]
     #: set by the row-group batched stage 1: ``(coeffs_tuple, qtabs_array, row_index)``
     #: where each component's ``blocks`` is a zero-copy view into ``coeffs_tuple[c]``.
-    #: Lets :func:`stack_jpeg_coefficients` re-assemble batches by slicing/gathering the
-    #: parent buffers instead of np.stack over per-row objects.
+    #: Lets :func:`_pack_group` read a batch's rows straight from the parent buffers,
+    #: and :func:`stack_jpeg_coefficients` slice or gather them, instead of np.stack
+    #: over per-row objects.
     batch_ref: tuple | None = None
     #: per component, the max ZIGZAG index with any nonzero coefficient (from the
     #: native batch decode) — lets the device transfer ship only the zigzag prefix.
@@ -951,18 +953,19 @@ def _stage_inputs(tree, sharding, n):
 
 def _decode_group(layout, group, sharding=None):
     """One same-layout group → device decode, in three phases on the profiler's
-    clock: ``ptpu.decode.pack`` (stack and narrow on the host, :func:`_pack_group`),
-    ``ptpu.decode.put`` (the coefficient H2D, ``bytes=`` shipped) and
-    ``ptpu.decode.launch`` (the stage-2 call, ``new_variant=1`` when it built a
-    new program: the call then compiles or loads it).
+    clock: ``ptpu.decode.pack`` (narrow on the host, :func:`_pack_group`, with
+    ``threads=`` and ``runs=``), ``ptpu.decode.put`` (the coefficient H2D,
+    ``bytes=`` shipped) and ``ptpu.decode.launch`` (the stage-2 call,
+    ``new_variant=1`` when it built a new program: the call then compiles or
+    loads it).
 
     ``sharding``: optional batch-axis sharding; staged inputs are placed across its
     devices so dequant+IDCT+upsample+color runs SPMD (one shard of the batch per
     device) instead of on the default device only."""
     from petastorm_tpu.trace import phase
 
-    with phase("decode.pack"):
-        variant, shipped, qtabs, shipped_bytes = _pack_group(layout, group)
+    with phase("decode.pack") as span:
+        variant, shipped, qtabs, shipped_bytes = _pack_group(layout, group, span)
     with phase("decode.put", bytes=shipped_bytes):
         (shipped, qtabs), spmd = _stage_inputs((shipped, qtabs), sharding, len(group))
     with phase("decode.launch") as span:
@@ -973,8 +976,47 @@ def _decode_group(layout, group, sharding=None):
         return program(shipped, qtabs)
 
 
-def _pack_group(layout, group):
-    """Stack a same-layout group and narrow what ships. Transfer narrowing, exact
+#: Rows a pack thread takes at the least, and the most threads one pack fans out
+#: to: packing is memory-bound, and past a few threads (4-8 on an 8-core host)
+#: more only add start-up and contend with the pool's decode threads.
+_PACK_ROWS_PER_THREAD = 32
+_PACK_MAX_THREADS = 8
+
+
+def _pack_threads(n):
+    """Threads that pack an ``n``-row group: one per slice of at least
+    ``_PACK_ROWS_PER_THREAD`` rows, at most the host's cores and
+    ``_PACK_MAX_THREADS``; a small group packs on the calling thread."""
+    return max(1, min(n // _PACK_ROWS_PER_THREAD, os.cpu_count() or 1,
+                      _PACK_MAX_THREADS))
+
+
+def _source_runs(group):
+    """The group's rows as maximal runs of consecutive rows of one source buffer:
+    ``[(coeffs, qtabs, start, count), ...]`` in row order, ``coeffs`` one ``(m,
+    nblocks, 64)`` int16 array per component and ``qtabs`` ``(m, ncomp, 64)``.
+    Rows of a row-group batch decode read their parent's stacked buffers (a run
+    ends where the batch crosses to another row group or skips a row); a row
+    without a ``batch_ref`` (per-image fallback decode) is a run of its own."""
+    runs = []
+    for p in group:
+        ref = p.batch_ref
+        if ref is not None:
+            last = runs[-1] if runs else None
+            if last is not None and last[0] is ref[0] \
+                    and last[2] + last[3] == ref[2]:
+                runs[-1] = (last[0], last[1], last[2], last[3] + 1)
+            else:
+                runs.append((ref[0], ref[1], ref[2], 1))
+            continue
+        coeffs = tuple(np.ascontiguousarray(c.blocks, dtype=np.int16).reshape(1, -1, 64)
+                       for c in p.components)
+        runs.append((coeffs, np.stack([c.qtable for c in p.components])[None], 0, 1))
+    return runs
+
+
+def _pack_group(layout, group, span=None):
+    """Narrow what a same-layout group ships. Transfer narrowing, exact
     and composable: (a) ship only the zigzag prefix when the batch's kmax says the
     rest of the spectrum is zero; (b) split what ships into per-position bit widths
     from the row group's measured spectral ranges (12-bit head / int8 mid / 4-bit
@@ -982,71 +1024,70 @@ def _pack_group(layout, group):
     content defeats (a) (kmax ≈ 63) but (b) still halves the 12-bit bytes — high
     zigzag positions are heavily quantized; smooth content composes (a)+(b).
 
+    The native packer builds each component's shipped slabs in one pass straight
+    from the rows' own coefficients (their row groups' stacked buffers, or a lone
+    row's blocks: :func:`_source_runs`), over :func:`_pack_threads` threads; no
+    stacked or truncated copy is made. ``span`` (the ``ptpu.decode.pack`` span, or
+    None) gets ``threads=`` and ``runs=``.
+
     Returns ``(variant, shipped, qtabs, shipped_bytes)``: ``variant`` is the
     ``(ks, packed, split)`` of the stage-2 program that reads ``shipped``."""
-    coeffs, qtabs = stack_jpeg_coefficients(group)
     from petastorm_tpu.ops import native
 
     if not native.native_available():
+        coeffs, qtabs = stack_jpeg_coefficients(group)
         # un-narrowed transfer still counts: ratio must read ~1.0 here, not "no data"
         full = sum(c.nbytes for c in coeffs)
         with _STICKY_KS_LOCK:
             _TRANSFER_BYTES["raw"] += full
             _TRANSFER_BYTES["shipped"] += full
         return (None, None, None), coeffs, qtabs, full
+    runs = _source_runs(group)
+    ncomp = len(layout[2])
+    n = len(group)
+    threads = _pack_threads(n)
+    if span is not None:
+        span.set_metadata(threads=threads, runs=len(runs))
+    qt = np.concatenate([q[s:s + m] for _c, q, s, m in runs])
+    qtabs = tuple(qt[:, ci] for ci in range(ncomp))
     ks = _truncation_ks(group, layout)
-    if ks is not None:
-        coeffs = tuple(
-            native.jpeg_zigzag_truncate_native(c, k) if k < 64 else c
-            for c, k in zip(coeffs, ks)
-        )
     profile = _batch_specmax(group)
     if profile is None:
         # Some row lacks a stage-1 profile (per-image fallback decode merged into the
-        # group): recover the split savings with one memory-bound pass over the
-        # already-stacked (possibly truncated) batch instead of forfeiting them.
-        vecs = []
-        for ci, c in enumerate(coeffs):
-            if ks is not None and ks[ci] < 64:
-                v = native.jpeg_specmax_native(c, is_zigzag=True)
-                v = np.pad(v, (0, 64 - ks[ci]))
-            else:
-                v = native.jpeg_specmax_native(c)
-            vecs.append(v)
-        profile = np.stack(vecs)
-    split = [None] * len(coeffs)
+        # group): recover the split savings with one memory-bound pass a run instead
+        # of forfeiting them. Past a component's k every row reads zero (kmax).
+        profile = np.stack([
+            np.maximum.reduce([native.jpeg_specmax_native(c[ci][s:s + m])
+                               for c, _q, s, m in runs])
+            for ci in range(ncomp)])
+    split = [None] * ncomp
     candidate = _split_points(profile, ks, layout)
     with _STICKY_KS_LOCK:
         for ci, s in enumerate(candidate):
             if s is not None and (layout, ci) not in _SPLIT_DISABLED:
                 split[ci] = s
-    packed = []
-    shipped = []
-    for ci, c in enumerate(coeffs):
-        if split[ci] is not None:
-            k1, k2 = split[ci]
-            is_zig = ks is not None and ks[ci] < 64
-            slabs = native.jpeg_pack_split_native(c, k1, k2, is_zigzag=is_zig)
-            if slabs is not None:
-                packed.append(False)
-                shipped.append(slabs)
-                continue
-            # Range exceeded despite the specmax bound: provenance-mixed rows.
-            # Disable sticky for this component and fall through to pack12.
-            split[ci] = None
+        packed = [split[ci] is None and (layout, ci) not in _PACK12_DISABLED
+                  for ci in range(ncomp)]
+    kk = ks if ks is not None else (64,) * ncomp
+    tiers = [(kk[ci], split[ci], packed[ci]) for ci in range(ncomp)]
+    sources = [(c, s, m) for c, _q, s, m in runs]
+    shipped = native.jpeg_pack_runs_native(sources, tiers, threads)
+    for ci in range(ncomp):
+        while shipped[ci] is None:  # a range failure: one tier wider, sticky
             with _STICKY_KS_LOCK:
-                _SPLIT_DISABLED.add((layout, ci))
-        p = None
-        with _STICKY_KS_LOCK:
-            enabled = (layout, ci) not in _PACK12_DISABLED
-        if enabled:
-            p = native.jpeg_pack12_native(c)
-            if p is None:  # 12-bit range exceeded: sticky int16 for this component
-                with _STICKY_KS_LOCK:
+                if split[ci] is not None:
+                    # Range exceeded despite the specmax bound: provenance-mixed
+                    # rows. Disable the split for this component; pack12 next.
+                    split[ci] = None
+                    _SPLIT_DISABLED.add((layout, ci))
+                    packed[ci] = (layout, ci) not in _PACK12_DISABLED
+                else:  # 12-bit range exceeded: int16 for this component
+                    packed[ci] = False
                     _PACK12_DISABLED.add((layout, ci))
-        packed.append(p is not None)
-        shipped.append(p if p is not None else c)
-    n = coeffs[0].shape[0]
+            tiers[ci] = (kk[ci], split[ci], packed[ci])
+            shipped[ci] = native.jpeg_pack_runs_native(
+                sources, [t if c == ci else None for c, t in enumerate(tiers)],
+                threads)[ci]
     raw_bytes = sum(n * by * bx * 64 * 2 for _h, _v, by, bx in layout[2])
     shipped_bytes = sum(
         sum(a.nbytes for a in s) if isinstance(s, tuple) else s.nbytes

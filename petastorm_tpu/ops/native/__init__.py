@@ -51,7 +51,8 @@ def _compile(sources, name):
         return out_path
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_cache_dir())
     os.close(fd)
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", tmp] + list(sources)
+    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread", "-o", tmp] \
+        + list(sources)
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True, timeout=120)
         os.replace(tmp, out_path)  # atomic: concurrent builders converge on one file
@@ -139,6 +140,11 @@ def _load():
                 ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_int8),
                 ctypes.POINTER(ctypes.c_uint8)]
             lib.ptpu_jpeg_pack_split.restype = ctypes.c_int32
+            lib.ptpu_jpeg_pack_rows.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p), ctypes.c_void_p,
+                ctypes.c_int32]
+            lib.ptpu_jpeg_pack_rows.restype = ctypes.c_int32
             _LIB = lib
         except Exception as e:  # noqa: BLE001 — degrade to Python fallback
             _LIB_ERR = str(e)
@@ -353,6 +359,85 @@ def jpeg_pack_split_native(src, k1, k2, is_zigzag=False):
         tail.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
     )
     return (head, mid, tail) if rc == 0 else None
+
+
+def jpeg_pack_runs_native(runs, tiers, threads=1):
+    """Stage-2's shipped input, every component packed in one native pass straight
+    from the rows' own coefficients, with no stacked copy in between.
+
+    ``runs``: ``[(srcs, start, count), ...]``, each the rows ``src[start:start +
+    count]`` of every component's ``src`` in ``srcs``, an ``(m, nblocks, 64)`` int16
+    C-contiguous natural-order array; the output's rows follow the runs in order.
+    ``tiers``: per component, None (left alone) or ``(k, split, pack12)``, ``k`` the
+    zigzag prefix shipped (64: all of it). A component's result is what
+    :func:`jpeg_zigzag_truncate_native` (at k < 64) followed by
+    :func:`jpeg_pack_split_native` (``split=(k1, k2)``), :func:`jpeg_pack12_native`
+    (``pack12``) or nothing gives for the stacked rows, byte for byte; it is None
+    where left alone or on the same range failures. ``threads`` contiguous row
+    slices are packed at once (GIL released)."""
+    import numpy as np
+
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native jpeg decoder unavailable: %s" % _LIB_ERR)
+    ncomp = len(tiers)
+    if not 1 <= ncomp <= 4 or any(len(srcs) != ncomp for srcs, _s, _c in runs):
+        raise ValueError("need 1-4 components, as many in every run as tiers")
+    counts = np.array([c for _s, _st, c in runs], dtype=np.int64)
+    n = int(counts.sum())
+    # row i of a run: that run's first row, plus its place in the run
+    place = np.arange(n, dtype=np.uint64) - np.repeat(
+        (np.cumsum(counts) - counts).astype(np.uint64), counts)
+    rows = np.empty((ncomp, n), dtype=np.uint64)
+    nblocks = np.empty(ncomp, dtype=np.int64)
+    spec = np.full((ncomp, 4), -1, dtype=np.int32)
+    outs, ptrs = [], []
+    for c, tier in enumerate(tiers):
+        nb = runs[0][0][c].shape[1]
+        firsts = []
+        for srcs, start, count in runs:
+            src = srcs[c]
+            if src.dtype != np.int16 or src.ndim != 3 or src.shape[1:] != (nb, 64) \
+                    or not src.flags.c_contiguous:
+                raise ValueError("each run needs C-contiguous (m, %d, 64) int16 arrays, "
+                                 "got %s %s" % (nb, src.dtype, src.shape))
+            if not 0 <= start <= start + count <= src.shape[0]:
+                raise ValueError("rows [%d, %d) outside a run of %d"
+                                 % (start, start + count, src.shape[0]))
+            firsts.append(src.ctypes.data + start * nb * 128)
+        rows[c] = np.repeat(np.array(firsts, dtype=np.uint64), counts) \
+            + place * np.uint64(nb * 128)
+        nblocks[c] = nb
+        out = None
+        if tier is not None:
+            k, split, pack12 = int(tier[0]), tier[1], bool(tier[2])
+            if not 1 <= k <= 64:
+                raise ValueError("k must be in [1, 64], got %r" % (k,))
+            if split is not None:
+                k1, k2 = int(split[0]), int(split[1])
+                if not 0 <= k1 <= k2 <= k or k1 % 2 or (k - k2) % 2:
+                    raise ValueError("need 0 <= k1 <= k2 <= k with k1 and k - k2 even, "
+                                     "got k1=%d k2=%d k=%d" % (k1, k2, k))
+                spec[c] = (k, 2, k1, k2)
+                out = (np.empty((n, nb, k1 * 3 // 2), dtype=np.uint8),
+                       np.empty((n, nb, k2 - k1), dtype=np.int8),
+                       np.empty((n, nb, (k - k2) // 2), dtype=np.uint8))
+            elif pack12:
+                if k % 2:
+                    raise ValueError("pack12 needs an even k, got %d" % k)
+                spec[c] = (k, 1, 0, 0)
+                out = np.empty((n, nb, k * 3 // 2), dtype=np.uint8)
+            else:
+                spec[c] = (k, 0, 0, 0)
+                out = np.empty((n, nb, k), dtype=np.int16)
+        outs.append(out)
+        slabs = out if isinstance(out, tuple) else () if out is None else (out,)
+        ptrs += [a.ctypes.data for a in slabs] + [None] * (3 - len(slabs))
+    status = np.zeros(ncomp, dtype=np.int32)
+    lib.ptpu_jpeg_pack_rows(
+        rows.ctypes.data, n, ncomp, nblocks.ctypes.data, spec.ctypes.data,
+        (ctypes.c_void_p * (3 * ncomp))(*ptrs), status.ctypes.data, int(threads))
+    return [None if st else out for out, st in zip(outs, status)]
 
 
 def jpeg_decode_coeffs_native(data):

@@ -17,9 +17,13 @@
 // interleaved DC scans, per-component AC scans, EOB runs), 1..4 components, restart
 // intervals, 0xFF00 byte stuffing. Rejects lossless/arithmetic/hierarchical modes.
 
+#include <atomic>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
+#include <system_error>
+#include <thread>
+#include <vector>
 
 // Error codes (ptpu_jpeg_error_string maps them to messages)
 enum {
@@ -429,6 +433,119 @@ int decode_progressive_scan(const ProgScanArgs& a, int64_t* end_pos) {
   }
   return prog_find_next_marker(a.data, a.len, br.pos > a.start ? br.pos : a.start,
                                end_pos);
+}
+
+// -- stage-2 input packing -------------------------------------------------------
+// A block row's value at shipped position j: zigzag position j of a natural-order
+// row (kZig), or the row's j-th value as stored.
+template <bool kZig>
+inline int16_t shipped_at(const int16_t* s, int32_t j) {
+  return kZig ? s[kZigzagToNatural[j]] : s[j];
+}
+
+// Two values as one 12-bit pair in 3 bytes (ptpu_jpeg_pack12's layout); false when
+// either lies outside the 12-bit range. The range checks branch: they are all but
+// always taken the same way, and measured faster than branch-free checks.
+inline bool put12(uint8_t* d, int16_t a, int16_t b) {
+  if (a < -2048 || a > 2047 || b < -2048 || b > 2047) return false;
+  uint16_t ua = (uint16_t)a & 0xFFF;
+  uint16_t ub = (uint16_t)b & 0xFFF;
+  d[0] = (uint8_t)(ua & 0xFF);
+  d[1] = (uint8_t)(((ua >> 8) & 0xF) | ((ub & 0xF) << 4));
+  d[2] = (uint8_t)((ub >> 4) & 0xFF);
+  return true;
+}
+
+// Each of nblocks block rows of src (``stride`` values apart), positions [0, k):
+// copy_blocks writes them as int16, pack12_blocks as 12-bit pairs (k even; -1 on a
+// range failure), split_blocks as ptpu_jpeg_pack_split's three slabs.
+template <bool kZig>
+void copy_blocks(const int16_t* src, int64_t stride, int64_t nblocks, int32_t k,
+                 int16_t* dst) {
+  for (int64_t b = 0; b < nblocks; b++) {
+    const int16_t* s = src + b * stride;
+    int16_t* d = dst + b * k;
+    for (int32_t j = 0; j < k; j++) d[j] = shipped_at<kZig>(s, j);
+  }
+}
+
+template <bool kZig>
+int32_t pack12_blocks(const int16_t* src, int64_t stride, int64_t nblocks, int32_t k,
+                      uint8_t* dst) {
+  const int64_t out_stride = (int64_t)(k / 2) * 3;
+  for (int64_t b = 0; b < nblocks; b++) {
+    const int16_t* s = src + b * stride;
+    uint8_t* d = dst + b * out_stride;
+    for (int32_t j = 0; j < k; j += 2)
+      if (!put12(d + (j / 2) * 3, shipped_at<kZig>(s, j), shipped_at<kZig>(s, j + 1)))
+        return -1;
+  }
+  return 0;
+}
+
+// Kept out of line: inlined into ptpu_jpeg_pack_rows' row loop it ran slower.
+template <bool kZig>
+__attribute__((noinline)) int32_t split_blocks(const int16_t* src, int64_t stride,
+                                               int64_t nblocks, int32_t k, int32_t k1,
+                                               int32_t k2, uint8_t* head, int8_t* mid,
+                                               uint8_t* tail) {
+  const int64_t head_stride = (int64_t)(k1 / 2) * 3;
+  const int64_t mid_stride = k2 - k1;
+  const int64_t tail_stride = (k - k2) / 2;
+  for (int64_t b = 0; b < nblocks; b++) {
+    const int16_t* s = src + b * stride;
+    uint8_t* hd = head + b * head_stride;
+    int8_t* md = mid + b * mid_stride;
+    uint8_t* tl = tail + b * tail_stride;
+    for (int32_t j = 0; j < k1; j += 2)
+      if (!put12(hd + (j / 2) * 3, shipped_at<kZig>(s, j), shipped_at<kZig>(s, j + 1)))
+        return -1;
+    for (int32_t j = k1; j < k2; j++) {
+      int16_t a = shipped_at<kZig>(s, j);
+      if (a < -128 || a > 127) return -2;
+      md[j - k1] = (int8_t)a;
+    }
+    for (int32_t j = k2; j < k; j += 2) {
+      int16_t a = shipped_at<kZig>(s, j), c = shipped_at<kZig>(s, j + 1);
+      if (a < -8 || a > 7 || c < -8 || c > 7) return -3;
+      tl[(j - k2) / 2] = (uint8_t)(((uint8_t)a & 0xF) | (((uint8_t)c & 0xF) << 4));
+    }
+  }
+  return 0;
+}
+
+// One component of ptpu_jpeg_pack_rows (see there): its rows, variant and slabs.
+struct PackComp {
+  const int16_t* const* rows;
+  int64_t nblocks;
+  int32_t k, mode, k1, k2;
+  void* out0;
+  int8_t* out1;
+  uint8_t* out2;
+};
+
+int32_t pack_row(const PackComp a, int64_t i) {
+  const int16_t* s = a.rows[i];
+  const int64_t nb = a.nblocks;
+  const int32_t k = a.k;
+  const bool zig = a.mode == 2 || k < 64;
+  if (a.mode == 0) {
+    int16_t* d = (int16_t*)a.out0 + i * nb * k;
+    if (zig)
+      copy_blocks<true>(s, 64, nb, k, d);
+    else
+      memcpy(d, s, (size_t)nb * 64 * sizeof(int16_t));
+    return 0;
+  }
+  if (a.mode == 1) {
+    uint8_t* d = (uint8_t*)a.out0 + i * nb * (k / 2) * 3;
+    return zig ? pack12_blocks<true>(s, 64, nb, k, d)
+               : pack12_blocks<false>(s, 64, nb, k, d);
+  }
+  return split_blocks<true>(s, 64, nb, k, a.k1, a.k2,
+                            (uint8_t*)a.out0 + i * nb * (a.k1 / 2) * 3,
+                            a.out1 + i * nb * (a.k2 - a.k1),
+                            a.out2 + i * nb * ((k - a.k2) / 2));
 }
 
 }  // namespace
@@ -975,11 +1092,7 @@ int ptpu_jpeg_decode_batch(const uint8_t* const* datas, const int64_t* lens, int
 // the needed elements — ~k/64 of the bytes a numpy fancy-gather touches.
 void ptpu_jpeg_zigzag_truncate(const int16_t* src, int16_t* dst, int64_t nblocks,
                                int32_t k) {
-  for (int64_t b = 0; b < nblocks; b++) {
-    const int16_t* s = src + b * 64;
-    int16_t* d = dst + b * k;
-    for (int32_t j = 0; j < k; j++) d[j] = s[kZigzagToNatural[j]];
-  }
+  copy_blocks<true>(src, 64, nblocks, k, dst);
 }
 
 // 12-bit coefficient pack: src (nvals,) int16 → dst (nvals * 3 / 2,) uint8, two
@@ -992,16 +1105,8 @@ void ptpu_jpeg_zigzag_truncate(const int16_t* src, int16_t* dst, int64_t nblocks
 // with even k. Quantized DCT coefficients exceed ±2047 only at extreme qualities
 // (quant step 1–2 with saturated content), so the fallback is rare but mandatory.
 int32_t ptpu_jpeg_pack12(const int16_t* src, uint8_t* dst, int64_t nvals) {
-  for (int64_t i = 0; i < nvals; i += 2) {
-    int16_t a = src[i], b = src[i + 1];
-    if (a < -2048 || a > 2047 || b < -2048 || b > 2047) return -1;
-    uint16_t ua = (uint16_t)a & 0xFFF;
-    uint16_t ub = (uint16_t)b & 0xFFF;
-    uint8_t* d = dst + (i / 2) * 3;
-    d[0] = (uint8_t)(ua & 0xFF);
-    d[1] = (uint8_t)(((ua >> 8) & 0xF) | ((ub & 0xF) << 4));
-    d[2] = (uint8_t)((ub >> 4) & 0xFF);
-  }
+  for (int64_t i = 0; i < nvals; i += 2)
+    if (!put12(dst + (i / 2) * 3, src[i], src[i + 1])) return -1;
   return 0;
 }
 
@@ -1039,38 +1144,72 @@ void ptpu_jpeg_specmax(const int16_t* src, int64_t nblocks, int32_t k,
 int32_t ptpu_jpeg_pack_split(const int16_t* src, int64_t nblocks, int32_t k,
                              int32_t is_zigzag, int32_t k1, int32_t k2,
                              uint8_t* head, int8_t* mid, uint8_t* tail) {
-  const int64_t head_stride = (int64_t)(k1 / 2) * 3;
-  const int64_t mid_stride = k2 - k1;
-  const int64_t tail_stride = (k - k2) / 2;
-  for (int64_t b = 0; b < nblocks; b++) {
-    const int16_t* s = src + b * k;
-    uint8_t* hd = head + b * head_stride;
-    int8_t* md = mid + b * mid_stride;
-    uint8_t* tl = tail + b * tail_stride;
-    for (int32_t j = 0; j < k1; j += 2) {
-      int16_t a = is_zigzag ? s[j] : s[kZigzagToNatural[j]];
-      int16_t c = is_zigzag ? s[j + 1] : s[kZigzagToNatural[j + 1]];
-      if (a < -2048 || a > 2047 || c < -2048 || c > 2047) return -1;
-      uint16_t ua = (uint16_t)a & 0xFFF;
-      uint16_t uc = (uint16_t)c & 0xFFF;
-      uint8_t* d = hd + (j / 2) * 3;
-      d[0] = (uint8_t)(ua & 0xFF);
-      d[1] = (uint8_t)(((ua >> 8) & 0xF) | ((uc & 0xF) << 4));
-      d[2] = (uint8_t)((uc >> 4) & 0xFF);
+  return is_zigzag ? split_blocks<false>(src, k, nblocks, k, k1, k2, head, mid, tail)
+                   : split_blocks<true>(src, k, nblocks, k, k1, k2, head, mid, tail);
+}
+
+// Stage-2's shipped input, every component in one pass straight from each row's own
+// coefficients: for component c, rows[c * n + i] points at row i's (nblocks[c], 64)
+// int16 natural-order blocks (a row of its row group's stacked buffer, or a lone
+// row's blocks), and row i of its output is written at row i. spec[4c..4c+3] is
+// (k, mode, k1, k2), and outs[3c..3c+2] its slabs; ``mode`` names the variant:
+//   -1 skip  : the component is left alone
+//    0 int16 : out0 (n, nblocks, k) int16, the zigzag prefix (ptpu_jpeg_zigzag_truncate)
+//              or, at k == 64, the natural-order row as it is
+//    1 pack12: out0 (n, nblocks, k*3/2) uint8, the same values as 12-bit pairs
+//    2 split : out0/out1/out2 the head/mid/tail slabs of ptpu_jpeg_pack_split over
+//              zigzag positions [0, k), split at (k1, k2)
+// The rows are cut into ``nthreads`` contiguous slices, one a thread (the calling
+// thread takes the first), each packing every component of its rows. status[c] is 0,
+// or the range failure code (pack12's -1, the split's -1/-2/-3) a row of component c
+// met: its output is then unspecified, and no slice packs it further. Returns the
+// number of components that failed.
+int32_t ptpu_jpeg_pack_rows(const int16_t* const* rows, int64_t n, int32_t ncomp,
+                            const int64_t* nblocks, const int32_t* spec,
+                            void* const* outs, int32_t* status, int32_t nthreads) {
+  if (ncomp < 1 || ncomp > 4) return -1;
+  PackComp comps[4];
+  std::atomic<int32_t> failed[4];
+  for (int32_t c = 0; c < ncomp; c++) {
+    const int32_t* sp = spec + 4 * c;
+    comps[c] = PackComp{rows + c * n, nblocks[c], sp[0], sp[1], sp[2], sp[3],
+                        outs[3 * c], (int8_t*)outs[3 * c + 1], (uint8_t*)outs[3 * c + 2]};
+    failed[c].store(sp[1] < 0 ? 1 : 0);  // a skipped component reads as done
+  }
+  auto slice = [&comps, &failed, ncomp](int64_t lo, int64_t hi) {
+    for (int64_t i = lo; i < hi; i++) {
+      bool any = false;
+      for (int32_t c = 0; c < ncomp; c++) {
+        if (failed[c].load(std::memory_order_relaxed) != 0) continue;
+        any = true;
+        int32_t rc = pack_row(comps[c], i);
+        if (rc != 0) {
+          int32_t none = 0;
+          failed[c].compare_exchange_strong(none, rc);
+        }
+      }
+      if (!any) return;
     }
-    for (int32_t j = k1; j < k2; j++) {
-      int16_t a = is_zigzag ? s[j] : s[kZigzagToNatural[j]];
-      if (a < -128 || a > 127) return -2;
-      md[j - k1] = (int8_t)a;
-    }
-    for (int32_t j = k2; j < k; j += 2) {
-      int16_t a = is_zigzag ? s[j] : s[kZigzagToNatural[j]];
-      int16_t c = is_zigzag ? s[j + 1] : s[kZigzagToNatural[j + 1]];
-      if (a < -8 || a > 7 || c < -8 || c > 7) return -3;
-      tl[(j - k2) / 2] = (uint8_t)(((uint8_t)a & 0xF) | (((uint8_t)c & 0xF) << 4));
+  };
+  if (nthreads > n) nthreads = (int32_t)n;
+  if (nthreads < 1) nthreads = 1;
+  std::vector<std::thread> workers;
+  for (int32_t t = 1; t < nthreads; t++) {
+    int64_t lo = n * t / nthreads, hi = n * (t + 1) / nthreads;
+    try {
+      workers.emplace_back(slice, lo, hi);
+    } catch (const std::system_error&) {
+      slice(lo, hi);  // no thread to be had: this one does the slice
     }
   }
-  return 0;
+  slice(0, n / nthreads);
+  for (auto& w : workers) w.join();
+  int32_t failures = 0;
+  for (int32_t c = 0; c < ncomp; c++) {
+    status[c] = comps[c].mode < 0 ? 0 : failed[c].load();
+    failures += status[c] != 0;
+  }
+  return failures;
 }
 
 }  // extern "C"

@@ -800,24 +800,31 @@ def test_pack_overflow_sticky_fallback_still_exact():
     ref = np.asarray(j.decode_jpeg_batch(batch))  # full-tier path (normal content)
 
     layout = j._layout_key(batch[0])
-    orig_split = native.jpeg_pack_split_native
-    orig_pack = native.jpeg_pack12_native
+    orig_pack = native.jpeg_pack_runs_native
+
+    def overflowing(*failing):
+        def pack(runs, tiers, threads=1):
+            out = orig_pack(runs, tiers, threads)
+            return [None if t is not None and ("split" if t[1] is not None else
+                                               "pack12" if t[2] else "int16") in failing
+                    else o for o, t in zip(out, tiers)]
+        return pack
+
     try:
         # force split 'overflow': must fall back to pack12 bit-equal + sticky-disable
-        native.jpeg_pack_split_native = lambda src, k1, k2, is_zigzag=False: None
+        native.jpeg_pack_runs_native = overflowing("split")
         out = np.asarray(j.decode_jpeg_batch(batch))
         np.testing.assert_array_equal(out, ref)
         with j._STICKY_KS_LOCK:
             assert any(key[0] == layout for key in j._SPLIT_DISABLED)
         # force pack12 'overflow' too: int16 fallback bit-equal + sticky-disable
-        native.jpeg_pack12_native = lambda src: None
+        native.jpeg_pack_runs_native = overflowing("split", "pack12")
         out = np.asarray(j.decode_jpeg_batch(batch))
         np.testing.assert_array_equal(out, ref)
         with j._STICKY_KS_LOCK:
             assert any(key[0] == layout for key in j._PACK12_DISABLED)
     finally:
-        native.jpeg_pack_split_native = orig_split
-        native.jpeg_pack12_native = orig_pack
+        native.jpeg_pack_runs_native = orig_pack
         with j._STICKY_KS_LOCK:
             j._PACK12_DISABLED.clear()  # don't leak the forced state to other tests
             j._SPLIT_DISABLED.clear()
@@ -848,3 +855,191 @@ def test_transfer_byte_counters_track_realized_narrowing():
     # raw equals the full int16 coefficient footprint for the batch
     expected_raw = sum(c.blocks.size * 2 for p in batch for c in p.components)
     assert after["raw"] == expected_raw
+
+
+# -- stage-2's input packed straight from the row groups' buffers ----------------------
+
+#: synthetic layout: 4:2:0 over 4x4 luma blocks (the packer reads only the block
+#: counts); its own image size keeps its sticky variant state apart from others
+_PACK_LAYOUT = (24, 40, ((2, 2, 4, 4), (1, 1, 2, 2), (1, 1, 2, 2)))
+#: per zigzag position, the largest |coefficient| drawn: 12-bit head, int8 mid,
+#: 4-bit tail, so the spectral split takes all three tiers
+_PACK_LIMITS = np.array([1500] * 4 + [100] * 20 + [7] * 40)
+
+
+def _pack_parent(rng, rows, kmax, dc=None, specmax_cap=None):
+    """A synthetic row-group batch decode: stacked coefficient buffers holding
+    nonzeros only up to ``kmax`` (zigzag) per component, the planes that view them,
+    and the group's profile (``specmax_cap`` understates it, as mixed-provenance
+    rows can; ``dc`` writes one out-of-12-bit DC coefficient)."""
+    from petastorm_tpu.ops import native
+    from petastorm_tpu.ops.jpeg import ZIGZAG, JpegComponent, JpegPlanes
+
+    height, width, comps = _PACK_LAYOUT
+    coeffs = []
+    for (_h, _v, by, bx), km in zip(comps, kmax):
+        a = np.zeros((rows, by * bx, 64), np.int16)
+        lim = _PACK_LIMITS[:km + 1]
+        a[..., ZIGZAG[:km + 1]] = rng.randint(-lim, lim + 1, (rows, by * bx, km + 1))
+        coeffs.append(a)
+    if dc is not None:
+        coeffs[0][rows // 2, 1, 0] = dc
+    coeffs = tuple(coeffs)
+    qtabs = rng.randint(1, 100, (rows, len(comps), 64)).astype(np.int32)
+    specmax = np.stack([native.jpeg_specmax_native(c) for c in coeffs])
+    if specmax_cap is not None:
+        specmax = np.minimum(specmax, specmax_cap)
+    return [JpegPlanes(height, width,
+                       [JpegComponent(coeffs[c][i].reshape(by, bx, 64), qtabs[i, c], h, v)
+                        for c, (h, v, by, bx) in enumerate(comps)],
+                       batch_ref=(coeffs, qtabs, i), kmax=tuple(kmax), specmax=specmax)
+            for i in range(rows)]
+
+
+def _lone(row, profiled=False):
+    """``row`` as a per-image fallback decode gives it: its own blocks, no
+    ``batch_ref`` (and, unless ``profiled``, no ``kmax`` or profile)."""
+    d = row.detach()
+    if not profiled:
+        d.kmax = d.specmax = None
+    return d
+
+
+def _stacked_pack(group, variant):
+    """What the stage-2 variant ships, composed as before the one-pass packer:
+    stack, zigzag-truncate, then split, 12-bit or int16."""
+    from petastorm_tpu.ops import native
+    from petastorm_tpu.ops.jpeg import stack_jpeg_coefficients
+
+    ks, packed, split = variant
+    coeffs, qtabs = stack_jpeg_coefficients(group)
+    out = []
+    for ci, c in enumerate(coeffs):
+        k = ks[ci] if ks is not None else 64
+        if k < 64:
+            c = native.jpeg_zigzag_truncate_native(c, k)
+        if split[ci] is not None:
+            out.append(native.jpeg_pack_split_native(c, *split[ci], is_zigzag=k < 64))
+        elif packed[ci]:
+            out.append(native.jpeg_pack12_native(c))
+        else:
+            out.append(np.ascontiguousarray(c, dtype=np.int16))
+    return out, qtabs
+
+
+def _pack_case(case, rng):
+    """``(group, expected ks, component the fallbacks hit, expected tier there)``."""
+    full = (63, 63, 63)
+    p = [_pack_parent(rng, 20, full) for _ in range(3)]
+    if case == "one_parent_consecutive":
+        return p[0][2:18], None, None, None
+    if case == "one_parent_gathered":
+        return [p[0][i] for i in (7, 3, 19, 0, 12, 4, 5, 6, 18, 1)], None, None, None
+    if case == "two_parents":
+        return p[0][12:] + p[1][:10], None, None, None
+    if case == "three_parents":
+        return p[0][15:] + p[1] + p[2][:5], None, None, None
+    if case == "fallback_rows":
+        return p[0][:6] + [_lone(p[1][3]), _lone(p[1][9])] + p[0][6:9], None, None, None
+    if case == "ks16":
+        a, b = _pack_parent(rng, 20, (15, 9, 9)), _pack_parent(rng, 20, (13, 11, 7))
+        return a[10:] + b[:8], (16, 16, 16), None, None
+    if case == "ks32_lone_profiled":
+        a = _pack_parent(rng, 20, (31, 17, 20))
+        return a[:9] + [_lone(a[15], profiled=True)] + a[9:12], (32, 32, 32), None, None
+    if case.startswith("split_fails_to_pack12"):
+        # the profile claims Cb's tail fits 4 bits; row values say otherwise
+        cap = np.full((3, 64), 10 ** 4)
+        cap[1, 8:] = 7
+        kmax, ks = ((31, 31, 31), (32, 32, 32)) if case.endswith("ks32") else (full, None)
+        a = _pack_parent(rng, 20, kmax, specmax_cap=cap)
+        return a[4:16], ks, 1, "pack12"
+    if case.startswith("pack12_overflows_to_int16"):
+        kmax, ks = ((15, 9, 9), (16, 16, 16)) if case.endswith("ks16") else (full, None)
+        a = _pack_parent(rng, 20, kmax, dc=3000)
+        return a[5:15] + _pack_parent(rng, 6, kmax), ks, 0, "int16"
+    raise AssertionError(case)
+
+
+@pytest.mark.parametrize("threads", [1, 5])
+@pytest.mark.parametrize("case", [
+    "one_parent_consecutive", "one_parent_gathered", "two_parents", "three_parents",
+    "fallback_rows", "ks16", "ks32_lone_profiled", "split_fails_to_pack12",
+    "split_fails_to_pack12_ks32", "pack12_overflows_to_int16",
+    "pack12_overflows_to_int16_ks16"])
+def test_one_pass_pack_matches_stacked_composition(case, threads, monkeypatch):
+    """The one-pass packer ships byte for byte what stacking the rows, truncating
+    the zigzag prefix and narrowing (split, 12-bit or int16) ships, with the same
+    qtabs and variant, over one thread or several, for rows of one, two or three
+    row groups in or out of order and lone per-image rows; a range failure falls
+    down the same chain (split, then 12-bit, then int16), sticky."""
+    from petastorm_tpu.ops import jpeg as j
+    from petastorm_tpu.ops import native
+
+    if not native.native_available():
+        pytest.skip("native toolchain unavailable: %s" % native.native_error())
+    group, ks, fault_comp, fault_tier = _pack_case(case, np.random.RandomState(51))
+    layout = _PACK_LAYOUT
+    monkeypatch.setattr(j, "_pack_threads", lambda n: threads)
+
+    def forget():
+        with j._STICKY_KS_LOCK:
+            j._STICKY_KS.pop(layout, None)
+            j._STICKY_SPLIT.pop(layout, None)
+            for ci in range(3):
+                j._SPLIT_DISABLED.discard((layout, ci))
+                j._PACK12_DISABLED.discard((layout, ci))
+
+    forget()
+    try:
+        variant, shipped, qtabs, nbytes = j._pack_group(layout, group)
+        with j._STICKY_KS_LOCK:
+            split_off = {ci for ci in range(3) if (layout, ci) in j._SPLIT_DISABLED}
+            pack12_off = {ci for ci in range(3) if (layout, ci) in j._PACK12_DISABLED}
+    finally:
+        forget()
+    ks_got, packed, split = variant
+    assert ks_got == ks
+    for ci in range(3):
+        tier = "split" if split[ci] is not None else "pack12" if packed[ci] else "int16"
+        assert tier == (fault_tier if ci == fault_comp else "split"), (ci, variant)
+    assert split_off == ({fault_comp} if fault_comp is not None else set())
+    assert pack12_off == ({fault_comp} if fault_tier == "int16" else set())
+
+    want, want_q = _stacked_pack(group, variant)
+    for got, exp in zip(shipped, want):
+        for g, e in zip(got if isinstance(got, tuple) else (got,),
+                        exp if isinstance(exp, tuple) else (exp,)):
+            assert g.dtype == e.dtype and g.shape == e.shape
+            np.testing.assert_array_equal(g, e)
+    for g, e in zip(qtabs, want_q):
+        assert g.dtype == e.dtype
+        np.testing.assert_array_equal(g, e)
+    assert nbytes == sum(a.nbytes for s in want
+                         for a in (s if isinstance(s, tuple) else (s,)))
+
+
+def test_decode_across_two_row_groups_bit_equal():
+    """A batch that crosses from one row group's buffers into the next decodes on
+    the one-pass path exactly as the un-narrowed stacked transfer does."""
+    from petastorm_tpu.ops import jpeg as j
+    from petastorm_tpu.ops import native
+
+    if not native.native_available():
+        pytest.skip("native toolchain unavailable: %s" % native.native_error())
+    rng = np.random.RandomState(52)
+    blobs = []
+    for _ in range(10):
+        img = rng.randint(0, 256, (32, 40, 3)).astype(np.float32)
+        img = 0.5 * cv2.GaussianBlur(img, (5, 5), 1.5) + 0.5 * img
+        ok, enc = cv2.imencode(".jpg", img.clip(0, 255).astype(np.uint8),
+                               [cv2.IMWRITE_JPEG_QUALITY, 85])
+        blobs.append(enc.tobytes())
+    first, second = j.entropy_decode_jpeg_batch(blobs[:6]), \
+        j.entropy_decode_jpeg_batch(blobs[6:])
+    batch = first[2:] + second
+    assert len(j._source_runs(batch)) == 2
+    layout = j._layout_key(batch[0])
+    coeffs, qtabs = j.stack_jpeg_coefficients(batch)
+    raw = np.asarray(j._batched_stage2(layout)(coeffs, qtabs))
+    np.testing.assert_array_equal(np.asarray(j.decode_jpeg_batch(batch)), raw)

@@ -311,3 +311,23 @@ def test_device_inflate_stage_on_the_profiler_clock(tmp_path):
     inflates = [a for _l, nm, _s, _e, a in events if nm == "decode.device_inflate"]
     assert n_batches == 3
     assert sorted(a["batch"] for a in inflates) == [0, 1, 2]
+
+
+def test_decode_pack_span_says_threads_and_runs(tmp_path):
+    """``ptpu.decode.pack`` carries how wide the pack ran (``threads``) and how
+    many contiguous source runs it read: 2 for a batch across two row groups."""
+    cv2 = pytest.importorskip("cv2")
+    from petastorm_tpu.ops import jpeg as j
+    from petastorm_tpu.ops import native
+
+    if not native.native_available():
+        pytest.skip("native toolchain unavailable: %s" % native.native_error())
+    rng = np.random.RandomState(7)
+    blobs = [cv2.imencode(".jpg", rng.randint(0, 256, (16, 24, 3), dtype=np.uint8))[1]
+             .tobytes() for _ in range(8)]
+    batch = j.entropy_decode_jpeg_batch(blobs[:5])[1:] + \
+        j.entropy_decode_jpeg_batch(blobs[5:])
+
+    _out, events = _profiled(tmp_path, lambda: np.asarray(j.decode_jpeg_batch(batch)))
+    packs = [a for _l, nm, _s, _e, a in events if nm == "decode.pack"]
+    assert packs == [{"threads": j._pack_threads(len(batch)), "runs": 2}]
