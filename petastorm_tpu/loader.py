@@ -44,6 +44,10 @@ logger = logging.getLogger(__name__)
 
 _SENTINEL = object()
 
+#: the host batch's key for a packed batch's chunk table (``pack=``): popped on
+#: the transfer thread, shipped beside the tokens, read by jit_ptpu_pack_layout
+_PACK_TABLE = "__pack_table__"
+
 #: per-process pipeline ids for health-scope namespacing: loaders SHARING one
 #: HealthMonitor must not share heartbeat slots (itertools.count is GIL-atomic)
 _pipeline_seq = itertools.count()
@@ -81,6 +85,11 @@ class PipelineStats:
     ``device_put``s), ``h2d_wait_s`` (the wait for the transfers before host
     buffers go back to their rings). ``h2d_bytes`` counts what both puts shipped.
 
+    Under ``DataLoader(pack=...)``: ``pack_s`` is producer time packing
+    documents into rows (chunking, placement, the token copy); ``pack_slots``,
+    ``pack_pad_slots`` and ``pack_chunks`` count the slots, the padding slots and
+    the chunks of the batches it emitted.
+
     ``decode_unsharded_batches`` counts staged-decode dispatches that ran on a
     SINGLE device although the configured sharding cuts the batch axis across
     several — the undivisible-batch / local-mesh-derivation-failure / pre-``sharding``-
@@ -107,7 +116,8 @@ class PipelineStats:
                  "decode_s", "h2d_s",
                  "queue_wait_s", "device_queue_wait_s", "decode_unsharded_batches",
                  "shm_slabs_in_flight", "shm_bytes", "shm_fallbacks",
-                 "shm_acquire_wait_s") + tuple(_PHASES.values()) + ("h2d_bytes",)
+                 "shm_acquire_wait_s") + tuple(_PHASES.values()) + (
+                     "h2d_bytes", "pack_s", "pack_slots", "pack_pad_slots", "pack_chunks")
 
     def __init__(self):
         self.reset()
@@ -144,6 +154,7 @@ class PipelineStats:
 _STAGES = {
     "reader.next": ("read_s", "read", None, None),
     "batch.form": ("batch_s", "batch", None, None),
+    "pack": ("pack_s", "pack", None, None),
     "wait.host_queue_put": ("put_wait_s", "host_queue_put", "loader.host_queue_put",
                             None),
     "wait.host_queue": ("queue_wait_s", "host_queue_wait", None, None),
@@ -161,17 +172,23 @@ class _Stage:
     ``PipelineStats`` field, the ``trace=`` recorder, the stage histogram, the
     flight ring, provenance (on ``bp`` when given, else on the batch in
     transfer), the tenant ledger, and ``ptpu.<name>`` on the JAX profiler's
-    clock with ``args`` (``batch=<host-batch number>``) as its metadata. While
-    open, the ``trace.phase`` spans on its thread add to the stats' phases."""
+    clock with ``args`` (``batch=<host-batch number>``) as its metadata, and
+    more through :meth:`set_metadata`. While open, the ``trace.phase`` spans on
+    its thread add to the stats' phases."""
 
-    __slots__ = ("loader", "name", "bp", "span", "t0", "dt")
+    __slots__ = ("loader", "name", "bp", "span", "entered", "t0", "dt")
 
     def __init__(self, loader, name, bp=None, **args):
         self.loader, self.name, self.bp = loader, name, bp
         self.span = profiler_span("ptpu." + name, **args)
 
+    def set_metadata(self, **args):
+        """Add ``args`` to the open profiler span (a no-op without JAX)."""
+        if self.entered is not None:
+            self.entered.set_metadata(**args)
+
     def __enter__(self):
-        self.span.__enter__()
+        self.entered = self.span.__enter__()
         set_phase_sink(self.loader.stats)
         self.t0 = time.perf_counter()
         return self
@@ -675,6 +692,31 @@ class DataLoader:
         boolean ``<field>__mask`` column marking the valid region, so the column
         reaches the device with a static shape. Rows exceeding the declared max raise.
         Ragged tensor fields WITHOUT a declared max raise at transfer time.
+    pack : dict, optional
+        Sequence packing for language-model pretraining:
+        ``{"field": "input_ids", "length": 8192}`` packs the documents of a
+        ragged integer list column (one document a row) into ``[batch_size,
+        length]`` rows; ``batch_size`` then counts packed rows. The reader must
+        deliver only that field (``schema_fields=[field]``). Rules: documents are
+        taken in delivered order; an empty one gives no chunk; one of ``n``
+        tokens gives ``ceil(n / length)`` chunks, in order, each ``length``
+        tokens except the last (no token is dropped); each chunk goes to the
+        lowest-index row of the batch being formed that has room for it, and
+        when no row has room the batch is emitted and the chunk starts the next
+        one, so a part-filled batch carries across reader deliveries, row groups
+        and epochs. At the end of a finite stream ``last_batch`` applies to the
+        part-filled batch: ``drop`` drops it unless every row holds a chunk,
+        ``pad`` adds empty rows marked false in ``__valid__``, ``partial`` yields
+        its rows that hold a chunk. Each batch has three ``[rows, length]`` int32
+        columns: ``<field>`` (tokens, 0 in padding), ``<field>_segment_ids`` (a
+        row's chunks numbered 1, 2, ... in placement order, 0 in padding) and
+        ``<field>_positions`` (0 .. len-1 within each chunk, 0 in padding). The
+        producer thread packs (the ``pack`` stage) and ships the tokens and a
+        small chunk table; the device program ``jit_ptpu_pack_layout`` derives
+        the segment ids and positions after the transfer. Raises with
+        ``shuffling_queue_capacity``, ``device_shuffle_capacity``, ``pad_shapes``
+        on the field and a batch split over processes, and ``state_dict()``
+        raises: the part-filled batch is not checkpointed yet.
     trace : petastorm_tpu.trace.TraceRecorder, optional
         Records every pipeline stage (reader fetch, batch formation, decode
         dispatch, H2D, queue waits) as chrome-trace spans — the per-span view of
@@ -773,7 +815,7 @@ class DataLoader:
                  to_device=True, host_queue_size=8, pad_shapes=None,
                  device_shuffle_capacity=0, device_decode_resize=None, trace=None,
                  metrics=None, health=None, staging=None, provenance=None,
-                 slos=None, controller=None, tenant=None):
+                 slos=None, controller=None, tenant=None, pack=None):
         if batch_size <= 0:
             raise ValueError("batch_size must be positive")
         #: per-tenant accounting (ISSUE 18): explicit tenant= wins, else the
@@ -810,6 +852,18 @@ class DataLoader:
         self._device_decode_resize = _validate_decode_resize(
             device_decode_resize, getattr(reader, "device_decode_fields", None))
         self._device_shuffle_capacity = int(device_shuffle_capacity or 0)
+        #: (field, length) of sequence packing, or None (see the ``pack`` parameter)
+        self._pack = _resolve_pack(pack, shuffling_queue_capacity,
+                                   self._device_shuffle_capacity, self._pad_shapes,
+                                   self.local_batch_size != self.batch_size)
+        #: jit_ptpu_pack_layout per row count (a partial last batch has fewer)
+        self._pack_programs = {}
+        #: a packing loader takes ragged columns as values and offsets (no
+        #: per-row arrays); un-adopted at __exit__
+        self._adopted_ragged = False
+        if self._pack is not None and hasattr(reader, "keep_ragged"):
+            reader.keep_ragged = True
+            self._adopted_ragged = True
         #: compressed-page pass-through adoption (ISSUE 14): this loader
         #: finishes the inflate itself (device kernels when a non-CPU backend
         #: is live, the numpy reference otherwise), so the reader must stop
@@ -898,7 +952,8 @@ class DataLoader:
         # next to each delivery's parquet IO.
         self._ckpt_enabled = (hasattr(reader, "state_dict")
                               and not shuffling_queue_capacity
-                              and not self._device_shuffle_capacity)
+                              and not self._device_shuffle_capacity
+                              and self._pack is None)
         self._ckpt_lock = threading.Lock()
         self._ckpt_log = collections.deque()
         self._ckpt_base = None
@@ -991,7 +1046,8 @@ class DataLoader:
             if trace is not None:
                 rec.set_trace(trace)  # Perfetto flow events into the dump
             rec.set_batch_tracking(not shuffling_queue_capacity
-                                   and not self._device_shuffle_capacity)
+                                   and not self._device_shuffle_capacity
+                                   and self._pack is None)
             if hasattr(reader, "set_provenance") and existing is not rec:
                 reader.set_provenance(rec)
             self._prov_rec = rec
@@ -1102,6 +1158,11 @@ class DataLoader:
     def _produce(self, q):
         batcher = _HostBatcher(self.local_batch_size, self._shuffling_queue_capacity,
                                self._seed)
+        packer = None
+        if self._pack is not None:
+            from petastorm_tpu.ops.packing import Packer
+
+            packer = Packer(self.local_batch_size, self._pack[1])
         stats = self.stats
         # health wiring (ISSUE 5): one heartbeat for this producer thread,
         # stamped at the stage sites (disabled = hb is None, one check per
@@ -1176,6 +1237,14 @@ class DataLoader:
                     columns = _detach_slab_views(columns)
                 if wire_stats_fn is not None:
                     stats.update_wire(wire_stats_fn())
+                if packer is not None:
+                    try:  # the packer copies the tokens out of the delivery
+                        if not self._pack_delivery(q, packer, columns, hb):
+                            return
+                    finally:
+                        if lease is not None:
+                            lease.release()
+                    continue
                 if hb is not None:
                     hb.beat("batch")
                 with _Stage(self, "batch.form", batch=self._batches_put) as st:
@@ -1225,6 +1294,12 @@ class DataLoader:
             # after the consumer already exited on the re-injected sentinel. Under
             # last_batch='drop' the shuffling buffer can still hold whole batches at
             # reader exhaustion — only the short tail is dropped.
+            if packer is not None:
+                with _Stage(self, "pack", batch=self._batches_put) as st:
+                    tail = self._packed_batch(packer.finish(), st)
+                if tail is not None:
+                    self._deliver_batches(q, [tail], hb)
+                return
             if not self._deliver_batches(q, batcher.finish(), hb,
                                          drop_short=self.last_batch == "drop"):
                 return
@@ -1242,6 +1317,80 @@ class DataLoader:
                 hb.done()
             self._hb_producer = None
             _put_sentinel(q, self._stop)
+
+    def _pack_delivery(self, q, packer, columns, hb):
+        """Pack one reader delivery: feed its documents, then hand on every batch
+        they complete, each under a ``pack`` stage of its own (the last stage of a
+        delivery places the chunks that start the next batch). Returns False once
+        the loader is stopped."""
+        field = self._pack[0]
+        if set(columns) != {field}:
+            raise ValueError(
+                "pack= packs the field %r alone, but the reader delivers %s: open it "
+                "with schema_fields=[%r]" % (field, sorted(columns), field))
+        docs = columns[field]
+        while True:
+            if hb is not None:
+                hb.beat("batch")
+            with _Stage(self, "pack", batch=self._batches_put) as st:
+                if docs is not None:
+                    packer.feed(docs)
+                    docs = None
+                batch = self._packed_batch(packer.next_batch(), st)
+            if batch is None:
+                return True
+            if not self._deliver_batches(q, [batch], hb):
+                return False
+
+    def _packed_batch(self, packed, st):
+        """A :class:`~petastorm_tpu.ops.packing.PackedBatch` as a host batch: the
+        tokens, the chunk table under ``_PACK_TABLE`` and, under ``last_batch=
+        'pad'``, ``__valid__``. A batch with empty rows (the part-filled batch at
+        the end of the stream) follows ``last_batch``; None for none. Its counts
+        go to the stats and to the open ``pack`` span."""
+        if packed is None:
+            return None
+        tokens = packed.tokens
+        if packed.rows_used < len(tokens):
+            if self.last_batch == "drop":
+                return None
+            if self.last_batch == "partial":
+                tokens = tokens[:packed.rows_used]
+        pad_slots = tokens.size - (packed.tokens.size - packed.pad_slots)
+        st.set_metadata(chunks=packed.chunks, split_docs=packed.split_docs,
+                        pad_slots=pad_slots)
+        stats = self.stats
+        stats.pack_slots += tokens.size
+        stats.pack_pad_slots += pad_slots
+        stats.pack_chunks += packed.chunks
+        batch = {self._pack[0]: tokens, _PACK_TABLE: packed.table}
+        if self.last_batch == "pad":
+            batch["__valid__"] = np.arange(len(tokens)) < packed.rows_used
+        return batch
+
+    def _pack_layout(self, tokens, table):
+        """Launch ``jit_ptpu_pack_layout`` on the device tokens' chunk table: the
+        segment ids and positions, laid out like the tokens."""
+        field, length = self._pack
+        rows = tokens.shape[0]
+        fn = self._pack_programs.get(rows)
+        if fn is None:
+            from petastorm_tpu.ops.packing import layout_program
+
+            fn = self._pack_programs[rows] = layout_program(
+                rows, length, tokens.sharding if self.sharding is not None else None)
+        seg, pos = fn(table)
+        return {field + "_segment_ids": seg, field + "_positions": pos}
+
+    def _pack_layout_host(self, batch):
+        """The host twin of :meth:`_pack_layout`, for ``to_device=False``."""
+        from petastorm_tpu.ops.packing import layout_numpy
+
+        field, length = self._pack
+        seg, pos = layout_numpy(batch.pop(_PACK_TABLE), len(batch[field]), length)
+        batch[field + "_segment_ids"] = seg
+        batch[field + "_positions"] = pos
+        return batch
 
     def _put_batch(self, q, batch, hb=None, bp=None):
         """Producer put into the host queue, timed: blocking here is DOWNSTREAM
@@ -1685,6 +1834,7 @@ class DataLoader:
         if hb is not None:
             hb.beat("h2d")
         with _Stage(self, "h2d.transfer", batch=seq):
+            table = batch.pop(_PACK_TABLE, None) if self._pack is not None else None
             leases = take_leases(batch)
             device = {k: v for k, v in batch.items() if _is_device_dtype(v)}
             host = {k: v for k, v in batch.items() if k not in device}
@@ -1724,6 +1874,8 @@ class DataLoader:
                             copied += arr.nbytes
                     count_copy("h2d_owned_copy", copied)
             nbytes = sum(getattr(v, "nbytes", 0) for v in device.values())
+            if table is not None:
+                nbytes += table.nbytes
             with phase("h2d.put", batch=seq, bytes=nbytes):
                 if self.sharding is None:
                     arrays = jax.device_put(device)
@@ -1738,6 +1890,9 @@ class DataLoader:
                             arrays[name] = jax.make_array_from_process_local_data(s, arr)
                         else:
                             arrays[name] = jax.device_put(arr, s)
+                if table is not None:
+                    table = jax.device_put(
+                        table, _replicated_like(arrays[self._pack[0]].sharding))
             arrays.update(staged)
             arrays.update(inflated)
             if staging_lease is not None or leases:
@@ -1749,6 +1904,11 @@ class DataLoader:
                     staging_lease.release()
                 for lease in leases:
                     lease.release()
+            if table is not None:
+                # after the wait: the layout reads no host buffer, and waiting on
+                # it would wait on every program queued on the device before it
+                with phase("pack.layout", batch=seq):
+                    arrays.update(self._pack_layout(arrays[self._pack[0]], table))
         return arrays, host
 
     def _apply_device_transform(self, arrays):
@@ -1857,6 +2017,8 @@ class DataLoader:
                             prev.release()
                         prev = batch if isinstance(batch, LeasedBatch) else None
                         batch = _materialize_passthrough(batch)
+                        if self._pack is not None:
+                            batch = self._pack_layout_host(batch)
                         self._advance_consumed(_batch_valid_rows(batch))
                         if self._prov_rec is not None:
                             self._prov_rec.batch_delivered()
@@ -2110,6 +2272,13 @@ class DataLoader:
         arbitrarily long, so no row-group watermark short of the epoch boundary is
         correct — checkpoint those at epoch ends via ``Reader.state_dict()``.
         """
+        if self._pack is not None:
+            raise ValueError(
+                "DataLoader.state_dict() is not available with pack=: the batch "
+                "being packed holds chunks of documents from deliveries the "
+                "reader already counts as consumed, and that carried state is not "
+                "checkpointed yet. Checkpoint at an epoch boundary with "
+                "Reader.state_dict() instead.")
         if self._shuffling_queue_capacity or self._device_shuffle_capacity:
             raise ValueError(
                 "DataLoader.state_dict() is not available with shuffling enabled "
@@ -2233,6 +2402,9 @@ class DataLoader:
             # outliving this loader serves decoded batches again
             self.reader.keep_passthrough = False
             self._adopted_passthrough = False
+        if self._adopted_ragged:
+            self.reader.keep_ragged = False
+            self._adopted_ragged = False
         if self._staging is not None:
             self._staging.close()
             self._staging = None
@@ -2304,7 +2476,8 @@ def _pad_ragged_columns(columns, pad_shapes):
     """Zero-pad ragged tensor columns to their declared max shape + a validity mask.
 
     Runs in the producer (before shuffling/batching) so downstream stages only ever
-    see static shapes."""
+    see static shapes. 1-D rows pad with one vectorized scatter of their joined
+    values; rows of higher rank pad one at a time."""
     columns = dict(columns)
     for name, target in pad_shapes.items():
         col = columns.get(name)
@@ -2315,27 +2488,93 @@ def _pad_ragged_columns(columns, pad_shapes):
                 columns[name + "__mask"] = np.ones(col.shape, dtype=bool)
                 continue
             col = list(col)  # uniform but below max: pad like the ragged case
-        rows = [np.asarray(r) for r in col]
         target = tuple(target)
-        out = np.zeros((len(rows),) + target, dtype=rows[0].dtype if rows else np.float64)
-        mask = np.zeros((len(rows),) + target, dtype=bool)
-        for i, r in enumerate(rows):
-            if r.ndim != len(target):
-                raise ValueError(
-                    "pad_shapes[%r]=%r has rank %d but row %d has rank %d"
-                    % (name, target, len(target), i, r.ndim)
-                )
-            if any(a > t for a, t in zip(r.shape, target)):
+        rows = [np.asarray(r) for r in col]
+        dtype = rows[0].dtype if rows else np.float64
+        if len(target) == 1 and all(r.ndim == 1 for r in rows):
+            lengths = np.fromiter((len(r) for r in rows), np.int64, len(rows))
+            over = np.flatnonzero(lengths > target[0])
+            if len(over):
+                i = int(over[0])
                 raise ValueError(
                     "Row %d of field %r has shape %r exceeding declared pad max %r"
-                    % (i, name, r.shape, target)
-                )
-            region = tuple(slice(0, s) for s in r.shape)
-            out[i][region] = r
-            mask[i][region] = True
+                    % (i, name, (int(lengths[i]),), target))
+            mask = np.arange(target[0])[None, :] < lengths[:, None]
+            out = np.zeros((len(rows),) + target, dtype=dtype)
+            if rows:
+                out[mask] = np.concatenate(rows)
+        else:
+            out = np.zeros((len(rows),) + target, dtype=dtype)
+            mask = np.zeros((len(rows),) + target, dtype=bool)
+            for i, r in enumerate(rows):
+                if r.ndim != len(target):
+                    raise ValueError(
+                        "pad_shapes[%r]=%r has rank %d but row %d has rank %d"
+                        % (name, target, len(target), i, r.ndim)
+                    )
+                if any(a > t for a, t in zip(r.shape, target)):
+                    raise ValueError(
+                        "Row %d of field %r has shape %r exceeding declared pad max %r"
+                        % (i, name, r.shape, target)
+                    )
+                region = tuple(slice(0, s) for s in r.shape)
+                out[i][region] = r
+                mask[i][region] = True
         columns[name] = out
         columns[name + "__mask"] = mask
     return columns
+
+
+def _resolve_pack(pack, shuffling_queue_capacity, device_shuffle_capacity, pad_shapes,
+                  split_over_processes):
+    """``DataLoader(pack=...)`` as ``(field, length)``, or None; raises for a
+    malformed spec and for the options packing cannot be combined with."""
+    if pack is None:
+        return None
+    if not isinstance(pack, dict) or set(pack) != {"field", "length"}:
+        raise ValueError("pack= takes {'field': <list column>, 'length': <tokens a "
+                         "row>}, got %r" % (pack,))
+    field, length = pack["field"], pack["length"]
+    if not isinstance(field, str) or isinstance(length, bool) \
+            or not isinstance(length, (int, np.integer)) or length <= 0:
+        raise ValueError("pack= needs a field name and a positive int length, got %r"
+                         % (pack,))
+    if shuffling_queue_capacity:
+        raise ValueError(
+            "pack= cannot be combined with shuffling_queue_capacity: the shuffling "
+            "buffer holds documents back and releases them out of order, while the "
+            "packer places the stream's documents in order and carries a part-filled "
+            "batch between deliveries. Shuffle row groups in the reader "
+            "(shuffle_row_groups=True) instead.")
+    if device_shuffle_capacity:
+        raise ValueError(
+            "pack= cannot be combined with device_shuffle_capacity: the HBM exchange "
+            "shuffle trades rows between batches, which undoes the placement order "
+            "of the packed stream. Shuffle row groups in the reader instead.")
+    if field in pad_shapes:
+        raise ValueError(
+            "pack= and pad_shapes both name %r: pad_shapes pads each document alone "
+            "to a max shape, pack= packs the documents into shared rows. Choose one."
+            % field)
+    if split_over_processes:
+        raise ValueError(
+            "pack= packs in one process: under a sharding whose batch axis spans "
+            "processes each process would pack its own rows and lay out only its own "
+            "chunk table")
+    return field, int(length)
+
+
+def _replicated_like(sharding):
+    """Where a packed batch's chunk table goes: every device of the tokens'
+    ``sharding`` (a ``NamedSharding`` or one device)."""
+    import jax.sharding as jsh
+
+    if isinstance(sharding, jsh.NamedSharding):
+        return jsh.NamedSharding(sharding.mesh, jsh.PartitionSpec())
+    if len(sharding.device_set) == 1:
+        return sharding
+    raise ValueError("pack= lays out batches under a NamedSharding or on one device, "
+                     "not under %s" % type(sharding).__name__)
 
 
 def _resolve_local_batch(batch_size, sharding):

@@ -473,3 +473,65 @@ def jpeg_decode_coeffs_native(data):
         return out.height, out.width, comps
     finally:
         lib.ptpu_jpeg_free_coeffs(ctypes.byref(out))
+
+
+_PACK_LIB = None
+_PACK_ERR = None
+
+
+def _load_pack():
+    """The sequence packer (packing.cpp), a shared object of its own, or None."""
+    global _PACK_LIB, _PACK_ERR
+    if _PACK_LIB is not None or _PACK_ERR is not None:
+        return _PACK_LIB
+    with _LOCK:
+        if _PACK_LIB is not None or _PACK_ERR is not None:
+            return _PACK_LIB
+        if os.environ.get("PETASTORM_TPU_DISABLE_NATIVE"):
+            _PACK_ERR = "disabled via PETASTORM_TPU_DISABLE_NATIVE"
+            return None
+        try:
+            path = _compile([os.path.join(_SRC_DIR, "packing.cpp")], "ptpu_packing")
+            lib = ctypes.CDLL(path)
+            lib.ptpu_pack_place.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64)]
+            lib.ptpu_pack_place.restype = ctypes.c_int64
+            _PACK_LIB = lib
+        except Exception as e:  # noqa: BLE001 — degrade to the numpy packer
+            _PACK_ERR = str(e)
+            logger.warning("Native packer unavailable (%s); using numpy", e)
+        return _PACK_LIB
+
+
+def pack_native_available():
+    """True when the native packer loaded (builds it on first call)."""
+    return _load_pack() is not None
+
+
+def pack_place_native(values, src, lens, i, fill, tokens, table, count):
+    """First-fit placement of chunks ``[i, len(src))`` into one packed batch, in one
+    GIL-free call (``ptpu_pack_place``): ``values`` int32, ``src``/``lens`` int64,
+    ``fill`` int32 ``[rows]``, ``tokens`` int32 ``[rows * length]``, ``table`` int32
+    ``[rows * length, 3]``, all C-contiguous and updated in place; ``count`` (an
+    ``int``) is the table's used length. The caller has checked each chunk's bounds
+    (``0 < lens <= length``, ``src + lens <= len(values)``) once for the whole
+    delivery. Returns ``(next chunk, new count)``; the next chunk is below
+    ``len(src)`` when the batch is full."""
+    lib = _load_pack()
+    if lib is None:
+        raise RuntimeError("native packer unavailable: %s" % _PACK_ERR)
+    rows = len(fill)
+    length = len(tokens) // rows
+    for arr, dt in ((values, "int32"), (src, "int64"), (lens, "int64"), (fill, "int32"),
+                    (tokens, "int32"), (table, "int32")):
+        if arr.dtype != dt or not arr.flags.c_contiguous:
+            raise ValueError("pack_place_native needs C-contiguous %s arrays" % dt)
+    if len(src) != len(lens) or table.shape != (rows * length, 3) or not 0 <= i <= len(src):
+        raise ValueError("pack_place_native: inconsistent shapes")
+    n = ctypes.c_int64(count)
+    nxt = lib.ptpu_pack_place(values.ctypes.data, src.ctypes.data, lens.ctypes.data, i,
+                              len(src), rows, length, fill.ctypes.data, tokens.ctypes.data,
+                              table.ctypes.data, ctypes.byref(n))
+    return int(nxt), int(n.value)

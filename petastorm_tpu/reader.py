@@ -48,6 +48,7 @@ from petastorm_tpu.metadata import (
     load_row_groups,
 )
 from petastorm_tpu.ngram import NGram
+from petastorm_tpu.ops.packing import RaggedColumn
 from petastorm_tpu.plan import EpochPlan, shard_indices
 from petastorm_tpu.recovery import (
     QuarantinedItem,
@@ -1238,6 +1239,14 @@ class ArrowWorker(_WorkerBase):
     def __init__(self, *args, ngram=None, **kwargs):
         super().__init__(*args, **kwargs)
         self._ngram = ngram
+        #: deliver ragged numeric list columns as RaggedColumn values (set through
+        #: ``Reader.keep_ragged`` by a packing DataLoader; in-process pools only)
+        self.ragged_columnar = False
+
+    def __getstate__(self):
+        state = super().__getstate__()
+        state["ragged_columnar"] = False  # a pool child sends per-row arrays
+        return state
 
     def __call__(self, item):
         piece, _partition = item
@@ -1323,12 +1332,16 @@ class ArrowWorker(_WorkerBase):
             indices = np.intersect1d(indices, keep)
         if len(indices) < table.num_rows:
             table = table.take(indices)
+        # a host transform or an NGram window reads rows: they get per-row arrays
+        spec = self._transform_spec
+        ragged = self.ragged_columnar and self._ngram is None and (
+            spec is None or spec.device)
         out = {}
         for name in wanted:
             if name in table.column_names:
                 try:
                     out[name] = _column_to_numpy(table, name, self._read_schema,
-                                                 self._device_fields)
+                                                 self._device_fields, ragged)
                 except DecodeFieldError as e:
                     raise _annotate_decode_error(e, piece) from e
                 except Exception as e:  # noqa: BLE001 — decode_row contract below
@@ -1358,14 +1371,18 @@ def _merge_tables(head, tail):
     return pa.table(cols)
 
 
-def _column_to_numpy(table, name, schema, device_fields=()):
+def _column_to_numpy(table, name, schema, device_fields=(), ragged_columnar=False):
     """Arrow column → numpy array; decodes codec columns, stacks list columns.
 
     List columns take the vectorized path: flatten the Arrow child buffer straight to
     numpy and reshape — ``to_pylist`` would materialize every element as a Python object
-    (~100x slower on image-sized rows, the data-plane hot loop). Codec columns named in
-    ``device_fields`` run only the host half of the two-stage decode and come back as an
-    object array of staging payloads the JAX loader finishes on device."""
+    (~100x slower on image-sized rows, the data-plane hot loop). A ragged numeric list
+    column without nulls keeps Arrow's child values and offsets: a
+    :class:`~petastorm_tpu.ops.packing.RaggedColumn` when ``ragged_columnar``, else
+    the object array of per-row arrays (views of one copy of the values). Codec
+    columns named in ``device_fields`` run only the host half of the two-stage decode
+    and come back as an object array of staging payloads the JAX loader finishes on
+    device."""
     import pyarrow as pa
 
     col = table.column(name)
@@ -1412,6 +1429,9 @@ def _column_to_numpy(table, name, schema, device_fields=()):
         stacked = _list_column_to_numpy(arr, field)
         if stacked is not None:
             return stacked
+        ragged = _ragged_list_column(arr, field)
+        if ragged is not None:
+            return ragged if ragged_columnar else ragged.to_rows()
         return _stack(arr.to_pylist(), field)
     return col.to_numpy(zero_copy_only=False)
 
@@ -1503,6 +1523,26 @@ def _list_column_to_numpy(arr, field):
     if np_dtype.kind in "biufc" and out.dtype != np_dtype:
         out = out.astype(np_dtype)
     return out
+
+
+def _ragged_list_column(arr, field):
+    """A ragged ``list``/``large_list`` column of numbers with no nulls, as a
+    :class:`~petastorm_tpu.ops.packing.RaggedColumn` over Arrow's child values (zero
+    copy unless the field's dtype differs) and offsets. None when it is not one."""
+    import pyarrow as pa
+
+    if not (pa.types.is_list(arr.type) or pa.types.is_large_list(arr.type)):
+        return None
+    child = arr.values
+    if arr.null_count or child.null_count:
+        return None
+    values = child.to_numpy(zero_copy_only=False)
+    if values.dtype.kind not in "biufc":
+        return None
+    np_dtype = np.dtype(field.numpy_dtype)
+    if np_dtype.kind in "biufc" and values.dtype != np_dtype:
+        values = values.astype(np_dtype)
+    return RaggedColumn(values, arr.offsets.to_numpy(zero_copy_only=False))
 
 
 def _stack(values, field):
@@ -1801,6 +1841,7 @@ class Reader:
         #: the DataLoader sets True and finishes the inflate itself (on
         #: device when a non-CPU backend is live)
         self.keep_passthrough = False
+        self._keep_ragged = False
         #: lease of the CURRENT batch/row-buffer on a view-mode wire — held
         #: until the consumer asks for the next batch (or calls release_batch()
         #: / takes ownership via take_lease())
@@ -2097,6 +2138,22 @@ class Reader:
             return out
         return self._row_type(**{name: row.get(name) for name in self.schema.fields})
 
+    @property
+    def keep_ragged(self):
+        """Whether ragged numeric list columns are delivered columnar, as
+        :class:`~petastorm_tpu.ops.packing.RaggedColumn` values (one ``values`` and
+        one ``offsets`` array a delivery). False (the default) delivers object
+        arrays of per-row arrays. A packing ``DataLoader`` sets it True; in-process
+        pools then skip the per-row arrays, while a process pool's children still
+        send them over the wire (the packer takes both)."""
+        return self._keep_ragged
+
+    @keep_ragged.setter
+    def keep_ragged(self, on):
+        self._keep_ragged = bool(on)
+        if hasattr(self._worker, "ragged_columnar"):
+            self._worker.ragged_columnar = self._keep_ragged
+
     def _next_batch(self):
         # previous batch's slab (shm view wire) returns to the ring: a batch's
         # views stay valid until the consumer asks for the NEXT batch
@@ -2133,6 +2190,10 @@ class Reader:
                 from petastorm_tpu.io.pagedec import materialize_columns
 
                 columns = materialize_columns(columns)
+            if not self._keep_ragged:
+                # a delivery made while a packing loader held the reader
+                columns = {k: v.to_rows() if isinstance(v, RaggedColumn) else v
+                           for k, v in columns.items()}
             if self.ngram is not None:
                 # flat 'offset/field' window columns cannot be namedtuple
                 # attributes — batched NGram delivers plain dicts

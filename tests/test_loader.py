@@ -287,7 +287,9 @@ def test_stats_populate_through_device_path(scalar_dataset):
                          "decode_unsharded_batches", "shm_slabs_in_flight",
                          "shm_bytes", "shm_fallbacks", "shm_acquire_wait_s",
                          "decode_pack_s", "decode_put_s", "decode_launch_s",
-                         "h2d_stage_s", "h2d_put_s", "h2d_wait_s", "h2d_bytes"}
+                         "h2d_stage_s", "h2d_put_s", "h2d_wait_s", "h2d_bytes",
+                         "pack_s", "pack_slots", "pack_pad_slots", "pack_chunks"}
+    assert snap["pack_s"] == 0 and snap["pack_chunks"] == 0  # no pack=: nothing packed
     assert snap["decode_unsharded_batches"] == 0  # no sharding configured → no fallback
     assert snap["h2d_bytes"] > 0  # the columns' device_put phase counted its bytes
     assert snap["read_s"] >= 0 and snap["device_queue_wait_s"] >= 0

@@ -152,6 +152,57 @@ def test_ragged_list_column_stays_object(tmp_path_factory):
     assert len(r[2]) == 0
 
 
+class _NoPylist:
+    """An Arrow array whose ``to_pylist`` raises (pyarrow's own types are
+    immutable, so the reader is handed this stand-in instead)."""
+
+    def __init__(self, arr):
+        self._arr = arr
+
+    def to_pylist(self):
+        raise AssertionError("to_pylist on the ragged path")
+
+    def __getattr__(self, name):
+        return getattr(self._arr, name)
+
+
+class _NoPylistTable:
+    def __init__(self, table):
+        self._table = table
+
+    def column(self, name):
+        return _NoPylist(self._table.column(name).combine_chunks())
+
+
+@pytest.mark.parametrize("list_type", [pa.list_(pa.int32()), pa.large_list(pa.int32())])
+@pytest.mark.parametrize("columnar", [True, False])
+def test_ragged_list_column_keeps_values_and_offsets(tmp_path_factory, list_type,
+                                                     columnar):
+    """A ragged numeric list column reaches the worker's output as Arrow's child
+    values and offsets (a RaggedColumn when asked for, else per-row views of them),
+    never through ``to_pylist``; a sliced column keeps its offsets absolute."""
+    from petastorm_tpu.ops.packing import RaggedColumn
+    from petastorm_tpu.reader import _column_to_numpy
+
+    docs = [[5, 6, 7], [], [8], [9, 10, 11, 12], [13, 14]]
+    table = pa.table({"input_ids": pa.array(docs, list_type)})
+    url = _write(tmp_path_factory.mktemp("ragged_cols"), table)
+    with make_batch_reader(url, num_epochs=1) as reader:
+        schema = reader.schema
+    sliced = table.slice(1, 4)
+    got = _column_to_numpy(_NoPylistTable(sliced), "input_ids", schema,
+                           ragged_columnar=columnar)
+    arr = sliced.column("input_ids").combine_chunks()
+    if columnar:
+        assert isinstance(got, RaggedColumn)
+        np.testing.assert_array_equal(got.offsets, arr.offsets.to_numpy())
+        np.testing.assert_array_equal(got.values, arr.values.to_numpy())
+    else:
+        assert got.dtype == object
+        assert [r.tolist() for r in got] == docs[1:5]
+        assert all(r.dtype == np.int32 for r in got)
+
+
 def test_multi_rowgroup_store_reads_all(tmp_path_factory, typed_table):
     url = _write(tmp_path_factory.mktemp("rg"), typed_table, row_group_size=2)
     got = _read_all(url, workers_count=2, reader_pool_type="thread")
