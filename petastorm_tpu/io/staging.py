@@ -74,6 +74,70 @@ def device_put_aliases_host():
     return _alias_probe
 
 
+def owns_memory(arr):
+    """True when numpy allocated the memory ``arr`` reads (``arr``, or the array
+    it views, owns its data). No ring, arena or staging pool recycles such
+    memory, and ``device_put`` keeps a reference to it until its transfer ends.
+    A view into a slab, a shared segment or any other foreign buffer is
+    borrowed."""
+    while isinstance(arr.base, np.ndarray):
+        arr = arr.base
+    return arr.flags.owndata
+
+
+#: per thread, the list :func:`put_program_inputs` adds the device arrays of
+#: borrowed inputs to while :func:`watch_borrowed_inputs` is open
+_borrowed = threading.local()
+
+
+class watch_borrowed_inputs:
+    """While open on this thread, :func:`put_program_inputs` minds inputs put
+    from borrowed memory; the ``as`` target is the list it adds their device
+    arrays to, for the caller to wait on before the borrowed buffers go back to
+    their owners. ``active=False`` (nothing borrowed can be recycled) watches
+    nothing and gives an empty tuple."""
+
+    __slots__ = ("held",)
+
+    def __init__(self, active=True):
+        self.held = [] if active else None
+
+    def __enter__(self):
+        _borrowed.held = self.held
+        return self.held if self.held is not None else ()
+
+    def __exit__(self, *exc):
+        _borrowed.held = None
+        return False
+
+
+def put_program_inputs(tree, put):
+    """``put(tree)``, a ``device_put`` of a device program's host inputs, minding
+    borrowed memory while :func:`watch_borrowed_inputs` is open on this thread.
+    An input numpy did not allocate (:func:`owns_memory`) is copied owned first
+    where ``device_put`` aliases host memory (its device array would otherwise
+    be the recycled buffer); elsewhere its device array joins the watch. The
+    program's outputs never need a wait: its inputs are then owned or waited
+    on."""
+    held = getattr(_borrowed, "held", None)
+    if held is None:
+        return put(tree)
+    import jax
+
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    borrowed = [isinstance(a, np.ndarray) and not owns_memory(a) for a in leaves]
+    if not any(borrowed):
+        return put(tree)
+    if device_put_aliases_host():
+        count_copy("h2d_owned_copy", sum(a.nbytes for a, b in zip(leaves, borrowed)
+                                         if b))
+        return put(jax.tree_util.tree_unflatten(
+            treedef, [a.copy() if b else a for a, b in zip(leaves, borrowed)]))
+    out = put(tree)
+    held.extend(d for d, b in zip(jax.tree_util.tree_leaves(out), borrowed) if b)
+    return out
+
+
 def _try_mlock(buf, nbytes):
     """Page-lock ``buf`` via libc ``mlock``; False (with a warn-once
     degradation) when the platform or RLIMIT_MEMLOCK refuses."""

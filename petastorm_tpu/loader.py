@@ -36,6 +36,7 @@ import numpy as np
 
 from petastorm_tpu.io.lease import (LeasedBatch, attach_leases, count_copy,
                                     take_leases)
+from petastorm_tpu.io.staging import watch_borrowed_inputs
 from petastorm_tpu.shuffle import BatchedRandomShufflingBuffer
 from petastorm_tpu.trace import phase, profiler_span, set_phase_sink
 from petastorm_tpu.utils import stack_as_column
@@ -84,6 +85,12 @@ class PipelineStats:
     ``h2d_stage_s`` (the pinned staging copy), ``h2d_put_s`` (the columns'
     ``device_put``s), ``h2d_wait_s`` (the wait for the transfers before host
     buffers go back to their rings). ``h2d_bytes`` counts what both puts shipped.
+    The wait blocks only on transfers that read a host buffer about to be
+    recycled: the columns' ``device_put`` (from the staging slab or leased
+    views) and any device program's input put from borrowed memory, never a
+    program's output. ``h2d_unwaited_outputs`` counts the outputs of stage-2
+    and of the pass-through inflate handed on without a host wait (one a
+    batch for each decoded field; 0 without device decode).
 
     Under ``DataLoader(pack=...)``: ``pack_s`` is producer time packing
     documents into rows (chunking, placement, the token copy); ``pack_slots``,
@@ -117,7 +124,8 @@ class PipelineStats:
                  "queue_wait_s", "device_queue_wait_s", "decode_unsharded_batches",
                  "shm_slabs_in_flight", "shm_bytes", "shm_fallbacks",
                  "shm_acquire_wait_s") + tuple(_PHASES.values()) + (
-                     "h2d_bytes", "pack_s", "pack_slots", "pack_pad_slots", "pack_chunks")
+                     "h2d_bytes", "h2d_unwaited_outputs", "pack_s", "pack_slots",
+                     "pack_pad_slots", "pack_chunks")
 
     def __init__(self):
         self.reset()
@@ -1826,16 +1834,19 @@ class DataLoader:
             # host batches flow to this thread strictly FIFO: advance the
             # recorder's transfer pointer to this batch's provenance
             self._prov_rec.transfer_next()
+        leases = take_leases(batch)
+        # the inflate's inputs are fresh copies of the pages, and it returns
+        # only once its programs have run (it reads their ok flags)
         batch, inflated = self._inflate_passthrough(batch)
         if hb is not None:
             hb.beat("decode")
-        with _Stage(self, "decode.dispatch", batch=seq):
+        with _Stage(self, "decode.dispatch", batch=seq), \
+                watch_borrowed_inputs(bool(leases)) as borrowed:
             batch, staged = self._decode_staged(batch)
         if hb is not None:
             hb.beat("h2d")
         with _Stage(self, "h2d.transfer", batch=seq):
             table = batch.pop(_PACK_TABLE, None) if self._pack is not None else None
-            leases = take_leases(batch)
             device = {k: v for k, v in batch.items() if _is_device_dtype(v)}
             host = {k: v for k, v in batch.items() if k not in device}
             for name, arr in host.items():
@@ -1893,13 +1904,19 @@ class DataLoader:
                 if table is not None:
                     table = jax.device_put(
                         table, _replicated_like(arrays[self._pack[0]].sharding))
+            waited = list(arrays.values()) + list(borrowed)
             arrays.update(staged)
             arrays.update(inflated)
+            self.stats.h2d_unwaited_outputs += len(staged) + len(inflated)
             if staging_lease is not None or leases:
-                # the H2D copy may still be reading the source buffers (device_put
-                # is async): wait for it before the slabs go back to their rings
-                with phase("h2d.wait", batch=seq):
-                    jax.block_until_ready(arrays)
+                # device_put is async: wait for the transfers that may still read
+                # a buffer about to go back to its ring (the columns' put from
+                # the slab or leased views, a program's input put from borrowed
+                # memory), and on no program's output: that wait would wait on
+                # every program queued on the device before it (the running step)
+                if waited:
+                    with phase("h2d.wait", batch=seq, arrays=len(waited)):
+                        jax.block_until_ready(waited)
                 if staging_lease is not None:
                     staging_lease.release()
                 for lease in leases:

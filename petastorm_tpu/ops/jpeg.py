@@ -933,12 +933,18 @@ def _stage_inputs(tree, sharding, n):
     next batch's transfer overlaps the current batch's decode and the jit receives
     device-resident buffers (donation evaluated and rejected: see
     ``_batched_stage2``). Returns ``(tree, spmd)``: ``spmd`` is the sharding
-    the stage-2 program must run under, None when it runs on one device."""
+    the stage-2 program must run under, None when it runs on one device.
+
+    The put goes through :func:`~petastorm_tpu.io.staging.put_program_inputs`,
+    so a loader decoding a leased batch learns which inputs read borrowed
+    memory (only the no-native :func:`stack_jpeg_coefficients` slices can)."""
     import jax
+
+    from petastorm_tpu.io.staging import put_program_inputs
 
     shards = _batch_axis_shards(sharding) if sharding is not None else 0
     if shards <= 1 or n % shards != 0:
-        return jax.device_put(tree), None
+        return put_program_inputs(tree, jax.device_put), None
     import jax.sharding as jsh
 
     axis = sharding.spec[0]
@@ -948,7 +954,8 @@ def _stage_inputs(tree, sharding, n):
         return jax.device_put(a, jsh.NamedSharding(sharding.mesh, spec))
 
     spmd = jsh.NamedSharding(sharding.mesh, jsh.PartitionSpec(axis))
-    return jax.tree_util.tree_map(put, tree), spmd
+    return put_program_inputs(
+        tree, lambda t: jax.tree_util.tree_map(put, t)), spmd
 
 
 def _decode_group(layout, group, sharding=None):

@@ -288,9 +288,11 @@ def test_stats_populate_through_device_path(scalar_dataset):
                          "shm_bytes", "shm_fallbacks", "shm_acquire_wait_s",
                          "decode_pack_s", "decode_put_s", "decode_launch_s",
                          "h2d_stage_s", "h2d_put_s", "h2d_wait_s", "h2d_bytes",
+                         "h2d_unwaited_outputs",
                          "pack_s", "pack_slots", "pack_pad_slots", "pack_chunks"}
     assert snap["pack_s"] == 0 and snap["pack_chunks"] == 0  # no pack=: nothing packed
     assert snap["decode_unsharded_batches"] == 0  # no sharding configured → no fallback
+    assert snap["h2d_unwaited_outputs"] == 0  # no device decode: no program outputs
     assert snap["h2d_bytes"] > 0  # the columns' device_put phase counted its bytes
     assert snap["read_s"] >= 0 and snap["device_queue_wait_s"] >= 0
 
@@ -589,3 +591,154 @@ def test_multiprocess_inmem_guards(scalar_dataset, monkeypatch):
     finally:
         reader.stop()
         reader.join()
+
+
+# -- the transfer thread's wait: transfers that read recycled buffers, never outputs -----
+
+def _record_waits(monkeypatch):
+    """Wrap ``jax.block_until_ready`` as the loader calls it; returns the list of
+    the leaves of every call (the arrays themselves, kept alive)."""
+    import jax
+
+    waited = []
+    real = jax.block_until_ready
+
+    def recording(x):
+        waited.extend(jax.tree_util.tree_leaves(x))
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", recording)
+    return waited
+
+
+def _is_among(arr, arrays):
+    return any(arr is a for a in arrays)
+
+
+def test_h2d_wait_skips_stage2_outputs(tmp_path, monkeypatch):
+    """A device-decode store with a staging slab: the wait covers the columns'
+    ``h2d.put`` arrays (read from the slab) and no stage-2 output; every batch
+    hands its one decoded field on unwaited."""
+    pytest.importorskip("cv2")
+    from petastorm_tpu.io import staging
+    from test_common import create_test_jpeg_dataset
+
+    monkeypatch.setattr(staging, "_alias_probe", False)  # a copying backend
+    ds = create_test_jpeg_dataset("file://" + str(tmp_path / "ds"), num_rows=24)
+    waited = _record_waits(monkeypatch)
+    reader = make_batch_reader(ds.url, decode_on_device=True, num_epochs=1,
+                               shuffle_row_groups=False, workers_count=1)
+    with DataLoader(reader, 8, staging=True) as loader:
+        batches = list(loader)
+    assert len(batches) == 3
+    for batch in batches:
+        assert not _is_among(batch["image_jpeg"], waited)
+        assert _is_among(batch["id"], waited) and _is_among(batch["label"], waited)
+    assert len(waited) == 2 * len(batches)  # the two put columns, nothing else
+    snap = loader.stats.snapshot()
+    assert snap["h2d_unwaited_outputs"] == snap["batches"] == 3
+    assert snap["h2d_wait_s"] > 0
+
+
+def test_h2d_wait_set_unchanged_for_tabular_store(scalar_dataset, monkeypatch):
+    """A store with no device program waits on every column it puts, as before,
+    and hands no output on unwaited."""
+    import jax
+
+    from petastorm_tpu.io import staging
+
+    monkeypatch.setattr(staging, "_alias_probe", False)
+    waited = _record_waits(monkeypatch)
+    reader = make_batch_reader(scalar_dataset.url, num_epochs=1)
+    with DataLoader(reader, 5, staging=True) as loader:
+        batches = list(loader)
+    put = [v for b in batches for v in b.values() if isinstance(v, jax.Array)]
+    assert put and len(waited) == len(put)
+    assert all(_is_among(a, waited) for a in put)
+    assert loader.stats.snapshot()["h2d_unwaited_outputs"] == 0
+
+
+def _leased_jpeg_batch(url, n):
+    """The first ``n`` rows of a device-decode JPEG store as a leased batch whose
+    stage-1 coefficients and quantization tables live in one ring slab (rows
+    reference it as their row group's stacked buffers), the lease scribbling
+    the slab on release as a recycling ring would; with owned copies of the
+    same planes for a fresh decode."""
+    from petastorm_tpu.io.lease import Lease, LeasedBatch
+    from petastorm_tpu.ops.jpeg import JpegComponent, JpegPlanes
+
+    with make_batch_reader(url, decode_on_device=True, num_epochs=1,
+                           shuffle_row_groups=False, reader_pool_type="dummy") as r:
+        item = next(iter(r))
+    cols = item._asdict() if hasattr(item, "_asdict") else item
+    planes = [p.detach() for p in list(cols["image_jpeg"])[:n]]
+    ncomp = len(planes[0].components)
+    shapes = [c.blocks.shape for c in planes[0].components]
+    sizes = [n * int(np.prod(s)) * 2 for s in shapes] + [n * ncomp * 64 * 4]
+    slab = bytearray(sum(sizes))
+    offset, parents = 0, []
+    for c, s in enumerate(shapes):
+        parent = np.frombuffer(slab, np.int16, n * int(np.prod(s)), offset)
+        parent = parent.reshape(n, -1, 64)
+        parent[:] = np.stack([p.components[c].blocks.reshape(-1, 64) for p in planes])
+        parents.append(parent)
+        offset += sizes[c]
+    qtabs = np.frombuffer(slab, np.int32, n * ncomp * 64, offset).reshape(n, ncomp, 64)
+    qtabs[:] = [[c.qtable for c in p.components] for p in planes]
+    leased = np.empty(n, dtype=object)
+    parents = tuple(parents)
+    for i, p in enumerate(planes):
+        comps = [JpegComponent(parents[c][i].reshape(shapes[c]), qtabs[i, c],
+                               pc.h_samp, pc.v_samp)
+                 for c, pc in enumerate(p.components)]
+        leased[i] = JpegPlanes(p.height, p.width, comps,
+                               batch_ref=(parents, qtabs, i))
+    recycled = []
+
+    def recycle():
+        slab[:] = b"\x7f" * len(slab)
+        recycled.append(True)
+
+    batch = LeasedBatch({"id": np.array(cols["id"][:n]), "image_jpeg": leased},
+                        leases=[Lease(release_cb=recycle, kind="test_slab")])
+    return batch, planes, recycled
+
+
+@pytest.mark.parametrize("aliasing", [False, True])
+def test_stage2_inputs_from_leased_buffers_are_guarded(tmp_path, monkeypatch, aliasing):
+    """Stage-2 inputs that view a leased slab (the no-native packer slices the
+    rows' stacked buffers): a copying backend waits on their transfers before
+    the lease goes back, an aliasing one puts owned copies; either way the
+    delivered images equal a fresh decode after the slab was recycled, and the
+    stage-2 output is never waited on."""
+    pytest.importorskip("cv2")
+    from petastorm_tpu.io import staging
+    from petastorm_tpu.io.lease import copy_census
+    from petastorm_tpu.ops import native
+    from test_common import create_test_jpeg_dataset
+
+    ds = create_test_jpeg_dataset("file://" + str(tmp_path / "ds"), num_rows=24)
+    n = 6
+    batch, planes, recycled = _leased_jpeg_batch(ds.url, n)
+    monkeypatch.setattr(native, "native_available", lambda: False)
+    monkeypatch.setattr(staging, "_alias_probe", aliasing)
+    waited = _record_waits(monkeypatch)
+    reader = make_batch_reader(ds.url, decode_on_device=True, num_epochs=1)
+    copied = copy_census().get("h2d_owned_copy", 0)
+    with DataLoader(reader, n) as loader:
+        arrays, _host = loader._transfer_batch(batch)
+    assert recycled == [True]
+    ncomp = len(planes[0].components)
+    inputs = [a for a in waited if a.shape[0] == n and a.ndim >= 2]
+    if aliasing:
+        assert inputs == []  # owned copies need no wait
+        assert copy_census()["h2d_owned_copy"] - copied == sum(
+            n * c.blocks.size * 2 + n * 64 * 4 for c in planes[0].components)
+    else:
+        assert len(inputs) == 2 * ncomp  # each component's coefficients and qtables
+    assert not _is_among(arrays["image_jpeg"], waited)
+    assert loader.stats.h2d_unwaited_outputs == 1
+    field = reader.schema.fields["image_jpeg"]
+    fresh = field.codec.device_decode_batch(field, planes)
+    np.testing.assert_array_equal(np.asarray(arrays["image_jpeg"]), np.asarray(fresh))
+    np.testing.assert_array_equal(np.asarray(arrays["id"]), np.asarray(batch["id"]))
