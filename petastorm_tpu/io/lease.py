@@ -297,6 +297,38 @@ def readonly_view(value):
     return value
 
 
+def detach_slab_views(columns):
+    """Copy every zero-copy slab view out of a view-mode reader delivery before it
+    enters a buffering stage: top-level read-only ndarrays, read-only ELEMENTS of
+    object (ragged) columns, and staged payload objects exposing ``detach()`` —
+    all go stale when the Reader releases the batch's lease at its next fetch.
+
+    This is the fallback for buffering the lease cannot ride (shuffling buffers,
+    per-row readers, host-only columns handed to the consumer); the plain
+    batched path retains the delivery's lease instead of copying. Bytes copied
+    here are charged to the ``loader_detach`` census site."""
+    out = {}
+    copied = 0
+    for name, v in columns.items():
+        if isinstance(v, np.ndarray):
+            if v.dtype.hasobject:
+                fresh = np.empty(v.shape, dtype=object)
+                for idx, e in np.ndenumerate(v):
+                    if isinstance(e, np.ndarray) and not e.flags.writeable:
+                        copied += e.nbytes
+                        e = e.copy()
+                    elif hasattr(e, "detach"):
+                        e = e.detach()
+                    fresh[idx] = e
+                v = fresh
+            elif not v.flags.writeable:
+                copied += v.nbytes
+                v = v.copy()
+        out[name] = v
+    count_copy("loader_detach", copied)
+    return out
+
+
 # --------------------------------------------------------------------------------------
 # Copy census: ptpu_copy_bytes_total{site=...}
 # --------------------------------------------------------------------------------------

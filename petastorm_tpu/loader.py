@@ -27,27 +27,25 @@ import collections
 import functools
 import itertools
 import logging
-import os
 import queue
 import threading
 import time
+import weakref
 
 import numpy as np
 
 from petastorm_tpu.io.lease import (LeasedBatch, attach_leases, count_copy,
-                                    take_leases)
-from petastorm_tpu.io.staging import watch_borrowed_inputs
+                                    detach_slab_views, take_leases)
+from petastorm_tpu.parallel.mesh import batch_axis_shard_count
 from petastorm_tpu.shuffle import BatchedRandomShufflingBuffer
-from petastorm_tpu.trace import phase, profiler_span, set_phase_sink
+from petastorm_tpu.trace import profiler_span, set_phase_sink
+from petastorm_tpu.transfer import (_PACK_TABLE, DeviceTransfer, _is_device_dtype,
+                                    column_sharding)
 from petastorm_tpu.utils import stack_as_column
 
 logger = logging.getLogger(__name__)
 
 _SENTINEL = object()
-
-#: the host batch's key for a packed batch's chunk table (``pack=``): popped on
-#: the transfer thread, shipped beside the tokens, read by jit_ptpu_pack_layout
-_PACK_TABLE = "__pack_table__"
 
 #: per-process pipeline ids for health-scope namespacing: loaders SHARING one
 #: HealthMonitor must not share heartbeat slots (itertools.count is GIL-atomic)
@@ -99,9 +97,9 @@ class PipelineStats:
 
     ``decode_unsharded_batches`` counts staged-decode dispatches that ran on a
     SINGLE device although the configured sharding cuts the batch axis across
-    several — the undivisible-batch / local-mesh-derivation-failure / pre-``sharding``-
-    kwarg-codec fallbacks (VERDICT r4 #6). Nonzero on a pod means one chip is
-    decoding for many; fix the batch size / sharding / codec signature.
+    several — the undivisible-batch / local-mesh-derivation-failure fallbacks.
+    Nonzero on a pod means one chip is decoding for many; fix the batch size or
+    the sharding.
 
     The ``shm_*`` fields mirror the process pool's shared-memory wire gauges
     (``Reader.wire_stats()``, refreshed per reader delivery; all zero on thread/
@@ -254,8 +252,6 @@ class _LoaderObs:
     """
 
     def __init__(self, registry, loader):
-        import weakref
-
         self.registry = registry
         self._hists = {
             stage: registry.histogram(
@@ -332,11 +328,6 @@ class _LoaderObs:
         handles, self._handles = self._handles, []
         for handle in handles:
             self.registry.unregister_collector(handle)
-
-
-def _is_device_dtype(arr):
-    """Only numeric/bool fixed-shape columns can live on device; strings/objects stay host."""
-    return isinstance(arr, np.ndarray) and arr.dtype.kind in "biufc" and arr.dtype.hasobject is False
 
 
 def _validate_decode_resize(resize, device_fields):
@@ -508,38 +499,6 @@ def _batch_row_count(batch):
     if not batch:
         return 0
     return int(len(next(iter(batch.values()))))
-
-
-def _detach_slab_views(columns):
-    """Copy every zero-copy slab view out of a view-mode reader delivery before it
-    enters a buffering stage: top-level read-only ndarrays, read-only ELEMENTS of
-    object (ragged) columns, and staged payload objects exposing ``detach()`` —
-    all go stale when the Reader releases the batch's lease at its next fetch.
-
-    Since ISSUE 6 this is the FALLBACK path (shuffling buffers and per-row
-    readers, whose buffering the lease cannot ride); the plain batched path
-    retains the delivery's lease instead of copying. Bytes copied here are
-    charged to the ``loader_detach`` census site."""
-    out = {}
-    copied = 0
-    for name, v in columns.items():
-        if isinstance(v, np.ndarray):
-            if v.dtype.hasobject:
-                fresh = np.empty(v.shape, dtype=object)
-                for idx, e in np.ndenumerate(v):
-                    if isinstance(e, np.ndarray) and not e.flags.writeable:
-                        copied += e.nbytes
-                        e = e.copy()
-                    elif hasattr(e, "detach"):
-                        e = e.detach()
-                    fresh[idx] = e
-                v = fresh
-            elif not v.flags.writeable:
-                copied += v.nbytes
-                v = v.copy()
-        out[name] = v
-    count_copy("loader_detach", copied)
-    return out
 
 
 def _materialize_passthrough(batch, cause=None):
@@ -854,18 +813,15 @@ class DataLoader:
         self._shuffling_queue_capacity = shuffling_queue_capacity
         self._host_queue_size = host_queue_size
         self._pad_shapes = dict(pad_shapes) if pad_shapes else {}
-        #: (h, w) — or {field: (h, w)} — on-device resize target for device-decoded
-        #: image columns; lets mixed-size stores (raw ImageNet-style) batch with one
-        #: static shape (petastorm_tpu.ops.jpeg.resize_image_batch)
-        self._device_decode_resize = _validate_decode_resize(
+        # (h, w) or {field: (h, w)}: the on-device resize of device-decoded image
+        # columns, so that mixed-size stores batch with one static shape
+        resize = _validate_decode_resize(
             device_decode_resize, getattr(reader, "device_decode_fields", None))
         self._device_shuffle_capacity = int(device_shuffle_capacity or 0)
         #: (field, length) of sequence packing, or None (see the ``pack`` parameter)
         self._pack = _resolve_pack(pack, shuffling_queue_capacity,
                                    self._device_shuffle_capacity, self._pad_shapes,
                                    self.local_batch_size != self.batch_size)
-        #: jit_ptpu_pack_layout per row count (a partial last batch has fewer)
-        self._pack_programs = {}
         #: a packing loader takes ragged columns as values and offsets (no
         #: per-row arrays); un-adopted at __exit__
         self._adopted_ragged = False
@@ -924,12 +880,6 @@ class DataLoader:
         self._transform_step = 0
         #: (n) -> frozen (gather index, validity mask) for last_batch='pad'
         self._pad_cache = {}
-        #: pinned-host staging ring (io/staging.py), built lazily on the
-        #: transfer thread from the first staged batch's size; None until then
-        #: (and forever when disabled/refused — see the `staging` parameter)
-        self._staging_arg = staging
-        self._staging = None
-        self._staging_decided = False
         self._producer = None
         self._queue = None
         self._dev_queue = None
@@ -948,7 +898,15 @@ class DataLoader:
         #: host batches the producer has put this iteration, and the number of
         #: the one in transfer: the ``batch=`` of every stage's profiler span
         self._batches_put = self._batch_seq = 0
-        self._warned_unsharded_decode = False
+        #: host batch -> device arrays (the transfer thread's work); its stages
+        #: reach the loader through a proxy, so the loader stays free of cycles
+        #: and is freed as soon as its last reference goes
+        self._transfer = DeviceTransfer(
+            self.stats, functools.partial(_Stage, weakref.proxy(self)),
+            sharding=sharding, staging=staging, pack=self._pack,
+            schema=getattr(reader, "schema", None),
+            decode_fields=getattr(reader, "device_decode_fields", None) or (),
+            resize=resize)
         # consumer-watermark checkpointing (see state_dict): producer logs
         # (cumulative-delivered-rows, reader state) per delivery; the consumer
         # counts rows actually yielded; state_dict() returns the newest logged
@@ -983,8 +941,6 @@ class DataLoader:
         self._health, self._health_owned = normalize_health(health)
         self._health_scope = None
         if self._health is not None:
-            import weakref
-
             monitor = self._health
             if self._health_owned:
                 # exclusive monitor: bare actor names (loader.producer, ...)
@@ -1242,7 +1198,7 @@ class DataLoader:
                     )
                 lease = take_lease_fn() if lease_mode else None
                 if detach_views:
-                    columns = _detach_slab_views(columns)
+                    columns = detach_slab_views(columns)
                 if wire_stats_fn is not None:
                     stats.update_wire(wire_stats_fn())
                 if packer is not None:
@@ -1376,30 +1332,6 @@ class DataLoader:
             batch["__valid__"] = np.arange(len(tokens)) < packed.rows_used
         return batch
 
-    def _pack_layout(self, tokens, table):
-        """Launch ``jit_ptpu_pack_layout`` on the device tokens' chunk table: the
-        segment ids and positions, laid out like the tokens."""
-        field, length = self._pack
-        rows = tokens.shape[0]
-        fn = self._pack_programs.get(rows)
-        if fn is None:
-            from petastorm_tpu.ops.packing import layout_program
-
-            fn = self._pack_programs[rows] = layout_program(
-                rows, length, tokens.sharding if self.sharding is not None else None)
-        seg, pos = fn(table)
-        return {field + "_segment_ids": seg, field + "_positions": pos}
-
-    def _pack_layout_host(self, batch):
-        """The host twin of :meth:`_pack_layout`, for ``to_device=False``."""
-        from petastorm_tpu.ops.packing import layout_numpy
-
-        field, length = self._pack
-        seg, pos = layout_numpy(batch.pop(_PACK_TABLE), len(batch[field]), length)
-        batch[field + "_segment_ids"] = seg
-        batch[field + "_positions"] = pos
-        return batch
-
     def _put_batch(self, q, batch, hb=None, bp=None):
         """Producer put into the host queue, timed: blocking here is DOWNSTREAM
         backpressure (decode/transfer/step slower than the producer) — the
@@ -1489,7 +1421,7 @@ class DataLoader:
                 if arr.dtype == object:
                     # ...of the OUTER pointers only: ragged ELEMENTS may still
                     # be read-only views into a leased slab the release below
-                    # recycles — copy them owned (what _detach_slab_views does
+                    # recycles — copy them owned (what detach_slab_views does
                     # on the non-lease path)
                     for i, e in np.ndenumerate(gathered):
                         if isinstance(e, np.ndarray) and not e.flags.writeable:
@@ -1590,343 +1522,22 @@ class DataLoader:
             self._batch_seq = seq
             yield item
 
-    def _decode_staged(self, batch):
-        """Finish two-stage codec decode on device: staging-payload columns (e.g. JPEG
-        DCT coefficient planes produced by ``decode_on_device=True`` readers) become
-        device arrays via one batched codec dispatch per column."""
-        fields = getattr(self.reader, "device_decode_fields", None)
-        if not fields:
-            return batch, {}
-        import jax
-
-        batch = dict(batch)
-        decoded = {}
-        unsharded_fallback = False  # per-BATCH: any staged field fell back
-        for name in fields:
-            arr = batch.pop(name, None)
-            if arr is None:
-                continue
-            field = self.reader.schema.fields[name]
-            staged = list(arr)
-            if any(s is None for s in staged):
-                raise ValueError(
-                    "Field %r has null rows; nullable columns are not supported with "
-                    "decode_on_device (pad or filter nulls upstream)" % name
-                )
-            base_s = None
-            if self.sharding is not None:
-                base_s = self.sharding.get(name) \
-                    if isinstance(self.sharding, dict) else self.sharding
-            decode_s = _decode_sharding(base_s, len(staged)) \
-                if base_s is not None else None
-            rt = self._device_decode_resize
-            if isinstance(rt, dict):
-                rt = rt.get(name)
-            # sharding passed only when resolved AND the codec takes it:
-            # third-party codec subclasses predating the kwarg keep decoding
-            # single-device (their output is resharded below — the old behavior)
-            kwargs = {} if decode_s is None else {"sharding": decode_s}
-            probe = False
-            if "sharding" in kwargs:
-                support = _accepts_kwarg(field.codec.device_decode_batch,
-                                         "sharding")
-                if support is False:
-                    kwargs.pop("sharding")
-                elif support is None:
-                    # uninspectable callable (C-implemented / exotic wrapper):
-                    # the old behavior ASSUMED the legacy signature and
-                    # silently degraded to unsharded decode. Probe instead —
-                    # one try-call with the kwarg; its outcome is cached per
-                    # underlying callable so the probe runs once per process
-                    # (ISSUE 8 satellite, ADVICE round-5 loader.py:1145).
-                    probe = True
-            if rt is not None:
-                kwargs["resize_to"] = tuple(rt)
-            try:
-                out = field.codec.device_decode_batch(field, staged, **kwargs)
-                if probe:
-                    _record_probed_kwarg(field.codec.device_decode_batch,
-                                         "sharding", True)
-            except TypeError as e:
-                # only the probed kwarg's rejection is absorbable; the message
-                # check keeps a TypeError raised INSIDE a sharding-aware decode
-                # from being eaten (worst case the retry below re-raises it)
-                if not (probe and "sharding" in kwargs and "sharding" in str(e)):
-                    raise
-                _record_probed_kwarg(field.codec.device_decode_batch,
-                                     "sharding", False)
-                kwargs.pop("sharding")
-                out = field.codec.device_decode_batch(field, staged, **kwargs)
-            # Surface the single-device fallback (VERDICT r4 #6): the configured
-            # sharding cuts the batch axis across >1 device, but this decode ran
-            # on one (axis undivisible, local-mesh derivation failed, or the
-            # codec rejected the kwarg). Correct output either way — but on a pod
-            # host it silently makes one chip decode for all of them, so count it
-            # and warn once. Computed from the FINAL call shape, after the probe
-            # resolved. (Mixed-layout sub-groups smaller than the batch can
-            # still fall back inside the codec without being counted here; the
-            # whole-batch divisibility check mirrors the codec's own.)
-            want_shards = _batch_shard_count(base_s) if base_s is not None else 1
-            got_shards = _batch_shard_count(kwargs["sharding"]) \
-                if "sharding" in kwargs else 1
-            if want_shards > 1 and (
-                    got_shards <= 1 or len(staged) % got_shards != 0):
-                if not unsharded_fallback:  # once per batch, however many fields
-                    unsharded_fallback = True
-                    self.stats.decode_unsharded_batches += 1
-                if not self._warned_unsharded_decode:
-                    self._warned_unsharded_decode = True
-                    from petastorm_tpu.obs.log import degradation
-
-                    # once=False: the per-LOADER flag above already gates the
-                    # log (obs.log's own warn-once is per process, and two
-                    # loaders each deserve their one warning)
-                    degradation(
-                        "unsharded_decode",
-                        "Staged decode of field %r is running on a SINGLE device "
-                        "although its sharding splits the batch axis %d ways "
-                        "(batch rows=%d). Decode output is correct but unscaled; "
-                        "make the per-process batch divisible by the batch-axis "
-                        "shard count and use a codec whose device_decode_batch "
-                        "accepts the `sharding` kwarg. (Warned once; see "
-                        "PipelineStats.decode_unsharded_batches.)",
-                        name, want_shards, len(staged), once=False)
-            if self.sharding is not None:
-                s = self.sharding.get(name) if isinstance(self.sharding, dict) \
-                    else _matching_sharding(self.sharding, out)
-                if s is not None:
-                    if jax.process_count() > 1:
-                        # `out` is already device-resident (the decode just ran on
-                        # device). jax's process-local assembly slices it lazily and
-                        # places shards device-to-device, so passing the jax.Array
-                        # straight through keeps the decoded pixels on device —
-                        # np.asarray here would re-pay the full decoded-bytes D2H+H2D
-                        # the two-stage split exists to avoid (VERDICT r2 #3).
-                        out = jax.make_array_from_process_local_data(s, out)
-                    else:
-                        with phase("decode.launch"):  # the reshard
-                            out = jax.device_put(out, s)
-            decoded[name] = out
-        return batch, decoded
-
-    def _to_device(self, batch):
-        arrays, host = self._transfer_batch(batch)
-        arrays = self._apply_device_transform(arrays)
-        arrays.update(host)
-        return arrays
-
-    def _inflate_passthrough(self, batch):
-        """The device inflate stage of the compressed-page pass-through
-        (ISSUE 14): PassthroughColumn values → device arrays via the Pallas
-        kernels (:mod:`petastorm_tpu.ops.pagedec_kernels`) when a non-CPU
-        backend is live, the numpy reference twin otherwise (the decoded
-        array then rides the normal staging + ``device_put`` path). Returns
-        ``(batch_without_passthrough, {name: device array})``.
-
-        Accounting: pages and compressed/saved bytes land in the
-        ``ptpu_pagedec_*`` family — the compressed payload (plus the small
-        page tables) is what the pipeline carried in place of decoded
-        arrays: the pool-wire volume on every path, and the PCIe volume when
-        the DEVICE inflate runs. Columns that take the host fallback here
-        (CPU backend, sharded delivery, kernel bail) additionally count
-        ``ptpu_pagedec_host_inflate_columns_total`` — their H2D leg shipped
-        the decoded array, so the saved-bytes number covered the wire only.
-        The stage records a ``decode.device_inflate`` span (provenance +
-        trace + kernel-time histogram) so ``attribution_report()`` can blame
-        or exonerate it, and carries a chaos hook site of the same name for
-        synthetic kernel-slow injection."""
-        names = [name for name, v in batch.items()
-                 if getattr(v, "is_passthrough", False)]
-        if not names:
-            return batch, {}
-        import jax
-
-        from petastorm_tpu import chaos as _chaos
-        from petastorm_tpu.io.pagedec import pagedec_counters
-        from petastorm_tpu.ops import pagedec_kernels as pk
-
-        counters = pagedec_counters()
-        with _Stage(self, "decode.device_inflate", batch=self._batch_seq) as st:
-            if _chaos.ACTIVE is not None:
-                _chaos.ACTIVE.hit("decode.device_inflate")
-            # sharded delivery keeps the host path for now: the decoded array
-            # goes through the same sharded device_put as any other column
-            # (per-shard device inflate is the ROADMAP item-2 follow-up)
-            use_device = self.sharding is None and (
-                jax.default_backend() != "cpu"
-                or os.environ.get("PTPU_PAGEDEC_DEVICE", "") not in ("", "0"))
-            decoded = {}
-            for name in names:
-                col = batch.pop(name)
-                counters["pages"].inc(sum(
-                    (p1 - p0) + (1 if c.dict_page is not None else 0)
-                    for c, s, t in col.parts
-                    for p0, p1, _base in (c.covering_pages(s, t),)))
-                shipped = col.shipped_nbytes
-                counters["bytes_compressed"].inc(shipped)
-                counters["bytes_saved"].inc(max(0, col.raw_nbytes - shipped))
-                arr = None
-                if use_device:
-                    try:
-                        arr = pk.inflate_column(col)
-                    except pk.DeviceInflateError:
-                        arr = None  # host twin below validates + raises if corrupt
-                if arr is None:
-                    # CPU fallback / kernel bail: reference decode, normal H2D
-                    counters["host_inflate_columns"].inc()
-                    batch[name] = col.materialize()
-                else:
-                    decoded[name] = arr
-        counters["inflate_seconds"].observe(st.dt)
-        return batch, decoded
-
-    def _ensure_staging(self, device):
-        """Resolve (once) and return the pinned H2D staging pool, or None.
-
-        Decided lazily on the transfer thread from the first device-bound
-        batch: ``staging=None`` auto-enables on accelerator backends only;
-        ``True``/an int force it — but ANY mode is refused when this backend's
-        ``device_put`` aliases host memory (recycled slabs would corrupt
-        delivered arrays), with a ``staging_aliasing`` degradation."""
-        if self._staging_decided:
-            return self._staging
-        sizes = [v.nbytes for v in device.values() if isinstance(v, np.ndarray)]
-        if not sizes:
-            return None  # nothing stageable yet: decide on a later batch
-        self._staging_decided = True
-        arg = self._staging_arg
-        if arg is False:
-            return None
-        from petastorm_tpu.io.staging import (PinnedStagingPool, _STAGE_ALIGN,
-                                              device_put_aliases_host)
-
-        if arg is None:
-            import jax
-
-            if jax.default_backend() == "cpu" or device_put_aliases_host():
-                return None  # auto mode: pageable→pinned buys nothing on CPU
-        elif device_put_aliases_host():
-            from petastorm_tpu.obs.log import degradation
-
-            degradation(
-                "staging_aliasing",
-                "DataLoader(staging=%r) refused: this backend's device_put "
-                "ALIASES host numpy memory, so staging-slab reuse would "
-                "corrupt delivered batches; transferring from pageable "
-                "memory", arg)
-            return None
-        need = 0
-        for nbytes in sizes:
-            need = -(-need // _STAGE_ALIGN) * _STAGE_ALIGN + nbytes
-        slab_bytes = int(arg) if not isinstance(arg, bool) and arg is not None \
-            else need
-        self._staging = PinnedStagingPool(max(slab_bytes, need), num_slabs=2)
-        return self._staging
-
-    def _transfer_batch(self, batch):
-        """Staged decode + device_put with the configured sharding. Returns the device
-        arrays and the host-only (string/object) columns separately."""
-        import jax
-
-        hb = self._hb_transfer
-        seq = self._batch_seq
+    def _transfer_next(self, batch):
+        """The next host batch through the transfer stage: ``(device arrays,
+        host-only columns)``."""
         if self._prov_rec is not None:
             # host batches flow to this thread strictly FIFO: advance the
             # recorder's transfer pointer to this batch's provenance
             self._prov_rec.transfer_next()
-        leases = take_leases(batch)
-        # the inflate's inputs are fresh copies of the pages, and it returns
-        # only once its programs have run (it reads their ok flags)
-        batch, inflated = self._inflate_passthrough(batch)
-        if hb is not None:
-            hb.beat("decode")
-        with _Stage(self, "decode.dispatch", batch=seq), \
-                watch_borrowed_inputs(bool(leases)) as borrowed:
-            batch, staged = self._decode_staged(batch)
-        if hb is not None:
-            hb.beat("h2d")
-        with _Stage(self, "h2d.transfer", batch=seq):
-            table = batch.pop(_PACK_TABLE, None) if self._pack is not None else None
-            device = {k: v for k, v in batch.items() if _is_device_dtype(v)}
-            host = {k: v for k, v in batch.items() if k not in device}
-            for name, arr in host.items():
-                if isinstance(arr, np.ndarray) and arr.dtype == object and len(arr) \
-                        and isinstance(arr[0], (np.ndarray, list, tuple)):
-                    raise ValueError(
-                        "Field %r holds ragged tensors and cannot reach the device "
-                        "with a static shape. Declare DataLoader(pad_shapes={%r: "
-                        "(max_dims...)}) to zero-pad it (a %s__mask column marks the "
-                        "valid region)." % (name, name, name)
-                    )
-            if host:
-                logger.debug("Fields kept host-side (non-device dtypes): %s", sorted(host))
-                if leases:
-                    # host columns outlive this thread (they ride to the consumer
-                    # past the lease release below) — copy them out of the slabs
-                    host = _detach_slab_views(host)
-            staging_lease = None
-            pool = self._ensure_staging(device) if device else None
-            if pool is not None:
-                # one copy into a page-locked slab; device_put below DMAs straight
-                # from it (and the original — possibly leased — buffers are done)
-                with phase("h2d.stage", batch=seq):
-                    device, staging_lease = pool.stage(device)
-            elif leases:
-                from petastorm_tpu.io.staging import device_put_aliases_host
+        hb = self._hb_transfer
+        return self._transfer(batch, self._batch_seq,
+                              None if hb is None else hb.beat)
 
-                if device_put_aliases_host():
-                    # this backend's device_put ALIASES host numpy: transferring the
-                    # leased slab views directly would hand the consumer arrays into
-                    # memory the release below recycles — copy them owned first
-                    copied = 0
-                    for name, arr in list(device.items()):
-                        if isinstance(arr, np.ndarray) and not arr.flags.writeable:
-                            device[name] = arr.copy()
-                            copied += arr.nbytes
-                    count_copy("h2d_owned_copy", copied)
-            nbytes = sum(getattr(v, "nbytes", 0) for v in device.values())
-            if table is not None:
-                nbytes += table.nbytes
-            with phase("h2d.put", batch=seq, bytes=nbytes):
-                if self.sharding is None:
-                    arrays = jax.device_put(device)
-                else:
-                    arrays = {}
-                    for name, arr in device.items():
-                        s = self.sharding.get(name) if isinstance(self.sharding, dict) \
-                            else _matching_sharding(self.sharding, arr)
-                        if s is None:  # a field without a sharding (e.g. __valid__)
-                            arrays[name] = jax.device_put(arr)
-                        elif jax.process_count() > 1:
-                            arrays[name] = jax.make_array_from_process_local_data(s, arr)
-                        else:
-                            arrays[name] = jax.device_put(arr, s)
-                if table is not None:
-                    table = jax.device_put(
-                        table, _replicated_like(arrays[self._pack[0]].sharding))
-            waited = list(arrays.values()) + list(borrowed)
-            arrays.update(staged)
-            arrays.update(inflated)
-            self.stats.h2d_unwaited_outputs += len(staged) + len(inflated)
-            if staging_lease is not None or leases:
-                # device_put is async: wait for the transfers that may still read
-                # a buffer about to go back to its ring (the columns' put from
-                # the slab or leased views, a program's input put from borrowed
-                # memory), and on no program's output: that wait would wait on
-                # every program queued on the device before it (the running step)
-                if waited:
-                    with phase("h2d.wait", batch=seq, arrays=len(waited)):
-                        jax.block_until_ready(waited)
-                if staging_lease is not None:
-                    staging_lease.release()
-                for lease in leases:
-                    lease.release()
-            if table is not None:
-                # after the wait: the layout reads no host buffer, and waiting on
-                # it would wait on every program queued on the device before it
-                with phase("pack.layout", batch=seq):
-                    arrays.update(self._pack_layout(arrays[self._pack[0]], table))
-        return arrays, host
+    def _to_device(self, batch):
+        arrays, host = self._transfer_next(batch)
+        arrays = self._apply_device_transform(arrays)
+        arrays.update(host)
+        return arrays
 
     def _apply_device_transform(self, arrays):
         if self._device_transform is None:
@@ -1970,23 +1581,16 @@ class DataLoader:
             return
         from petastorm_tpu.ops.device_shuffle import DeviceShuffleBuffer
 
-        def _ring_sharding(name, arr):
-            # lay the ring out like the batches (capacity axis where the batch axis
-            # is), so the resident rows split across devices instead of replicating
-            if self.sharding is None:
-                return None
-            s = self.sharding.get(name) if isinstance(self.sharding, dict) \
-                else _matching_sharding(self.sharding, arr)
-            return s
-
-        shuffler = DeviceShuffleBuffer(self._device_shuffle_capacity,
-                                       seed=self._seed or 0,
-                                       shardings=_ring_sharding)
+        # the ring is laid out like the batches (capacity axis where the batch
+        # axis is), so the resident rows split across devices, not replicate
+        shuffler = DeviceShuffleBuffer(
+            self._device_shuffle_capacity, seed=self._seed or 0,
+            shardings=functools.partial(column_sharding, self.sharding))
         for batch in self._host_batches(host_q):
             if self._stop.is_set():
                 _release_leases(batch)
                 return
-            arrays, host = self._transfer_batch(batch)
+            arrays, host = self._transfer_next(batch)
             if host:
                 raise ValueError(
                     "device_shuffle_capacity requires every delivered column to be "
@@ -2016,7 +1620,7 @@ class DataLoader:
                     # host delivery IS the designed host-decode seam for
                     # pass-through columns (no degradation counted)
                     batch = _materialize_passthrough(batch)
-                    rest, staged = self._decode_staged(batch)
+                    rest, staged = self._transfer.decode(batch)
                     rest.update({k: np.asarray(v) for k, v in staged.items()})
                     self._advance_consumed(_batch_valid_rows(rest))
                     if self._prov_rec is not None:
@@ -2035,7 +1639,7 @@ class DataLoader:
                         prev = batch if isinstance(batch, LeasedBatch) else None
                         batch = _materialize_passthrough(batch)
                         if self._pack is not None:
-                            batch = self._pack_layout_host(batch)
+                            batch = self._transfer.layout_host(batch)
                         self._advance_consumed(_batch_valid_rows(batch))
                         if self._prov_rec is not None:
                             self._prov_rec.batch_delivered()
@@ -2051,7 +1655,7 @@ class DataLoader:
                     self._prov_rec.batch_delivered()
                 yield batch
             return
-        # Async transfer thread: host batches → decode dispatch + device_put → a small
+        # Async transfer thread: host batches → the transfer stage → a small
         # device-batch queue. Keeping dispatch OFF the consumer thread both overlaps
         # H2D/decode with the training step and absorbs device-service latency spikes
         # (a slow dispatch drains the queue instead of stalling the step).
@@ -2059,7 +1663,7 @@ class DataLoader:
         self._dev_queue = dev_q
         transfer_error = []
 
-        def _transfer():
+        def _transfer_loop():
             scope = self._health_scope
             hb = None
             if scope is not None:
@@ -2084,7 +1688,7 @@ class DataLoader:
                 _put_sentinel(dev_q, self._stop)
 
         self._transfer_thread = threading.Thread(
-            target=_transfer, name="ptpu-transfer", daemon=True)
+            target=_transfer_loop, name="ptpu-transfer", daemon=True)
         self._transfer_thread.start()
         finished = False
         try:
@@ -2422,9 +2026,7 @@ class DataLoader:
         if self._adopted_ragged:
             self.reader.keep_ragged = False
             self._adopted_ragged = False
-        if self._staging is not None:
-            self._staging.close()
-            self._staging = None
+        self._transfer.close()
         if self._slo_engine is not None and self._slo_owned:
             # a loader-built engine stops evaluating on the sampler cadence
             # (alerts stay readable); a caller-supplied SHARED engine keeps
@@ -2581,19 +2183,6 @@ def _resolve_pack(pack, shuffling_queue_capacity, device_shuffle_capacity, pad_s
     return field, int(length)
 
 
-def _replicated_like(sharding):
-    """Where a packed batch's chunk table goes: every device of the tokens'
-    ``sharding`` (a ``NamedSharding`` or one device)."""
-    import jax.sharding as jsh
-
-    if isinstance(sharding, jsh.NamedSharding):
-        return jsh.NamedSharding(sharding.mesh, jsh.PartitionSpec())
-    if len(sharding.device_set) == 1:
-        return sharding
-    raise ValueError("pack= lays out batches under a NamedSharding or on one device, "
-                     "not under %s" % type(sharding).__name__)
-
-
 def _resolve_local_batch(batch_size, sharding):
     """Rows this process feeds per global batch of ``batch_size`` (1 process → all).
 
@@ -2649,103 +2238,6 @@ def _resolve_local_batch(batch_size, sharding):
         return batch_size  # batch axis replicated: every process feeds all rows
     axes = (spec0,) if isinstance(spec0, str) else tuple(spec0)
     return local_batch_size(batch_size, sharding.mesh, batch_axes=axes)
-
-
-def _batch_shard_count(sharding):
-    """See :func:`petastorm_tpu.parallel.mesh.batch_axis_shard_count` (shared with
-    the decode op's SPMD input staging)."""
-    from petastorm_tpu.parallel.mesh import batch_axis_shard_count
-
-    return batch_axis_shard_count(sharding)
-
-
-#: try-call probe outcomes for uninspectable callables, keyed by the
-#: underlying function — codecs live for the process, so strong refs are fine
-_probed_kwargs = {}
-
-
-def _record_probed_kwarg(fn, name, supported):
-    """Cache a try-call probe's verdict so it runs once per process."""
-    _probed_kwargs[(getattr(fn, "__func__", fn), name)] = bool(supported)
-
-
-def _accepts_kwarg(fn, name):
-    """``True``/``False`` when ``fn``'s signature answers whether keyword
-    ``name`` is accepted (or ``**kwargs`` taken); ``None`` when the callable
-    is uninspectable — the caller then probes by calling once and records the
-    outcome via :func:`_record_probed_kwarg`. Cached on the underlying
-    function — this runs on the transfer thread per batch, and a signature
-    cannot change between batches."""
-    fn = getattr(fn, "__func__", fn)
-    probed = _probed_kwargs.get((fn, name))
-    if probed is not None:
-        return probed
-    return _accepts_kwarg_cached(fn, name)
-
-
-@functools.lru_cache(maxsize=None)
-def _accepts_kwarg_cached(fn, name):
-    import inspect
-
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        # Uninspectable (C-implemented / exotic wrappers): unknown — the old
-        # behavior assumed the legacy signature and silently dropped the
-        # kwarg; callers now try-call once instead (ISSUE 8 satellite)
-        return None
-    return name in params or any(
-        p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values())
-
-
-def _decode_sharding(s, local_rows):
-    """Batch-axis sharding to SPMD-decode staged payloads under (VERDICT r3 #2).
-
-    Single-process: the loader's sharding itself (stage 2 consumes its mesh + batch
-    axis; trailing axes are replicated per slab inside the decode). Multi-process: a
-    global ``NamedSharding`` cannot place host data, so derive a process-LOCAL 1-D
-    mesh whose device order mirrors ``s``'s local batch-slice order — decode output
-    shards then already sit where ``make_array_from_process_local_data`` wants them.
-    Returns None when the batch axis is unsharded or does not divide — decode then
-    runs on the default device exactly as before (correct, just unscaled)."""
-    import jax
-    import jax.sharding as jsh
-
-    if not isinstance(s, jsh.NamedSharding) or not len(s.spec) or s.spec[0] is None:
-        return None
-    if jax.process_count() == 1:
-        return s
-    axis = s.spec[0]
-    s1 = jsh.NamedSharding(s.mesh, jsh.PartitionSpec(axis))
-    global_rows = local_rows * jax.process_count()
-    try:
-        imap = s1.addressable_devices_indices_map((global_rows,))
-    except ValueError:
-        return None
-    by_start = {}
-    for dev, idx in imap.items():
-        sl = idx[0]
-        start = 0 if sl.start is None else int(sl.start)
-        by_start.setdefault(start, dev)  # one device per distinct slice (replicas skip)
-    devs = [by_start[k] for k in sorted(by_start)]
-    if len(devs) <= 1 or local_rows % len(devs) != 0:
-        return None
-    mesh = jsh.Mesh(np.asarray(devs), ("_decode_batch",))
-    return jsh.NamedSharding(mesh, jsh.PartitionSpec("_decode_batch"))
-
-
-def _matching_sharding(sharding, arr):
-    """Adapt a batch-axis sharding to an array's rank (replicate the trailing axes)."""
-    import jax.sharding as jsh
-
-    if isinstance(sharding, jsh.NamedSharding):
-        spec = list(sharding.spec)
-        if len(spec) < arr.ndim:
-            spec = spec + [None] * (arr.ndim - len(spec))
-        elif len(spec) > arr.ndim:
-            spec = spec[: arr.ndim]
-        return jsh.NamedSharding(sharding.mesh, jsh.PartitionSpec(*spec))
-    return sharding
 
 
 class InMemDataLoader:
@@ -2899,7 +2391,7 @@ class InMemDataLoader:
             # gathered batches are laid out per the sharding
             try:
                 self._store = {
-                    k: jax.device_put(v, _matching_sharding(sharding, v))
+                    k: jax.device_put(v, column_sharding(sharding, k, v))
                     for k, v in self._store.items()
                 }
             except ValueError:
@@ -3027,8 +2519,8 @@ class InMemDataLoader:
                     # Divisibility is checked explicitly — a blanket except would
                     # misreport genuine sharding bugs (rank/spec mismatch) as a
                     # tail-batch artifact and transfer columns only to discard them.
-                    if len(idx) % _batch_shard_count(self._sharding) == 0:
-                        batch = {k: jax.device_put(v, _matching_sharding(self._sharding, v))
+                    if len(idx) % batch_axis_shard_count(self._sharding) == 0:
+                        batch = {k: jax.device_put(v, column_sharding(self._sharding, k, v))
                                  for k, v in batch.items()}
                     else:
                         logger.warning(
@@ -3067,8 +2559,7 @@ class InMemDataLoader:
             local = self._gather(self._store, idx)
             batch = {}
             for k, v in local.items():
-                s = self._sharding.get(k) if isinstance(self._sharding, dict) \
-                    else _matching_sharding(self._sharding, v)
+                s = column_sharding(self._sharding, k, v)
                 if s is None:
                     batch[k] = v  # field without a declared layout stays local
                 else:

@@ -913,14 +913,6 @@ def _split_points(profile, ks, layout):
     return spec
 
 
-def _batch_axis_shards(sharding):
-    """Distinct batch-axis slice count under ``sharding`` (1 = not batch-sharded);
-    single shared definition with the loader's layout checks."""
-    from petastorm_tpu.parallel.mesh import batch_axis_shard_count
-
-    return batch_axis_shard_count(sharding)
-
-
 def _stage_inputs(tree, sharding, n):
     """Explicit async ``device_put`` of host staging slabs ahead of the stage-2 jit.
 
@@ -941,8 +933,9 @@ def _stage_inputs(tree, sharding, n):
     import jax
 
     from petastorm_tpu.io.staging import put_program_inputs
+    from petastorm_tpu.parallel.mesh import batch_axis_shard_count
 
-    shards = _batch_axis_shards(sharding) if sharding is not None else 0
+    shards = batch_axis_shard_count(sharding)
     if shards <= 1 or n % shards != 0:
         return put_program_inputs(tree, jax.device_put), None
     import jax.sharding as jsh

@@ -173,42 +173,6 @@ def test_loader_spmd_decode_output_presharded(jpeg_dataset):
     assert seen_shardings and all(s is sharding for s in seen_shardings)
 
 
-def test_sharded_loader_with_presharding_codec_signature(jpeg_dataset):
-    """A third-party codec subclass predating the ``sharding`` kwarg must keep
-    working under a sharded DataLoader: the loader inspects the signature and falls
-    back to single-device decode + reshard (review r4)."""
-    import jax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-    from petastorm_tpu import codecs as codecs_mod
-
-    calls = []
-    orig = codecs_mod.CompressedImageCodec.device_decode_batch
-
-    def legacy_sig(self, field, staged, resize_to=None):  # no sharding kwarg
-        calls.append(resize_to)
-        return orig(self, field, staged, resize_to=resize_to)
-
-    mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ("dp",))
-    sharding = NamedSharding(mesh, PartitionSpec("dp"))
-    reader = make_batch_reader(jpeg_dataset.url, decode_on_device=True, num_epochs=1,
-                               shuffle_row_groups=False)
-    try:
-        codecs_mod.CompressedImageCodec.device_decode_batch = legacy_sig
-        with DataLoader(reader, batch_size=8, sharding=sharding) as loader:
-            batch = next(iter(loader))
-            img = batch["image_jpeg"]
-            assert img.shape == (8, 32, 48, 3)
-            assert len(img.sharding.device_set) == 8  # resharded after decode
-            # the fallback is correct but single-device — it must be SURFACED
-            # (VERDICT r4 #6), not silent
-            assert loader.stats.decode_unsharded_batches >= 1
-            assert "decode_unsharded_batches" in loader.stats.snapshot()
-    finally:
-        codecs_mod.CompressedImageCodec.device_decode_batch = orig
-    assert calls  # the legacy signature really was invoked, without a TypeError
-
-
 def test_decode_unsharded_fallback_counter_and_warning(jpeg_dataset, caplog):
     """An 8-way batch sharding with an undivisible batch makes staged decode fall
     back to a single device: the loader must count it in

@@ -222,56 +222,50 @@ def test_staging_pool_close_is_idempotent_and_stage_after_close_falls_back():
 
 # -- loader integration: staging decision ------------------------------------------------
 
+def _transfer(staging):
+    from petastorm_tpu.loader import PipelineStats
+    from petastorm_tpu.transfer import DeviceTransfer
+
+    return DeviceTransfer(PipelineStats(), stage=None, staging=staging)
+
+
 def test_loader_staging_refused_on_aliasing_backend(monkeypatch):
     """staging=True on a backend whose device_put aliases host numpy must be
     REFUSED with a degradation — recycled slabs would corrupt delivered
     batches — and the loader keeps transferring from pageable memory."""
     import petastorm_tpu.io.staging as staging_mod
-    from petastorm_tpu.loader import DataLoader
     from petastorm_tpu.obs.log import degradation_counts
 
     monkeypatch.setattr(staging_mod, "_alias_probe", True)
-    loader = DataLoader.__new__(DataLoader)
-    loader._staging_arg = True
-    loader._staging = None
-    loader._staging_decided = False
+    transfer = _transfer(True)
     before = degradation_counts().get("staging_aliasing", 0)
-    pool = loader._ensure_staging({"x": np.arange(8, dtype=np.float32)})
-    assert pool is None and loader._staging is None
+    pool = transfer._ensure_staging({"x": np.arange(8, dtype=np.float32)})
+    assert pool is None and transfer._staging is None
     assert degradation_counts()["staging_aliasing"] == before + 1
 
 
 def test_loader_staging_disabled_and_auto_cpu_off(monkeypatch):
     import petastorm_tpu.io.staging as staging_mod
-    from petastorm_tpu.loader import DataLoader
 
     monkeypatch.setattr(staging_mod, "_alias_probe", False)
     for arg in (False, None):  # explicit off; auto mode on the CPU backend
-        loader = DataLoader.__new__(DataLoader)
-        loader._staging_arg = arg
-        loader._staging = None
-        loader._staging_decided = False
-        assert loader._ensure_staging({"x": np.arange(8.0)}) is None
+        assert _transfer(arg)._ensure_staging({"x": np.arange(8.0)}) is None
 
 
 def test_loader_staging_forced_builds_pinned_pool(monkeypatch):
     """staging=True on a copying backend builds the pool sized to the first
     batch, and the staged transfer path stages + releases the slab."""
     import petastorm_tpu.io.staging as staging_mod
-    from petastorm_tpu.loader import DataLoader
 
     monkeypatch.setattr(staging_mod, "_alias_probe", False)
-    loader = DataLoader.__new__(DataLoader)
-    loader._staging_arg = True
-    loader._staging = None
-    loader._staging_decided = False
-    pool = loader._ensure_staging({"x": np.arange(1024, dtype=np.float32)})
+    transfer = _transfer(True)
+    pool = transfer._ensure_staging({"x": np.arange(1024, dtype=np.float32)})
     try:
         assert pool is not None and len(pool) == 2
         assert pool.slab_bytes >= 4096
-        assert loader._ensure_staging({"x": np.arange(4.0)}) is pool  # cached
+        assert transfer._ensure_staging({"x": np.arange(4.0)}) is pool  # cached
     finally:
-        pool.close()
+        transfer.close()
 
 
 # -- loader lease path end-to-end --------------------------------------------------------

@@ -72,7 +72,7 @@ def test_detach_slab_views_covers_nested_object_elements():
     of object (ragged) columns and detach() staged payloads, not just top-level
     read-only arrays — the outer object array is writable, its slab-view elements
     are not."""
-    from petastorm_tpu.loader import _detach_slab_views
+    from petastorm_tpu.io.lease import detach_slab_views
 
     class _Staged:
         def __init__(self):
@@ -88,8 +88,8 @@ def test_detach_slab_views_covers_nested_object_elements():
     ragged[:] = [ro_elem, np.arange(2), _Staged()]
     ro_flat = np.arange(5, dtype=np.float32)
     ro_flat.setflags(write=False)
-    out = _detach_slab_views({"ragged": ragged, "flat": ro_flat,
-                              "ok": np.arange(3)})
+    out = detach_slab_views({"ragged": ragged, "flat": ro_flat,
+                             "ok": np.arange(3)})
     assert out["flat"].flags.writeable and out["flat"] is not ro_flat
     assert out["ok"].flags.writeable  # already-writable column passes through
     assert out["ragged"][0].flags.writeable and out["ragged"][0] is not ro_elem
@@ -726,7 +726,7 @@ def test_stage2_inputs_from_leased_buffers_are_guarded(tmp_path, monkeypatch, al
     reader = make_batch_reader(ds.url, decode_on_device=True, num_epochs=1)
     copied = copy_census().get("h2d_owned_copy", 0)
     with DataLoader(reader, n) as loader:
-        arrays, _host = loader._transfer_batch(batch)
+        arrays, _host = loader._transfer(batch, 0)
     assert recycled == [True]
     ncomp = len(planes[0].components)
     inputs = [a for a in waited if a.shape[0] == n and a.ndim >= 2]

@@ -264,13 +264,15 @@ def test_throttled_reader_is_producer_bound(scalar_dataset):
 
 
 def test_slow_decode_stage_is_consumer_bound(scalar_dataset, monkeypatch):
-    orig = DataLoader._decode_staged
+    from petastorm_tpu.transfer import DeviceTransfer
+
+    orig = DeviceTransfer.decode
 
     def slow_decode(self, batch):
         time.sleep(0.05)  # artificially slow decode dispatch
         return orig(self, batch)
 
-    monkeypatch.setattr(DataLoader, "_decode_staged", slow_decode)
+    monkeypatch.setattr(DeviceTransfer, "decode", slow_decode)
     reader = make_batch_reader(scalar_dataset.url, num_epochs=3,
                                shuffle_row_groups=False, workers_count=1)
     loader = DataLoader(reader, batch_size=5, host_queue_size=2, prefetch=1)

@@ -874,45 +874,6 @@ class TestReaderIntegration:
 
 
 # --------------------------------------------------------------------------------------
-# loader satellite: try-call probe for uninspectable codecs
-# --------------------------------------------------------------------------------------
-
-
-class TestKwargProbe:
-    def test_signature_answers_stay_authoritative(self):
-        from petastorm_tpu.loader import _accepts_kwarg
-
-        def with_kwarg(a, sharding=None):
-            return a
-
-        def without(a):
-            return a
-
-        def var_kw(a, **kw):
-            return a
-
-        assert _accepts_kwarg(with_kwarg, "sharding") is True
-        assert _accepts_kwarg(without, "sharding") is False
-        assert _accepts_kwarg(var_kw, "sharding") is True
-
-    def test_uninspectable_returns_unknown_then_probe_caches(self):
-        from petastorm_tpu.loader import _accepts_kwarg, _record_probed_kwarg
-
-        class Weird:
-            __signature__ = 42  # inspect.signature -> TypeError
-
-            def __call__(self, a, sharding=None):
-                return a
-
-        fn = Weird()
-        assert _accepts_kwarg(fn, "sharding") is None  # unknown: probe me
-        _record_probed_kwarg(fn, "sharding", True)
-        assert _accepts_kwarg(fn, "sharding") is True  # probe outcome cached
-        _record_probed_kwarg(fn, "sharding", False)
-        assert _accepts_kwarg(fn, "sharding") is False
-
-
-# --------------------------------------------------------------------------------------
 # options plumbing
 # --------------------------------------------------------------------------------------
 
