@@ -156,13 +156,29 @@ def _try_mlock(buf, nbytes):
     return False
 
 
+class StagedSlab(Lease):
+    """The lease over one staged batch's slab, which also says where the batch
+    sits in it: ``prefix`` is the slab's used bytes ``[0, end)`` as a read-only
+    ``uint8`` array (a view, no copy), and ``columns`` holds ``(name, offset,
+    shape, dtype)`` for each staged column, in staging order. Offsets round up
+    to ``_STAGE_ALIGN``; the bytes of a gap belong to no column."""
+
+    __slots__ = ("prefix", "columns")
+
+    def __init__(self, prefix, columns, release_cb):
+        super().__init__(release_cb=release_cb, kind="staging_slab")
+        self.prefix = prefix
+        self.columns = columns
+
+
 class PinnedStagingPool:
     """Ring of page-locked host slabs the transfer thread stages device-bound
     batches into before ``device_put``.
 
     ``stage(arrays)`` returns ``(staged_arrays, lease)``: the staged dict maps
     the same keys to ndarray views INTO one slab (read-only — nothing may
-    write a slab under DMA), and the lease returns the slab to the ring on
+    write a slab under DMA), and the lease (a :class:`StagedSlab`, which also
+    gives each column's place in the slab) returns the slab to the ring on
     release. Returns ``(arrays, None)`` unchanged when the batch cannot stage
     (oversized / ring starved) — callers need no special path.
     """
@@ -216,19 +232,21 @@ class PinnedStagingPool:
                 "pageable memory", self._timeout)
             return arrays, None
         buf = memoryview(self._slabs[slab_id])
+        prefix = np.frombuffer(buf, dtype=np.uint8, count=end)
         staged = dict(arrays)
+        columns = []
         total = 0
         for (name, v), start in zip(items, spans):
-            flat = np.frombuffer(buf, dtype=np.uint8, count=v.nbytes,
-                                 offset=start)
-            dst = flat.view(v.dtype).reshape(v.shape)
+            dst = prefix[start:start + v.nbytes].view(v.dtype).reshape(v.shape)
             np.copyto(dst, v)
             dst.flags.writeable = False  # nothing may write a slab under DMA
             staged[name] = dst
+            columns.append((name, start, v.shape, v.dtype))
             total += v.nbytes
+        prefix.flags.writeable = False
         count_copy("h2d_stage", total)
-        lease = Lease(release_cb=lambda: self._release(slab_id),
-                      kind="staging_slab")
+        lease = StagedSlab(prefix, tuple(columns),
+                           release_cb=lambda: self._release(slab_id))
         return staged, lease
 
     def _release(self, slab_id):

@@ -81,7 +81,8 @@ class PipelineStats:
     (stage-2 input stacked and narrowed on the host), ``decode_put_s`` (its
     ``device_put``), ``decode_launch_s`` (the stage-2 call, the flip, a reshard);
     ``h2d_stage_s`` (the pinned staging copy), ``h2d_put_s`` (the columns'
-    ``device_put``s), ``h2d_wait_s`` (the wait for the transfers before host
+    ``device_put``s, or the staged slab's one put and the program that cuts it
+    into columns), ``h2d_wait_s`` (the wait for the transfers before host
     buffers go back to their rings). ``h2d_bytes`` counts what both puts shipped.
     The wait blocks only on transfers that read a host buffer about to be
     recycled: the columns' ``device_put`` (from the staging slab or leased
@@ -89,6 +90,10 @@ class PipelineStats:
     program's output. ``h2d_unwaited_outputs`` counts the outputs of stage-2
     and of the pass-through inflate handed on without a host wait (one a
     batch for each decoded field; 0 without device decode).
+    ``h2d_slab_puts`` counts the batches whose columns shipped as ONE transfer
+    of their staged slab (at least two staged columns of one itemsize, dtypes
+    JAX keeps as they are, one device), cut into the columns on the device
+    by ``jit_ptpu_slab_split``; the wait then blocks on that one transfer.
 
     Under ``DataLoader(pack=...)``: ``pack_s`` is producer time packing
     documents into rows (chunking, placement, the token copy); ``pack_slots``,
@@ -122,8 +127,8 @@ class PipelineStats:
                  "queue_wait_s", "device_queue_wait_s", "decode_unsharded_batches",
                  "shm_slabs_in_flight", "shm_bytes", "shm_fallbacks",
                  "shm_acquire_wait_s") + tuple(_PHASES.values()) + (
-                     "h2d_bytes", "h2d_unwaited_outputs", "pack_s", "pack_slots",
-                     "pack_pad_slots", "pack_chunks")
+                     "h2d_bytes", "h2d_unwaited_outputs", "h2d_slab_puts", "pack_s",
+                     "pack_slots", "pack_pad_slots", "pack_chunks")
 
     def __init__(self):
         self.reset()

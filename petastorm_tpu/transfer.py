@@ -2,7 +2,8 @@
 
 :class:`DeviceTransfer` runs, on the loader's transfer thread: inflate
 (compressed-page pass-through) → decode (two-stage codec dispatch) → split
-(device / host-only columns) → stage (pinned slab) → put → release (the wait,
+(device / host-only columns) → stage (pinned slab) → put (the slab in one
+transfer where the batch allows, else column by column) → release (the wait,
 then the slab and leases back) → layout (a packed batch's segment ids and
 positions). :func:`column_sharding` is the one placement rule. The stages open
 through the loader's ``stage`` factory, so their spans and sinks stay the
@@ -11,6 +12,7 @@ loader's.
 from __future__ import annotations
 
 import logging
+import math
 import os
 
 import numpy as np
@@ -99,6 +101,27 @@ def _decode_sharding(s, local_rows):
     return jsh.NamedSharding(mesh, jsh.PartitionSpec("_decode_batch"))
 
 
+def _split_program(columns, word, shardings):
+    """``jit_ptpu_slab_split``: a staged slab's used bytes, put as one array of
+    ``word``s, cut into its ``columns`` (``(offset, shape, dtype)`` each) — a
+    slice, a reshape and, last, a bitcast to the column's dtype. It does no
+    arithmetic, so every value arrives bit for bit. ``shardings``, when given,
+    places each output as the column's own put would have."""
+    import jax
+    from jax import lax
+
+    def ptpu_slab_split(words):
+        out = []
+        for offset, shape, dtype in columns:
+            start = offset // word.itemsize
+            col = lax.slice(words, (start,), (start + math.prod(shape),))
+            out.append(lax.bitcast_convert_type(col.reshape(shape), dtype))
+        return tuple(out)
+
+    kwargs = {} if shardings is None else {"out_shardings": shardings}
+    return jax.jit(ptpu_slab_split, **kwargs)
+
+
 def _is_device_dtype(arr):
     """Only numeric/bool fixed-shape columns can live on device; strings/objects stay host."""
     return isinstance(arr, np.ndarray) and arr.dtype.kind in "biufc"
@@ -111,8 +134,9 @@ class DeviceTransfer:
     opens one of its stages; the rest are the loader's ``sharding``,
     ``staging=`` and resolved ``pack``, the reader's schema and device-decode
     fields, and the validated ``device_decode_resize``. It owns the staging
-    pool (:meth:`close` frees it), the layout program per row count and the
-    once-per-loader unsharded-decode warning."""
+    pool (:meth:`close` frees it), the layout program per row count, the slab
+    split program per slab layout and the once-per-loader unsharded-decode
+    warning."""
 
     def __init__(self, stats, stage, sharding=None, staging=None, pack=None,
                  schema=None, decode_fields=(), resize=None):
@@ -127,6 +151,7 @@ class DeviceTransfer:
         self._staging = None
         self._staging_decided = False
         self._pack_programs = {}
+        self._split_programs = {}
         self._warned_unsharded_decode = False
 
     def __call__(self, batch, seq, beat=None):
@@ -148,8 +173,8 @@ class DeviceTransfer:
             table = batch.pop(_PACK_TABLE, None) if self.pack is not None else None
             device, host = self.split(batch, leases)
             device, slab = self.stage(device, leases, seq)
-            arrays, table = self.put(device, table, seq)
-            waited = list(arrays.values()) + list(borrowed)
+            arrays, table, waited = self.put(device, table, seq, slab)
+            waited.extend(borrowed)
             arrays.update(decoded)
             arrays.update(inflated)
             self.stats.h2d_unwaited_outputs += len(decoded) + len(inflated)
@@ -383,40 +408,109 @@ class DeviceTransfer:
         self._staging = PinnedStagingPool(max(slab_bytes, need), num_slabs=2)
         return self._staging
 
-    def put(self, device, table, seq):
-        """``h2d.put``: each column to :func:`column_sharding`'s placement (a
-        field without one to the default device), and a packed batch's chunk
-        table to every device of the tokens. Returns ``(arrays, table)``."""
+    def put(self, device, table, seq, slab=None):
+        """``h2d.put``: the columns to their placement, and a packed batch's
+        chunk table to every device of the tokens. A batch :meth:`_slab_words`
+        admits goes as ONE transfer of its staged slab, cut into its columns
+        on the device by :func:`_split_program` (``h2d_slab_puts``); any other
+        goes column by column to :func:`column_sharding`'s placement (a field
+        without one to the default device). Returns ``(arrays, table,
+        waited)``: ``waited`` lists the transfers that read the host columns
+        (the slab's word array, or the columns), never the split program's
+        outputs."""
         import jax
 
-        nbytes = sum(getattr(v, "nbytes", 0) for v in device.values())
+        one = self._slab_words(device, slab)
+        if one is not None:
+            nbytes, transfers = one[0].nbytes, 1
+        else:
+            nbytes = sum(getattr(v, "nbytes", 0) for v in device.values())
+            transfers = len(device)
         if table is not None:
             nbytes += table.nbytes
-        with phase("h2d.put", batch=seq, bytes=nbytes):
-            if self.sharding is None:
-                arrays = jax.device_put(device)
+            transfers += 1
+        with phase("h2d.put", batch=seq, bytes=nbytes, arrays=transfers):
+            if one is not None:
+                arrays, waited = self._put_slab(slab.columns, *one)
+                self.stats.h2d_slab_puts += 1
             else:
-                arrays = {}
-                for name, arr in device.items():
-                    s = column_sharding(self.sharding, name, arr)
-                    if s is None:
-                        arrays[name] = jax.device_put(arr)
-                    elif jax.process_count() > 1:
-                        arrays[name] = jax.make_array_from_process_local_data(s, arr)
-                    else:
-                        arrays[name] = jax.device_put(arr, s)
+                arrays = self._put_columns(device)
+                waited = list(arrays.values())
             if table is not None:
                 table = jax.device_put(
                     table, _replicated_like(arrays[self.pack[0]].sharding))
-        return arrays, table
+        return arrays, table, waited
+
+    def _slab_words(self, device, slab):
+        """``(words, shardings)`` where the staged batch can go as one
+        transfer, else None. It can where ``slab`` holds at least two columns,
+        all of one itemsize and of integer or float dtypes that JAX keeps as
+        they are (with x64 off an ``int64`` is narrowed on the host), all bound
+        for one device (no sharding, or a one-device sharding for every
+        column, in one process). ``words`` is the slab's used bytes viewed as
+        unsigned words of that itemsize (no copy); ``shardings`` the columns'
+        placements, None without a sharding."""
+        if slab is None or len(slab.columns) < 2:
+            return None
+        import jax
+
+        dtypes = {dtype for _name, _offset, _shape, dtype in slab.columns}
+        itemsizes = {dtype.itemsize for dtype in dtypes}
+        if len(itemsizes) != 1:
+            return None
+        word = np.dtype("uint%d" % (8 * itemsizes.pop()))
+        if any(dtype.kind not in "iuf" or jax.dtypes.canonicalize_dtype(dtype) != dtype
+               for dtype in dtypes | {word}):
+            return None
+        shardings = None
+        if self.sharding is not None:
+            if jax.process_count() > 1:
+                return None
+            shardings = tuple(column_sharding(self.sharding, name, device[name])
+                              for name, _offset, _shape, _dtype in slab.columns)
+            if any(s is None for s in shardings) or \
+                    len(set().union(*(s.device_set for s in shardings))) != 1:
+                return None
+        return slab.prefix.view(word), shardings
+
+    def _put_slab(self, columns, words, shardings):
+        """One ``device_put`` of ``words`` and the cached split program on it.
+        Returns ``({name: column}, [the word array])``."""
+        import jax
+
+        layout = tuple((offset, shape, dtype) for _name, offset, shape, dtype in columns)
+        key = (layout, words.dtype, shardings)
+        fn = self._split_programs.get(key)
+        if fn is None:
+            fn = self._split_programs[key] = _split_program(layout, words.dtype, shardings)
+        target = None if shardings is None else next(iter(shardings[0].device_set))
+        on_device = jax.device_put(words, target)
+        return dict(zip((c[0] for c in columns), fn(on_device))), [on_device]
+
+    def _put_columns(self, device):
+        """Each column on its own ``device_put`` (or process-local assembly)."""
+        import jax
+
+        if self.sharding is None:
+            return jax.device_put(device)
+        arrays = {}
+        for name, arr in device.items():
+            s = column_sharding(self.sharding, name, arr)
+            if s is None:
+                arrays[name] = jax.device_put(arr)
+            elif jax.process_count() > 1:
+                arrays[name] = jax.make_array_from_process_local_data(s, arr)
+            else:
+                arrays[name] = jax.device_put(arr, s)
+        return arrays
 
     def release(self, waited, slab, leases, seq):
         """``h2d.wait``, then the slab and the leases back to their rings.
 
         ``device_put`` is async: ``waited`` holds the transfers that may still
-        read a buffer about to be recycled (the columns' put from the slab or
-        leased views, a program's input put from borrowed memory), and no
-        program's output — that wait would wait on every program queued on the
+        read a buffer about to be recycled (the slab's word array or the
+        columns' puts from the slab or leased views, a program's input put
+        from borrowed memory), and no program's output — that wait would wait on every program queued on the
         device before it (the running step)."""
         if slab is None and not leases:
             return

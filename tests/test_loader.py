@@ -288,11 +288,12 @@ def test_stats_populate_through_device_path(scalar_dataset):
                          "shm_bytes", "shm_fallbacks", "shm_acquire_wait_s",
                          "decode_pack_s", "decode_put_s", "decode_launch_s",
                          "h2d_stage_s", "h2d_put_s", "h2d_wait_s", "h2d_bytes",
-                         "h2d_unwaited_outputs",
+                         "h2d_unwaited_outputs", "h2d_slab_puts",
                          "pack_s", "pack_slots", "pack_pad_slots", "pack_chunks"}
     assert snap["pack_s"] == 0 and snap["pack_chunks"] == 0  # no pack=: nothing packed
     assert snap["decode_unsharded_batches"] == 0  # no sharding configured → no fallback
     assert snap["h2d_unwaited_outputs"] == 0  # no device decode: no program outputs
+    assert snap["h2d_slab_puts"] == 0  # the CPU backend stages nothing
     assert snap["h2d_bytes"] > 0  # the columns' device_put phase counted its bytes
     assert snap["read_s"] >= 0 and snap["device_queue_wait_s"] >= 0
 

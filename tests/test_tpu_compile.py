@@ -159,3 +159,31 @@ def test_jpeg_stage2_compiles_spmd_over_dp4(tpu, topo):
     compiled = _compile(fn, coeffs, qtabs)
     assert "tpu_custom_call" in compiled.as_text()
     jpeg._batched_stage2.cache_clear()
+
+
+@pytest.mark.parametrize("rows,pair", [(16384, False), (1000, True)])
+def test_slab_split_compiles_for_a_criteo_batch(tpu, one_chip, rows, pair):
+    """The staged slab's split program at the Criteo cell's size (a b16384
+    label, 13 float32 dense and 26 int32 categorical columns, one uint32 word
+    array) and at a partial batch with an alignment gap and a 2-D column: the
+    outputs keep each column's shape and dtype, with no scratch beyond them."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from petastorm_tpu.transfer import _split_program
+
+    dtypes = [np.dtype(np.int32)] + [np.dtype(np.float32)] * 13 + \
+        [np.dtype(np.int32)] * 26
+    shapes = [(rows,)] * len(dtypes)
+    if pair:
+        shapes[1] = (rows, 3)
+    layout, end = [], 0
+    for shape, dtype in zip(shapes, dtypes):
+        offset = -(-end // 256) * 256
+        layout.append((offset, shape, dtype))
+        end = offset + int(np.prod(shape)) * 4
+    fn = _split_program(tuple(layout), np.dtype(np.uint32), None)
+    compiled = _compile(fn, _spec((end // 4,), jnp.uint32, one_chip))
+    outs = compiled.out_info
+    assert [(o.shape, np.dtype(o.dtype)) for o in outs] == list(zip(shapes, dtypes))
+    assert compiled.memory_analysis().temp_size_in_bytes <= end
