@@ -45,12 +45,14 @@ class DataframeColumnCodec:
         )
 
     def device_decode_batch(self, unischema_field, staged, resize_to=None,
-                            sharding=None):
+                            sharding=None, boxes=None):
         """On-device decode path, device half: list of staging objects (one per row) →
         one batched device array matching :meth:`decode`'s per-row output contract.
         ``resize_to=(h, w)`` (image codecs) asks for an on-device resize to one
-        static shape so mixed-size stores can batch. ``sharding`` (optional batch-axis
-        sharding) asks the decode to run SPMD — one batch shard per device."""
+        static shape so mixed-size stores can batch; ``boxes`` (``(n, 5)`` int32,
+        :mod:`petastorm_tpu.ops.crop`) crops each row to its box first.
+        ``sharding`` (optional batch-axis sharding) asks the decode to run SPMD — one
+        batch shard per device."""
         raise NotImplementedError(
             "%s does not support on-device decode" % type(self).__name__
         )
@@ -299,12 +301,18 @@ class CompressedImageCodec(DataframeColumnCodec):
         """Sequence of encoded blobs (``None`` entries preserved) → list of staging
         payloads, one native call per row group when possible.
 
-        The batched stage 1 (petastorm_tpu/ops/jpeg.py ``entropy_decode_jpeg_batch``)
-        entropy-decodes every same-layout stream into stacked buffers in one
-        GIL-released native call; streams it cannot handle (lossless/arithmetic, corrupt,
-        layout differs from the group) fall back to :meth:`host_stage_decode`
-        individually, so the output mixes ``JpegPlanes`` and host-decoded ndarrays
-        exactly like the per-row path."""
+        A row group whose frame headers give one size takes the batched stage 1
+        (petastorm_tpu/ops/jpeg.py ``entropy_decode_jpeg_batch``): every same-layout
+        stream entropy-decodes into stacked buffers in one GIL-released native call;
+        streams it cannot handle (lossless/arithmetic, corrupt, layout differs from
+        the group) fall back to :meth:`host_stage_decode` individually, so the output
+        mixes ``JpegPlanes`` and host-decoded ndarrays exactly like the per-row path.
+
+        A row group of several sizes stages image by image, its work shared with the
+        pool's other workers (``stage_images``), and an image no canvas of the
+        mixed-size device decode holds (:func:`petastorm_tpu.ops.jpeg.canvas_holds`)
+        decodes here with cv2 instead, so that the rare giant does not compile a
+        program of its own size on the device."""
         if not self.device_decodable:
             raise NotImplementedError("on-device decode is only available for jpeg")
         idx = [i for i, v in enumerate(values) if v is not None]
@@ -312,20 +320,28 @@ class CompressedImageCodec(DataframeColumnCodec):
         if not idx:
             return out
         blobs = [bytes(values[i]) for i in idx]
-        planes = None
+        from petastorm_tpu.ops.jpeg import (canvas_holds, entropy_decode_jpeg_batch,
+                                            jpeg_size, stage_images)
         from petastorm_tpu.trace import profiler_span
 
-        try:
-            from petastorm_tpu.ops.jpeg import entropy_decode_jpeg_batch
+        sizes = [jpeg_size(b) for b in blobs]
+        if len(set(sizes) - {None}) > 1:
+            def stage(job):
+                blob, size = job
+                if size is not None and not canvas_holds(size):
+                    return self.decode(unischema_field, blob)
+                return self.host_stage_decode(unischema_field, blob)
 
+            with profiler_span("ptpu.pool.stage1", rows=len(blobs)):
+                staged = stage_images(stage, list(zip(blobs, sizes)))
+            for j, i in enumerate(idx):
+                out[i] = staged[j]
+            return out
+        try:
             with profiler_span("ptpu.pool.stage1", rows=len(blobs)):
                 planes = entropy_decode_jpeg_batch(blobs)
         except (ValueError, RuntimeError):
-            planes = None
-        if planes is None:
-            for i in idx:
-                out[i] = self.host_stage_decode(unischema_field, values[i])
-            return out
+            planes = [None] * len(idx)
         for j, i in enumerate(idx):
             p = planes[j]
             out[i] = p if p is not None \
@@ -333,7 +349,7 @@ class CompressedImageCodec(DataframeColumnCodec):
         return out
 
     def device_decode_batch(self, unischema_field, staged, resize_to=None,
-                            sharding=None):
+                            sharding=None, boxes=None):
         """Coefficient planes (one per row) → (n, ...) uint8 device array, one batched
         Pallas dispatch. Matches :meth:`decode`'s per-row contract: cv2 returns images
         in stored (BGR for color) channel order and 2-D for grayscale fields, so the
@@ -341,53 +357,65 @@ class CompressedImageCodec(DataframeColumnCodec):
         back to host decode in :meth:`host_stage_decode` arrive as ndarrays and are
         merged in at their original positions.
 
-        ``resize_to=(h, w)`` enables mixed-size stores: device rows resize on device
-        after decode (:func:`petastorm_tpu.ops.jpeg.resize_image_batch`), host
-        fallbacks via ``cv2.resize`` INTER_LINEAR — the matching sampling.
+        ``resize_to=(h, w)`` enables mixed-size stores: the rows decode in canvas
+        buckets and are resized on the device
+        (:func:`petastorm_tpu.ops.jpeg.decode_jpeg_crop_batch`), each inside its
+        ``boxes`` row (``y0, x0, h, w, flip``) when given, else whole. Host
+        fallbacks (among them, in a row group of several sizes, the images no
+        canvas holds) take ``cv2.resize`` INTER_LINEAR of the same box: the
+        matching sampling.
 
         ``sharding``: optional batch-axis sharding; the coefficient slabs are placed
         across its devices before the stage-2 jit so decode runs SPMD (one batch
-        shard per device) instead of serializing on the default device."""
+        shard per device) instead of serializing on the default device. The canvas
+        decode runs on one device."""
         if not self.device_decodable:
             raise NotImplementedError("on-device decode is only available for jpeg")
         import jax.numpy as jnp
 
         from petastorm_tpu.ops.jpeg import (JpegPlanes, decode_jpeg_batch,
-                                            to_stored_order)
+                                            decode_jpeg_crop_batch, to_stored_order)
 
         staged = list(staged)
         plane_idx = [i for i, s in enumerate(staged) if isinstance(s, JpegPlanes)]
         host_idx = [i for i in range(len(staged)) if i not in set(plane_idx)]
         shape = unischema_field.shape
         grayscale = shape is not None and len(shape) == 2
+        if resize_to is not None:
+            from petastorm_tpu.ops.crop import whole_boxes
+
+            size = (int(resize_to[0]), int(resize_to[1]))
+            if boxes is None:
+                boxes = whole_boxes([(s.height, s.width) if isinstance(s, JpegPlanes)
+                                     else s.shape[:2] for s in staged])
 
         parts = []
         order = []
-        if plane_idx:
-            img = decode_jpeg_batch([staged[i] for i in plane_idx],
-                                    resize_to=resize_to, sharding=sharding)
-            img = to_stored_order(img, grayscale)
-            parts.append(img)
+        if plane_idx and resize_to is None:
+            img = decode_jpeg_batch([staged[i] for i in plane_idx], sharding=sharding)
+            parts.append(to_stored_order(img, grayscale))
+            order.extend(plane_idx)
+        elif plane_idx:
+            parts.append(decode_jpeg_crop_batch(
+                [staged[i] for i in plane_idx], boxes[plane_idx], size, grayscale))
             order.extend(plane_idx)
         if host_idx:
             from petastorm_tpu.obs.log import degradation
+            from petastorm_tpu.trace import count
 
+            count("decode_host_rows", len(host_idx))
             degradation("jpeg_host_fallback",
                         "%d of %d rows of field %r were decoded on host (stage 1 "
-                        "could not take them); the device decode was skipped "
-                        "for those rows", len(host_idx), len(staged),
+                        "could not take them, or no canvas holds them); the device "
+                        "decode was skipped for those rows", len(host_idx), len(staged),
                         unischema_field.name)
             # host-decoded fallbacks are already in stored order; no flip
             fallbacks = [staged[i] for i in host_idx]
             if resize_to is not None:
-                import cv2
+                from petastorm_tpu.ops.crop import crop_resize_host
 
-                h, w = int(resize_to[0]), int(resize_to[1])
-                fallbacks = [
-                    f if f.shape[0] == h and f.shape[1] == w
-                    else cv2.resize(f, (w, h), interpolation=cv2.INTER_LINEAR)
-                    for f in fallbacks
-                ]
+                fallbacks = [crop_resize_host(f, boxes[i], size)
+                             for f, i in zip(fallbacks, host_idx)]
             parts.append(jnp.asarray(np.stack(fallbacks)))
             order.extend(host_idx)
         if len(parts) == 1:

@@ -58,6 +58,10 @@ _PHASES = {"decode.pack": "decode_pack_s", "decode.put": "decode_put_s",
            "decode.launch": "decode_launch_s", "h2d.stage": "h2d_stage_s",
            "h2d.put": "h2d_put_s", "h2d.wait": "h2d_wait_s"}
 
+#: the counters code below the loader adds to (``trace.count`` names)
+_COUNTS = ("decode_batches", "decode_launches", "decode_real_blocks",
+           "decode_canvas_blocks", "decode_host_rows", "crop_boxes")
+
 
 class PipelineStats:
     """Cheap per-stage counters for the loader pipeline (SURVEY.md §6: the reference
@@ -95,6 +99,16 @@ class PipelineStats:
     JAX keeps as they are, one device), cut into the columns on the device
     by ``jit_ptpu_slab_split``; the wait then blocks on that one transfer.
 
+    Device decode counts (``trace.count`` from the codec and ``ops/jpeg.py``):
+    ``decode_batches`` (decoded columns of batches), ``decode_launches`` (stage-2
+    programs launched: one a layout group, or one a canvas bucket),
+    ``decode_real_blocks`` and ``decode_canvas_blocks`` (8x8 coefficient blocks
+    the images hold, and the blocks those programs decode, padding included),
+    ``decode_host_rows`` (rows decoded on the host instead: the pool's cv2
+    fallbacks, among them the images no canvas holds in a row group of several
+    sizes) and ``crop_boxes`` (crop
+    boxes drawn under a ``random_resized`` or flipping policy).
+
     Under ``DataLoader(pack=...)``: ``pack_s`` is producer time packing
     documents into rows (chunking, placement, the token copy); ``pack_slots``,
     ``pack_pad_slots`` and ``pack_chunks`` count the slots, the padding slots and
@@ -102,9 +116,10 @@ class PipelineStats:
 
     ``decode_unsharded_batches`` counts staged-decode dispatches that ran on a
     SINGLE device although the configured sharding cuts the batch axis across
-    several — the undivisible-batch / local-mesh-derivation-failure fallbacks.
-    Nonzero on a pod means one chip is decoding for many; fix the batch size or
-    the sharding.
+    several — the undivisible-batch / local-mesh-derivation-failure fallbacks,
+    and every batch a ``device_decode_resize`` crop decodes in canvases (that
+    decode runs on one device). Nonzero on a pod means one chip is decoding for
+    many; fix the batch size or the sharding.
 
     The ``shm_*`` fields mirror the process pool's shared-memory wire gauges
     (``Reader.wire_stats()``, refreshed per reader delivery; all zero on thread/
@@ -128,7 +143,7 @@ class PipelineStats:
                  "shm_slabs_in_flight", "shm_bytes", "shm_fallbacks",
                  "shm_acquire_wait_s") + tuple(_PHASES.values()) + (
                      "h2d_bytes", "h2d_unwaited_outputs", "h2d_slab_puts", "pack_s",
-                     "pack_slots", "pack_pad_slots", "pack_chunks")
+                     "pack_slots", "pack_pad_slots", "pack_chunks") + _COUNTS
 
     def __init__(self):
         self.reset()
@@ -144,6 +159,11 @@ class PipelineStats:
         if field is not None:
             setattr(self, field, getattr(self, field) + seconds)
         self.h2d_bytes += nbytes
+
+    def add_count(self, name, n):
+        """A ``trace.count`` below the loader: add ``n`` to counter ``name``."""
+        if name in _COUNTS:
+            setattr(self, name, getattr(self, name) + n)
 
     def snapshot(self):
         return {field: round(getattr(self, field), 4) if field.endswith("_s")
@@ -338,7 +358,9 @@ class _LoaderObs:
 def _validate_decode_resize(resize, device_fields):
     """Normalize/validate ``device_decode_resize`` at construction: a misspelled dict
     key or a malformed target must fail HERE, not silently no-op and resurface later
-    as a mixed-size error telling the user to pass the option they already passed."""
+    as a mixed-size error telling the user to pass the option they already passed.
+    Returns a :class:`~petastorm_tpu.ops.crop.CropPolicy`, a dict of them by field,
+    or None."""
     if resize is None:
         return None
     if not device_fields:
@@ -346,28 +368,18 @@ def _validate_decode_resize(resize, device_fields):
             "device_decode_resize was given but the reader has no device-decoded "
             "fields — open it with decode_on_device=True (and an image-codec "
             "column) for the on-device resize to apply")
+    from petastorm_tpu.ops.crop import parse_policy
 
-    def check_target(t, label):
-        try:
-            h, w = int(t[0]), int(t[1])
-        except (TypeError, ValueError, IndexError):
-            raise ValueError(
-                "device_decode_resize%s must be an (h, w) pair, got %r" % (label, t))
-        if h <= 0 or w <= 0 or len(tuple(t)) != 2:
-            raise ValueError(
-                "device_decode_resize%s must be two positive ints, got %r" % (label, t))
-        return (h, w)
-
-    if isinstance(resize, dict):
-        known = set(device_fields or ())
+    known = set(device_fields or ())
+    if isinstance(resize, dict) and not ("size" in resize and "size" not in known):
         unknown = set(resize) - known
         if unknown:
             raise ValueError(
                 "device_decode_resize names %s, but the reader's device-decoded "
                 "fields are %s (is decode_on_device=True set, and are the names "
                 "spelled right?)" % (sorted(unknown), sorted(known)))
-        return {k: check_target(v, "[%r]" % k) for k, v in resize.items()}
-    return check_target(resize, "")
+        return {k: parse_policy(v, "[%r]" % k) for k, v in resize.items()}
+    return parse_policy(resize)
 
 
 class _HostBatcher:
@@ -689,6 +701,23 @@ class DataLoader:
         ``shuffling_queue_capacity``, ``device_shuffle_capacity``, ``pad_shapes``
         on the field and a batch split over processes, and ``state_dict()``
         raises: the part-filled batch is not checkpointed yet.
+    device_decode_resize : tuple, dict, optional
+        The delivered size of device-decoded image fields (``decode_on_device=True``
+        readers), so that a store of mixed sizes batches in one static shape:
+        ``(h, w)`` resizes each whole image; a policy dict such as ``{"size": [224,
+        224], "crop": "random_resized", "scale": [0.08, 1.0], "ratio": [0.75,
+        1.3333], "flip": 0.5}`` crops first, as torchvision's ``RandomResizedCrop``
+        (10 attempts, then the center crop) and a random horizontal flip do
+        (keys: ``size``; ``crop``, ``"whole"`` or ``"random_resized"``;
+        ``scale``; ``ratio``; ``flip``, a probability). Either may be given per
+        field, ``{field: ...}``. Boxes are drawn on the host from each image's
+        header size, keyed by the loader's ``seed`` (else the reader's) and the
+        batch number, and delivered beside the image as ``<field>_box`` (``(n,
+        5)`` int32: y0, x0, h, w, flip); the image decodes in a canvas bucket
+        and is cropped, resized (bilinear, ``cv2.resize`` INTER_LINEAR's
+        sampling) and flipped on the device (``jit_ptpu_jpeg_crop_resize``), on
+        one device whatever the ``sharding`` (``decode_unsharded_batches``). A
+        batch already at ``(h, w)`` decodes as without the option.
     trace : petastorm_tpu.trace.TraceRecorder, optional
         Records every pipeline stage (reader fetch, batch formation, decode
         dispatch, H2D, queue waits) as chrome-trace spans — the per-span view of
@@ -818,8 +847,8 @@ class DataLoader:
         self._shuffling_queue_capacity = shuffling_queue_capacity
         self._host_queue_size = host_queue_size
         self._pad_shapes = dict(pad_shapes) if pad_shapes else {}
-        # (h, w) or {field: (h, w)}: the on-device resize of device-decoded image
-        # columns, so that mixed-size stores batch with one static shape
+        # (h, w), a crop policy, or {field: either}: the on-device crop and resize
+        # of device-decoded image columns, so mixed-size stores batch in one shape
         resize = _validate_decode_resize(
             device_decode_resize, getattr(reader, "device_decode_fields", None))
         self._device_shuffle_capacity = int(device_shuffle_capacity or 0)
@@ -911,7 +940,8 @@ class DataLoader:
             sharding=sharding, staging=staging, pack=self._pack,
             schema=getattr(reader, "schema", None),
             decode_fields=getattr(reader, "device_decode_fields", None) or (),
-            resize=resize)
+            resize=resize,
+            seed=seed if seed is not None else getattr(reader, "seed", None))
         # consumer-watermark checkpointing (see state_dict): producer logs
         # (cumulative-delivered-rows, reader state) per delivery; the consumer
         # counts rows actually yielded; state_dict() returns the newest logged

@@ -16,8 +16,10 @@ the correctness oracle for the tests.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
+import math
 import os
 import struct
 import threading
@@ -336,14 +338,21 @@ def _blocks_to_plane(pixels, blocks_y, blocks_x):
     return jnp.transpose(p, (0, 2, 1, 3)).reshape(blocks_y * 8, blocks_x * 8)
 
 
-def _fancy_upsample2(plane, axis):
+def _fancy_upsample2(plane, axis, limit=None):
     """libjpeg 'fancy' 2x upsampling along ``axis``: triangle filter (3*near + far) / 4,
-    edges clamped — matches libjpeg/cv2 output much closer than pixel doubling."""
+    edges clamped — matches libjpeg/cv2 output much closer than pixel doubling.
+
+    ``limit`` (per image of an ``(n, h, w)`` stack) clamps each image at its own
+    last real sample, as libjpeg clamps at a component's downsampled size, where
+    the stack's edge lies past it (a canvas)."""
     import jax.numpy as jnp
 
     plane = jnp.moveaxis(plane, axis, 0)
     prev = jnp.concatenate([plane[:1], plane[:-1]], axis=0)
     nxt = jnp.concatenate([plane[1:], plane[-1:]], axis=0)
+    if limit is not None:
+        idx = jnp.arange(plane.shape[0]).reshape(-1, 1, 1)
+        nxt = jnp.where(idx >= jnp.reshape(limit, (1, -1, 1)) - 1, plane, nxt)
     even = (3.0 * plane + prev) * 0.25
     odd = (3.0 * plane + nxt) * 0.25
     out = jnp.stack([even, odd], axis=1).reshape((-1,) + plane.shape[1:])
@@ -410,11 +419,18 @@ def entropy_decode_jpeg_fast(data):
 
     if native.native_available():
         height, width, comps = native.jpeg_decode_coeffs_native(data)
+        # the spectral profile and kmax a row-group decode would give, so that a
+        # batch of per-image rows (mixed sizes) still ships narrowed
+        specmax = np.stack([native.jpeg_specmax_native(blocks.reshape(1, -1, 64))
+                            for blocks, _q, _h, _v in comps])
+        kmax = tuple(int(np.flatnonzero(row).max()) if row.any() else 0
+                     for row in specmax)
         planes = JpegPlanes(
             height=height,
             width=width,
             components=[JpegComponent(blocks, qtable, h, v)
                         for blocks, qtable, h, v in comps],
+            kmax=kmax, specmax=specmax,
         )
     else:
         planes = entropy_decode_jpeg(data)
@@ -470,6 +486,87 @@ def entropy_decode_jpeg_batch(blobs):
     return out
 
 
+def jpeg_size(blob):
+    """``(height, width)`` from a JPEG's frame header, or None where no frame header
+    comes before the first scan. A walk over the marker segments (whose lengths skip
+    APPn payloads such as an EXIF thumbnail's own frame); no entropy decode."""
+    n = len(blob)
+    if n < 4 or blob[0] != 0xFF or blob[1] != 0xD8:
+        return None
+    i = 2
+    while i + 9 <= n:
+        if blob[i] != 0xFF:
+            return None
+        marker = blob[i + 1]
+        if marker == 0xFF:  # fill byte
+            i += 1
+            continue
+        if 0xC0 <= marker <= 0xCF and marker not in (0xC4, 0xC8, 0xCC):
+            height, width = struct.unpack_from(">HH", blob, i + 5)
+            return height, width
+        if marker in (0xD9, 0xDA):
+            return None
+        i += 2 + struct.unpack_from(">H", blob, i + 2)[0]
+    return None
+
+
+class _StageJob:
+    __slots__ = ("fn", "out", "left", "error", "done")
+
+    def __init__(self, fn, n):
+        self.fn = fn
+        self.out = [None] * n
+        self.left = n
+        self.error = None
+        self.done = threading.Event()
+
+
+#: the per-image stage-1 tasks of every ``stage_images`` call in flight, oldest first
+_STAGE_TASKS = collections.deque()
+_STAGE_LOCK = threading.Lock()
+
+
+def stage_images(fn, items):
+    """``[fn(x) for x in items]``, the work shared among the threads that call this
+    at the same time, oldest call first.
+
+    A call queues one task per item at the tail of a process-wide queue, then runs
+    tasks from its head, whichever call queued them, until its own are all done.
+    The reader pool's workers stage row groups of mixed sizes image by image; this
+    way the row group claimed first is staged first, by every worker that is free,
+    and row groups come out one after another rather than in bursts of as many as
+    there are workers (which a step's p95 reads as a stall). The threads and the
+    work are the same; only the order differs. An exception raised by ``fn`` on this
+    call's items is raised here once they are all done."""
+    job = _StageJob(fn, len(items))
+    if not items:
+        return job.out
+    with _STAGE_LOCK:
+        _STAGE_TASKS.extend((job, i, x) for i, x in enumerate(items))
+    while not job.done.is_set():
+        with _STAGE_LOCK:
+            task = _STAGE_TASKS.popleft() if _STAGE_TASKS else None
+        if task is None:
+            job.done.wait()  # the rest of ours are running on other threads
+            break
+        owner, i, x = task
+        try:
+            owner.out[i] = owner.fn(x)
+        except Exception as e:  # noqa: BLE001 — re-raised on the owner's thread
+            error = e
+        else:
+            error = None
+        with _STAGE_LOCK:
+            if owner.error is None:
+                owner.error = error
+            owner.left -= 1
+            if owner.left == 0:
+                owner.done.set()
+    if job.error is not None:
+        raise job.error
+    return job.out
+
+
 # -- batched stage 2 (one device dispatch per image batch) -----------------------------
 
 
@@ -511,6 +608,64 @@ def _idct_scaled(scaled):
     return out[:n]
 
 
+def _unpack12(u8):
+    """(n, blocks, m*3) uint8 → (n, blocks, 2m) int32, 12-bit two's complement."""
+    import jax.numpy as jnp
+
+    triples = u8.reshape(u8.shape[0], u8.shape[1], -1, 3)
+    b0 = triples[..., 0].astype(jnp.int32)
+    b1 = triples[..., 1].astype(jnp.int32)
+    b2 = triples[..., 2].astype(jnp.int32)
+    lo = b0 | ((b1 & 0xF) << 8)
+    hi = (b1 >> 4) | (b2 << 4)
+    pair = jnp.stack([lo, hi], axis=-1)
+    pair = pair - ((pair & 0x800) << 1)  # sign-extend 12-bit
+    return pair.reshape(u8.shape[0], u8.shape[1], -1)
+
+
+def _unpack4(u8):
+    """(n, blocks, m) uint8 → (n, blocks, 2m) int32, 4-bit two's complement."""
+    import jax.numpy as jnp
+
+    b = u8.astype(jnp.int32)
+    lo = b & 0xF
+    hi = (b >> 4) & 0xF
+    pair = jnp.stack([lo, hi], axis=-1)
+    pair = pair - ((pair & 0x8) << 1)  # sign-extend 4-bit
+    return pair.reshape(u8.shape[0], u8.shape[1], -1)
+
+
+def _unpack_coefficients(coef, k_ship, split, packed):
+    """One component's shipped coefficients → ``(n, blocks, 64)`` natural order:
+    the split-pack slab tuple (``split`` its ``(k1, k2)``), a 12-bit pack
+    (``packed``) or int16, of the zigzag prefix ``k_ship`` when below 64."""
+    import jax.numpy as jnp
+
+    # HOST constant, deliberately: a device array closed over by a jitted
+    # program would be lowered via a D2H fetch at every new variant's compile
+    # (measured MINUTES when that fetch queues behind in-flight transfers)
+    unzig = np.asarray(UNZIGZAG)
+    if split is not None:
+        head, mid, tail = coef
+        parts = []
+        if head.shape[-1]:
+            parts.append(_unpack12(head))
+        if mid.shape[-1]:
+            parts.append(mid.astype(jnp.int32))
+        if tail.shape[-1]:
+            parts.append(_unpack4(tail))
+        coef = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+        if k_ship < 64:
+            coef = jnp.pad(coef, ((0, 0), (0, 0), (0, 64 - k_ship)))
+        return jnp.take(coef, unzig, axis=-1)
+    if packed:
+        coef = _unpack12(coef)
+    if k_ship < 64:
+        coef = jnp.pad(coef, ((0, 0), (0, 0), (0, 64 - k_ship)))
+        coef = jnp.take(coef, unzig, axis=-1)
+    return coef
+
+
 @functools.lru_cache(maxsize=32)
 def _batched_stage2(layout, ks=None, packed=None, split=None, spmd=None):
     """Layout-specialized jitted decoder: stacked coefficient arrays → (n, h, w, 3)
@@ -549,32 +704,6 @@ def _batched_stage2(layout, ks=None, packed=None, split=None, spmd=None):
     height, width, comp_layout = layout
     hmax = max(h for h, _v, _by, _bx in comp_layout)
     vmax = max(v for _h, v, _by, _bx in comp_layout)
-    # HOST constant, deliberately: a device array closed over by ``fn`` would be
-    # lowered via a D2H fetch at every new layout variant's compile — measured
-    # MINUTES when that fetch queues behind in-flight transfers on a degraded
-    # service (r4 bench hang, faulthandler: _array_mlir_constant_handler → _value)
-    unzig = np.asarray(UNZIGZAG)
-
-    def unpack12(u8):
-        # (n, blocks, m*3) uint8 → (n, blocks, 2m) int32, 12-bit two's complement
-        triples = u8.reshape(u8.shape[0], u8.shape[1], -1, 3)
-        b0 = triples[..., 0].astype(jnp.int32)
-        b1 = triples[..., 1].astype(jnp.int32)
-        b2 = triples[..., 2].astype(jnp.int32)
-        lo = b0 | ((b1 & 0xF) << 8)
-        hi = (b1 >> 4) | (b2 << 4)
-        pair = jnp.stack([lo, hi], axis=-1)
-        pair = pair - ((pair & 0x800) << 1)  # sign-extend 12-bit
-        return pair.reshape(u8.shape[0], u8.shape[1], -1)
-
-    def unpack4(u8):
-        # (n, blocks, m) uint8 → (n, blocks, 2m) int32, 4-bit two's complement
-        b = u8.astype(jnp.int32)
-        lo = b & 0xF
-        hi = (b >> 4) & 0xF
-        pair = jnp.stack([lo, hi], axis=-1)
-        pair = pair - ((pair & 0x8) << 1)  # sign-extend 4-bit
-        return pair.reshape(u8.shape[0], u8.shape[1], -1)
 
     # the program's name in a profile (``jit_ptpu_jpeg_stage2``), and its
     # phases as named scopes: unpack, dequant_idct, upsample, color
@@ -587,28 +716,11 @@ def _batched_stage2(layout, ks=None, packed=None, split=None, spmd=None):
             # zigzag prefix when this component was truncated, or the 12-bit uint8
             # pack of either, or the split-pack slab tuple; qtab: (n, 64) int32
             # (per-image: quality may vary)
-            k_ship = ks[ci] if ks is not None else 64
             with jax.named_scope("unpack"):
-                if split is not None and split[ci] is not None:
-                    head, mid, tail = coef
-                    parts = []
-                    if head.shape[-1]:
-                        parts.append(unpack12(head))
-                    if mid.shape[-1]:
-                        parts.append(mid.astype(jnp.int32))
-                    if tail.shape[-1]:
-                        parts.append(unpack4(tail))
-                    coef = parts[0] if len(parts) == 1 \
-                        else jnp.concatenate(parts, axis=-1)
-                    if k_ship < 64:
-                        coef = jnp.pad(coef, ((0, 0), (0, 0), (0, 64 - k_ship)))
-                    coef = jnp.take(coef, unzig, axis=-1)
-                else:
-                    if packed is not None and packed[ci]:
-                        coef = unpack12(coef)
-                    if ks is not None and ks[ci] < 64:
-                        coef = jnp.pad(coef, ((0, 0), (0, 0), (0, 64 - ks[ci])))
-                        coef = jnp.take(coef, unzig, axis=-1)
+                coef = _unpack_coefficients(
+                    coef, ks[ci] if ks is not None else 64,
+                    split[ci] if split is not None else None,
+                    packed is not None and packed[ci])
             with jax.named_scope("dequant_idct"):
                 scaled = coef.astype(jnp.float32) * qtab.astype(jnp.float32)[:, None, :]
                 pix = _idct_scaled(scaled.reshape(n * by * bx, 64))
@@ -696,23 +808,6 @@ def stack_jpeg_coefficients(planes_list):
     return tuple(coeffs), tuple(qtabs)
 
 
-def resize_image_batch(img, target):
-    """(n, h, w, c) uint8 device batch → (n, *target, c), bilinear, no antialiasing
-    (tracks ``cv2.resize(..., INTER_LINEAR)``, the reference host resize idiom —
-    identical sampling grid on upscale, same no-prefilter choice on downscale; values
-    differ from cv2 only by float rounding). No-op when already at ``target``."""
-    import jax
-    import jax.numpy as jnp
-
-    h, w = int(target[0]), int(target[1])
-    if img.shape[1] == h and img.shape[2] == w:
-        return img
-    out = jax.image.resize(
-        img.astype(jnp.float32), (img.shape[0], h, w, img.shape[3]),
-        method="linear", antialias=False)
-    return jnp.clip(jnp.round(out), 0, 255).astype(jnp.uint8)
-
-
 @functools.lru_cache(maxsize=None)
 def _to_bgr_program():
     import jax
@@ -732,17 +827,12 @@ def to_stored_order(img, grayscale):
         return _to_bgr_program()(img, grayscale)
 
 
-def decode_jpeg_batch(planes_list, resize_to=None, sharding=None):
+def decode_jpeg_batch(planes_list, sharding=None):
     """Batched stage 2: list of :class:`JpegPlanes` → (n, h, w, 3) uint8 ``jax.Array``.
 
-    Without ``resize_to`` all images must share height/width (resize on write, or use
-    padded-shape fields); mixed chroma samplings are grouped and decoded per-group,
-    then re-gathered in input order on device.
-
-    ``resize_to=(h, w)`` lifts the uniform-size requirement for mixed-size stores
-    (raw ImageNet-style corpora): each same-layout group decodes at its stored size
-    and is bilinearly resized ON DEVICE to the target (``resize_image_batch``), so
-    every batch leaves with one static shape regardless of composition.
+    All images must share height/width (resize on write, or decode mixed sizes
+    through :func:`decode_jpeg_crop_batch`); mixed chroma samplings are grouped and
+    decoded per-group, then re-gathered in input order on device.
 
     ``sharding``: optional batch-axis sharding (e.g. the loader's). Coefficient slabs
     are placed across its devices before the stage-2 jit, so decode runs SPMD — one
@@ -750,31 +840,30 @@ def decode_jpeg_batch(planes_list, resize_to=None, sharding=None):
     (single-layout batches; mixed-layout re-gathers may reshard)."""
     import jax.numpy as jnp
 
+    from petastorm_tpu.trace import count
+
     if not planes_list:
         raise ValueError("decode_jpeg_batch: empty batch")
     sizes = {(p.height, p.width) for p in planes_list}
-    if len(sizes) > 1 and resize_to is None:
+    if len(sizes) > 1:
         raise ValueError(
             "decode_jpeg_batch requires a uniform image size per batch, got %s. "
-            "Pass resize_to=(h, w) (DataLoader(device_decode_resize=...)) to decode "
-            "mixed sizes with an on-device resize, resize on write, or decode on "
+            "Pass DataLoader(device_decode_resize=...) to decode mixed sizes in "
+            "canvases and resize them on the device, resize on write, or decode on "
             "host via CompressedImageCodec.decode." % sizes
         )
+    count("decode_batches")
     groups = {}
     for i, p in enumerate(planes_list):
         groups.setdefault(_layout_key(p), []).append(i)
     if len(groups) == 1:
         layout, = groups
-        out = _decode_group(layout, planes_list, sharding=sharding)
-        return resize_image_batch(out, resize_to) if resize_to is not None else out
+        return _decode_group(layout, planes_list, sharding=sharding)
     parts = []
     order = []
     for layout, indices in groups.items():
-        group = [planes_list[i] for i in indices]
-        decoded = _decode_group(layout, group, sharding=sharding)
-        if resize_to is not None:
-            decoded = resize_image_batch(decoded, resize_to)
-        parts.append(decoded)
+        parts.append(_decode_group(layout, [planes_list[i] for i in indices],
+                                   sharding=sharding))
         order.extend(indices)
     stacked = jnp.concatenate(parts, axis=0)
     inverse = np.argsort(np.asarray(order))
@@ -804,6 +893,12 @@ def _truncation_ks(group, layout=None):
     if any(km is None for km in kms):
         return None
     ncomp = len(group[0].components)
+    return _sticky_ks([max(km[c] for km in kms) + 1 for c in range(ncomp)], layout)
+
+
+def _sticky_ks(kcounts, layout=None):
+    """Per-component coefficient counts → their ``_K_BUCKETS``, grown to the
+    largest seen for ``layout``; None when every component ships all 64."""
 
     def bucket(kcount):
         for b in _K_BUCKETS:
@@ -811,7 +906,7 @@ def _truncation_ks(group, layout=None):
                 return b
         return 64
 
-    ks = [bucket(max(km[c] for km in kms) + 1) for c in range(ncomp)]
+    ks = [bucket(k) for k in kcounts]
     if layout is not None:
         with _STICKY_KS_LOCK:
             prev = _STICKY_KS.get(layout)
@@ -873,15 +968,12 @@ def _batch_specmax(group):
     return vecs[0] if len(vecs) == 1 else np.maximum.reduce(vecs)
 
 
-def _round_up4(x):
-    return (x + 3) & ~3
-
-
-def _split_points(profile, ks, layout):
+def _split_points(profile, ks, layout, align=4):
     """Per-component spectral split ``(k1, k2)`` (or None = plain pack12 is as good)
     from the measured per-position ranges. Positions ≥ k1 fit int8, positions ≥ k2
-    fit 4 bits; both bucketed to multiples of 4 (pack alignment + bounded recompiles)
-    and sticky-grown per layout."""
+    fit 4 bits; both bucketed to multiples of ``align`` (pack alignment + bounded
+    recompiles; 8 keeps every slab's rows whole 32-bit words) and sticky-grown per
+    layout."""
     ncomp = profile.shape[0]
     out = []
     for ci in range(ncomp):
@@ -894,8 +986,8 @@ def _split_points(profile, ks, layout):
                 j -= 1
             return j
 
-        k1 = min(_round_up4(low_bound(127)), k)
-        k2 = min(max(_round_up4(low_bound(7)), k1), k)
+        k1 = min(-(-low_bound(127) // align) * align, k)
+        k2 = min(max(-(-low_bound(7) // align) * align, k1), k)
         out.append((k1, k2))
     with _STICKY_KS_LOCK:
         prev = _STICKY_SPLIT.get(layout)
@@ -962,8 +1054,12 @@ def _decode_group(layout, group, sharding=None):
     ``sharding``: optional batch-axis sharding; staged inputs are placed across its
     devices so dequant+IDCT+upsample+color runs SPMD (one shard of the batch per
     device) instead of on the default device only."""
-    from petastorm_tpu.trace import phase
+    from petastorm_tpu.trace import count, phase
 
+    blocks = len(group) * sum(by * bx for _h, _v, by, bx in layout[2])
+    count("decode_real_blocks", blocks)
+    count("decode_canvas_blocks", blocks)
+    count("decode_launches")
     with phase("decode.pack") as span:
         variant, shipped, qtabs, shipped_bytes = _pack_group(layout, group, span)
     with phase("decode.put", bytes=shipped_bytes):
@@ -1060,8 +1156,25 @@ def _pack_group(layout, group, span=None):
             np.maximum.reduce([native.jpeg_specmax_native(c[ci][s:s + m])
                                for c, _q, s, m in runs])
             for ci in range(ncomp)])
+    sources = [(c, s, m) for c, _q, s, m in runs]
+    variant, shipped, shipped_bytes = _narrow(
+        layout, ks, profile,
+        lambda tiers: native.jpeg_pack_runs_native(sources, tiers, threads),
+        sum(n * by * bx * 64 * 2 for _h, _v, by, bx in layout[2]))
+    return variant, shipped, qtabs, shipped_bytes
+
+
+def _narrow(layout, ks, profile, pack, raw_bytes, align=4):
+    """Pack every component at its narrowest exact tier: the zigzag prefix ``ks``,
+    the split points :func:`_split_points` reads off ``profile``, else 12 bits,
+    sticky per ``(layout, component)``; a range failure widens that component's
+    tier (split → 12-bit → int16) for good; ``align`` rounds the split points.
+    ``pack(tiers)`` packs the components whose tier is not None, None where one
+    failed. Returns ``(variant, shipped,
+    shipped_bytes)``, ``variant`` the ``(ks, packed, split)`` that reads them."""
+    ncomp = len(profile)
     split = [None] * ncomp
-    candidate = _split_points(profile, ks, layout)
+    candidate = _split_points(profile, ks, layout, align)
     with _STICKY_KS_LOCK:
         for ci, s in enumerate(candidate):
             if s is not None and (layout, ci) not in _SPLIT_DISABLED:
@@ -1070,8 +1183,7 @@ def _pack_group(layout, group, span=None):
                   for ci in range(ncomp)]
     kk = ks if ks is not None else (64,) * ncomp
     tiers = [(kk[ci], split[ci], packed[ci]) for ci in range(ncomp)]
-    sources = [(c, s, m) for c, _q, s, m in runs]
-    shipped = native.jpeg_pack_runs_native(sources, tiers, threads)
+    shipped = pack(tiers)
     for ci in range(ncomp):
         while shipped[ci] is None:  # a range failure: one tier wider, sticky
             with _STICKY_KS_LOCK:
@@ -1085,14 +1197,429 @@ def _pack_group(layout, group, span=None):
                     packed[ci] = False
                     _PACK12_DISABLED.add((layout, ci))
             tiers[ci] = (kk[ci], split[ci], packed[ci])
-            shipped[ci] = native.jpeg_pack_runs_native(
-                sources, [t if c == ci else None for c, t in enumerate(tiers)],
-                threads)[ci]
-    raw_bytes = sum(n * by * bx * 64 * 2 for _h, _v, by, bx in layout[2])
+            shipped[ci] = pack([t if c == ci else None for c, t in enumerate(tiers)])[ci]
     shipped_bytes = sum(
         sum(a.nbytes for a in s) if isinstance(s, tuple) else s.nbytes
         for s in shipped)
     with _STICKY_KS_LOCK:
         _TRANSFER_BYTES["raw"] += raw_bytes
         _TRANSFER_BYTES["shipped"] += shipped_bytes
-    return (ks, tuple(packed), tuple(split)), tuple(shipped), qtabs, shipped_bytes
+    return (ks, tuple(packed), tuple(split)), tuple(shipped), shipped_bytes
+
+
+# -- mixed sizes: canvas buckets, cropped and resized on the chip ----------------------
+
+#: Canvas edges, in pixels. A batch of mixed sizes decodes in square canvases: an
+#: image takes the smallest edge that holds its MCU-padded height and width, so one
+#: program runs per (sampling, canvas) bucket instead of one per size. 512 holds
+#: both orientations of ImageNet's common 500x375; 1,024 and 2,048 take the tail.
+#: In a row group of several sizes, stage 1 sends an image no canvas holds to
+#: cv2 (:func:`canvas_holds`; ``decode_host_rows``); one that still arrives (from
+#: a row group of its one size) decodes in its own size.
+CANVAS_EDGES = (512, 1024, 2048)
+
+#: the fewest rows a bucket pads to: its rows are a compiled shape, so it pads to
+#: a power of two (at most the batch's), and the few images of a tail bucket vary
+#: batch to batch
+_MIN_BUCKET_ROWS = 8
+
+#: Per component, the flat coefficient array's capacity in blocks, and half as
+#: much again in an overflow array that ships only when a batch needs it (else a
+#: zero array already on the device takes its place). The capacity grows only
+#: when a batch outgrows both, to ``_CAP_HEADROOM`` over it rounded up to 8-15 x
+#: 2^e: batches that fit reuse the compiled programs. A quarter of overflow let
+#: a batch of rare tail images past the warm-up grow it (a recompile in the
+#: timed window, on one seed of the original-size ImageNet cell); a half leaves
+#: a batch 1.65 times the first one's blocks in. Guarded by _STICKY_KS_LOCK.
+_CAPS: dict = {}
+_CAP_HEADROOM = 1.1
+
+
+def _capacity(key, need):
+    """The capacity (blocks) of flat array ``key`` for a batch of ``need``."""
+    with _STICKY_KS_LOCK:
+        cap = _CAPS.get(key, 0)
+        if need > cap + cap // 2:
+            want = int(need * _CAP_HEADROOM) + 1
+            shift = max(0, want.bit_length() - 4)
+            cap = -(-want >> shift) << shift
+            _CAPS[key] = cap
+    return cap
+
+
+def _mcu_size(planes):
+    """The image's MCU-padded ``(height, width)`` in pixels."""
+    c = planes.components[0]
+    hmax = max(x.h_samp for x in planes.components)
+    vmax = max(x.v_samp for x in planes.components)
+    return (c.blocks.shape[0] * 8 * vmax // c.v_samp,
+            c.blocks.shape[1] * 8 * hmax // c.h_samp)
+
+
+def _bucket(planes, exact):
+    """``(sampling, (canvas_h, canvas_w))`` of an image: its own MCU-padded size
+    when the batch has one layout (``exact``), else the smallest square of
+    ``CANVAS_EDGES`` that holds it; None when none does."""
+    sampling = tuple((c.h_samp, c.v_samp) for c in planes.components)
+    ph, pw = _mcu_size(planes)
+    if exact:
+        return sampling, (ph, pw)
+    for edge in CANVAS_EDGES:
+        if ph <= edge and pw <= edge:
+            return sampling, (edge, edge)
+    return None
+
+
+def canvas_holds(image):
+    """True when a canvas of :data:`CANVAS_EDGES` holds the image, given as its
+    :class:`JpegPlanes` or its ``(height, width)`` (:func:`jpeg_size`). Every MCU
+    size divides the largest edge, so MCU padding takes no size that fits past it."""
+    height, width = (image.height, image.width) if isinstance(image, JpegPlanes) \
+        else image
+    return max(height, width) <= CANVAS_EDGES[-1]
+
+
+def _grids(sampling, canvas):
+    """Each component's ``(blocks_y, blocks_x)`` in a canvas."""
+    hmax = max(h for h, _v in sampling)
+    vmax = max(v for _h, v in sampling)
+    return tuple((canvas[0] * v // (8 * vmax), canvas[1] * h // (8 * hmax))
+                 for h, v in sampling)
+
+
+def _tap_weights(start, length, out_len, src_len, flip=None):
+    """``(n, out_len, src_len)`` float32 weights of ``cv2.resize`` INTER_LINEAR
+    (half-pixel centres, no antialiasing) of each image's ``length`` source pixels
+    from ``start``, its taps clamped to them; ``flip`` (per image, nonzero: mirror)
+    reverses the output positions."""
+    import jax.numpy as jnp
+
+    idx = jnp.arange(out_len, dtype=jnp.float32)[None, :]
+    if flip is not None:
+        idx = jnp.where(flip[:, None] > 0, out_len - 1 - idx, idx)
+    start, length = start[:, None], length[:, None]
+    src = (idx + 0.5) * (length.astype(jnp.float32) / out_len) - 0.5
+    i0 = jnp.floor(src)
+    frac = src - i0
+    i0 = i0.astype(jnp.int32)
+    edge = (i0 < 0) | (i0 >= length - 1)
+    frac = jnp.where(edge, 0.0, frac)
+    i0 = jnp.clip(i0, 0, length - 1)
+    i1 = jnp.minimum(i0 + 1, length - 1)
+    cols = jnp.arange(src_len, dtype=jnp.int32)[None, None, :]
+    return ((1.0 - frac)[..., None] * (cols == (start + i0)[..., None])
+            + frac[..., None] * (cols == (start + i1)[..., None]))
+
+
+def _crop_resample(wy, img, wx):
+    """``(n, oh, h)`` row weights and ``(n, ow, w)`` column weights over ``(n, h,
+    w, c)`` images → ``(n, oh, ow, c)`` float32: two float32 matmuls at HIGHEST
+    precision."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    out = jnp.einsum("nrh,nhwc->nrwc", wy, img, precision=lax.Precision.HIGHEST)
+    return jnp.einsum("nrwc,nsw->nrsc", out, wx, precision=lax.Precision.HIGHEST)
+
+
+#: a canvas bucket's table of per-image int32 columns: height, width, the output
+#: row it lands in, its box (y0, x0, h, w, flip), then per component its first
+#: block in the flat array and its block grid, then per component its qtable
+_TABLE_HEAD = 8
+
+
+def _slab_dtypes(variant, ci):
+    """The dtypes of component ``ci``'s shipped slabs under ``variant``."""
+    ks, packed, split = variant
+    if split is not None and split[ci] is not None:
+        return (np.uint8, np.int8, np.uint8)
+    return (np.uint8,) if packed is not None and packed[ci] else (np.int16,)
+
+
+@functools.lru_cache(maxsize=None)
+def _crop_program(sampling, canvas, rows, variant, out_hw, grayscale):
+    """``jit_ptpu_jpeg_crop_resize``: one canvas bucket of a mixed-size batch, from
+    the batch's flat coefficient arrays to its rows of the delivered images.
+
+    Each shipped slab arrives as rows of 32-bit words (the flat array, and its
+    overflow beside it). Per image of the bucket (``rows`` of them, padding
+    included; a table row each) the program gathers its real blocks into the canvas
+    (``canvas`` scope), unpacks, dequantizes and inverse-DCTs them, upsamples
+    chroma clamped at the image's own edge, converts to RGB, and resamples its box
+    to ``out_hw`` with separable bilinear weights built from the box
+    (``crop_resize`` scope: two float32 matmuls at HIGHEST precision; the flip
+    folds into the column weights). The result, uint8 in stored order (BGR, or the
+    one plane of a grayscale field), is scattered into the batch's output rows
+    (``acc``)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    ch, cw = canvas
+    oh, ow = out_hw
+    hmax = max(h for h, _v in sampling)
+    vmax = max(v for _h, v in sampling)
+    grids = _grids(sampling, canvas)
+    nc = len(sampling)
+    ks, packed, split = variant
+
+    def gather(main, over, idx, dtype, blocks):
+        width = main.shape[1] * 4 // np.dtype(dtype).itemsize
+        if not width:
+            return jnp.zeros((rows, blocks, 0), dtype)
+        words = jnp.take(jnp.concatenate([main, over]), idx, axis=0)
+        return lax.bitcast_convert_type(words, dtype).reshape(rows, blocks, width)
+
+    def ptpu_jpeg_crop_resize(flat, overflow, table, acc):
+        height, width, dest = table[:, 0], table[:, 1], table[:, 2]
+        y0, x0, bh, bw, flip = (table[:, 3 + j] for j in range(5))
+        planes = []
+        for ci, ((hs, vs), (cby, cbx)) in enumerate(zip(sampling, grids)):
+            first, by, bx = (table[:, _TABLE_HEAD + 3 * ci + j] for j in range(3))
+            q0 = _TABLE_HEAD + 3 * nc + 64 * ci
+            with jax.named_scope("canvas"):
+                yy = jnp.arange(cby, dtype=jnp.int32)[None, :, None]
+                xx = jnp.arange(cbx, dtype=jnp.int32)[None, None, :]
+                valid = (yy < by[:, None, None]) & (xx < bx[:, None, None])
+                idx = jnp.where(valid, first[:, None, None] + yy * bx[:, None, None]
+                                + xx, 0).reshape(-1)
+                coef = tuple(gather(m, o, idx, dt, cby * cbx) for m, o, dt in
+                             zip(flat[ci], overflow[ci], _slab_dtypes(variant, ci)))
+            with jax.named_scope("unpack"):
+                coef = _unpack_coefficients(
+                    coef if len(coef) > 1 else coef[0],
+                    ks[ci] if ks is not None else 64,
+                    split[ci] if split is not None else None,
+                    packed is not None and packed[ci])
+                coef = jnp.where(valid.reshape(rows, -1, 1), coef, 0)
+            with jax.named_scope("dequant_idct"):
+                scaled = coef.astype(jnp.float32) \
+                    * table[:, None, q0:q0 + 64].astype(jnp.float32)
+                pix = _idct_scaled(scaled.reshape(-1, 64))
+                pix = jnp.clip(jnp.round(pix), 0.0, 255.0)
+            with jax.named_scope("upsample"):
+                plane = pix.reshape(rows, cby, cbx, 8, 8)
+                plane = jnp.transpose(plane, (0, 1, 3, 2, 4)).reshape(
+                    rows, cby * 8, cbx * 8)
+                limits = ((height * vs + vmax - 1) // vmax,
+                          (width * hs + hmax - 1) // hmax)
+                for axis, r, lim in ((1, vmax // vs, limits[0]),
+                                     (2, hmax // hs, limits[1])):
+                    if r == 2:
+                        plane = _fancy_upsample2(plane, axis, lim)
+                    elif r > 1:
+                        plane = jnp.repeat(plane, r, axis=axis)
+                planes.append(plane[:, :ch, :cw])
+        with jax.named_scope("color"):
+            img = planes[0][..., None] if nc == 1 else ycbcr_to_rgb(*planes)
+            img = jnp.clip(jnp.round(img), 0.0, 255.0)
+        with jax.named_scope("crop_resize"):
+            out = _crop_resample(_tap_weights(y0, bh, oh, ch), img,
+                                 _tap_weights(x0, bw, ow, cw, flip))
+            out = jnp.clip(jnp.round(out), 0.0, 255.0).astype(jnp.uint8)
+            if grayscale:
+                out = out[..., 0]
+            elif nc == 1:
+                out = jnp.broadcast_to(out, out.shape[:3] + (3,))
+            else:
+                out = out[..., ::-1]
+        return acc.at[dest].set(out, mode="drop")
+
+    return jax.jit(ptpu_jpeg_crop_resize)
+
+
+@functools.lru_cache(maxsize=32)
+def _device_zeros(shape, dtype):
+    """A zero array on the default device, made once: the output a batch's first
+    bucket scatters into, and the overflow a batch that fits does not ship. Put
+    from the host, so that it compiles nothing."""
+    import jax
+
+    return jax.device_put(np.zeros(shape, dtype))
+
+
+_ZERO_BLOCKS = np.zeros((0, 1, 64), np.int16)
+
+
+def _zero_blocks(m):
+    """``(m, 1, 64)`` int16 zeros, from one buffer that grows."""
+    global _ZERO_BLOCKS
+    if len(_ZERO_BLOCKS) < m:
+        _ZERO_BLOCKS = np.zeros((max(m, 2 * len(_ZERO_BLOCKS)), 1, 64), np.int16)
+    return _ZERO_BLOCKS
+
+
+def _block_runs(images, ci):
+    """Component ``ci`` of ``images`` as runs of ``(m, 1, 64)`` int16 blocks:
+    ``[(src, start, count), ...]``, each row-group row a slice of its parent's
+    stacked buffer (consecutive rows of one parent merge), each other row its own."""
+    runs = []
+    for p in images:
+        ref = p.batch_ref
+        comp = p.components[ci]
+        nb = comp.blocks.shape[0] * comp.blocks.shape[1]
+        if ref is not None:
+            parent = ref[0][ci]
+            last = runs[-1] if runs else None
+            if last is not None and last[3] is parent and last[1] + last[2] == ref[2] * nb:
+                runs[-1] = (last[0], last[1], last[2] + nb, parent)
+            else:
+                runs.append((parent.reshape(-1, 1, 64), ref[2] * nb, nb, parent))
+            continue
+        src = np.ascontiguousarray(comp.blocks, dtype=np.int16).reshape(-1, 1, 64)
+        runs.append((src, 0, nb, None))
+    return [(src, start, count) for src, start, count, _p in runs]
+
+
+def _profile(p, ci):
+    """Image ``p``'s per-zigzag-position max |coefficient| of component ``ci``."""
+    from petastorm_tpu.ops import native
+
+    if p.specmax is not None:
+        return p.specmax[ci]
+    return native.jpeg_specmax_native(
+        np.ascontiguousarray(p.components[ci].blocks, dtype=np.int16).reshape(1, -1, 64))
+
+
+def _words(slab):
+    """A packed slab's rows as 32-bit words (a view)."""
+    return slab.reshape(slab.shape[0], -1).view(np.uint32)
+
+
+def _pack_flat(images, ncomp, threads):
+    """Every image's real blocks, component by component, in one flat array each
+    (the images in order), zero-padded to its capacity (:func:`_capacity`; blocks
+    past it in an overflow array of half of it) and narrowed like a layout
+    group's, with split points on 8 so that every slab's rows are 32-bit words.
+
+    Returns ``(variant, flat, overflow, offsets, shipped_bytes, real_blocks)``:
+    per component a tuple of ``(capacity, words)`` uint32 slabs, and of the overflow
+    slabs (None where the batch fits), and each image's first block
+    (``offsets[ci][j]``)."""
+    from petastorm_tpu.ops import native
+
+    key = ("canvas", ncomp)
+    offsets, runs, caps, sizes_ = [], [], [], []
+    real = 0
+    for ci in range(ncomp):
+        sizes = np.array([math.prod(p.components[ci].blocks.shape[:2])
+                          if len(p.components) > ci else 0 for p in images], np.int64)
+        offsets.append(np.cumsum(sizes) - sizes)
+        total = int(sizes.sum())
+        real += total
+        cap = _capacity((key, ci), total)
+        size = cap if total <= cap else cap + cap // 2
+        run = _block_runs([p for p in images if len(p.components) > ci], ci)
+        if size > total:
+            run.append((_zero_blocks(size - total), 0, size - total))
+        runs.append(run)
+        caps.append(cap)
+        sizes_.append(size)
+    if not native.native_available():
+        shipped = [np.concatenate([src[s:s + m] for src, s, m in run]) for run in runs]
+        variant = (None, None, None)
+    else:
+        profile = np.stack([
+            np.maximum.reduce([_profile(p, ci) for p in images if len(p.components) > ci])
+            for ci in range(ncomp)])
+        kcounts = [int(np.flatnonzero(row).max()) + 1 if row.any() else 1
+                   for row in profile]
+        ks = _sticky_ks(kcounts, key)
+
+        def pack(tiers):
+            return [None if t is None else native.jpeg_pack_runs_native(
+                [((src,), s, m) for src, s, m in runs[ci]], [t], threads)[0]
+                for ci, t in enumerate(tiers)]
+
+        variant, shipped, _nbytes = _narrow(key, ks, profile, pack,
+                                            sum(sizes_) * 128, align=8)
+    flat, overflow, shipped_bytes = [], [], 0
+    for ci, s in enumerate(shipped):
+        slabs = tuple(_words(a) for a in (s if isinstance(s, tuple) else (s,)))
+        shipped_bytes += sum(a.nbytes for a in slabs)
+        flat.append(tuple(a[:caps[ci]] for a in slabs))
+        overflow.append(tuple(a[caps[ci]:] for a in slabs)
+                        if sizes_[ci] > caps[ci] else None)
+    return variant, flat, overflow, offsets, shipped_bytes, real
+
+
+def decode_jpeg_crop_batch(planes_list, boxes, size, grayscale=False):
+    """Mixed-size stage 2 with a crop and resize: :class:`JpegPlanes` rows and
+    their ``(n, 5)`` int32 boxes (``y0, x0, h, w, flip``; see
+    :mod:`petastorm_tpu.ops.crop`) → one ``(m, *size, 3)`` uint8 device array in
+    stored (BGR) order, or ``(m, *size)`` for a ``grayscale`` field.
+
+    The rows group by (sampling, canvas) (:data:`CANVAS_EDGES`; a batch of one
+    layout, and an image no canvas holds, decode in their own size, with no
+    padding). Their real coefficient
+    blocks ship as one flat array per component (``decode.pack`` then
+    ``decode.put``, ``canvas=`` naming each bucket's canvas and images), and one
+    ``jit_ptpu_jpeg_crop_resize`` launch a bucket (``decode.launch``) decodes the
+    bucket in its canvases, crops, resizes and flips each image by its box and
+    writes its rows of the output, in order.
+    """
+    from petastorm_tpu.trace import count, phase
+
+    boxes = np.asarray(boxes, np.int32)
+    exact = len({_layout_key(p) for p in planes_list}) == 1
+    buckets = {}
+    for i, p in enumerate(planes_list):
+        buckets.setdefault(_bucket(p, exact) or _bucket(p, True), []).append(i)
+    count("decode_batches")
+    n = len(planes_list)
+    order = [i for rows in buckets.values() for i in rows]
+    images = [planes_list[i] for i in order]
+    ncomp = max(len(p.components) for p in images)
+    batch_rows = 1 << (n - 1).bit_length()
+    desc = ",".join("%dx%d:%d" % (c[0], c[1], len(r)) for (_s, c), r in buckets.items())
+    with phase("decode.pack", canvas=desc) as span:
+        threads = _pack_threads(len(images))
+        if span is not None:
+            span.set_metadata(threads=threads)
+        variant, flat, overflow, offsets, shipped_bytes, real = _pack_flat(
+            images, ncomp, threads)
+        at = {i: j for j, i in enumerate(order)}
+        tables, programs, canvas_blocks = [], [], 0
+        for (sampling, canvas), rows in buckets.items():
+            nc = len(sampling)
+            n_pad = min(batch_rows,
+                        max(_MIN_BUCKET_ROWS, 1 << (len(rows) - 1).bit_length()))
+            table = np.zeros((n_pad, _TABLE_HEAD + 67 * nc), np.int32)
+            table[:, 2] = n  # padding rows land nowhere,
+            table[:, 5:7] = 1  # from a 1x1 box of an empty canvas
+            for r, i in enumerate(rows):
+                p = planes_list[i]
+                table[r, :3] = p.height, p.width, i
+                table[r, 3:8] = boxes[i]
+                for ci, c in enumerate(p.components):
+                    col = _TABLE_HEAD + 3 * ci
+                    table[r, col:col + 3] = (offsets[ci][at[i]], c.blocks.shape[0],
+                                             c.blocks.shape[1])
+                    q0 = _TABLE_HEAD + 3 * nc + 64 * ci
+                    table[r, q0:q0 + 64] = c.qtable
+            tables.append(table)
+            programs.append((sampling, canvas, n_pad,
+                             tuple(x[:nc] if x is not None else None for x in variant)))
+            canvas_blocks += n_pad * sum(y * x for y, x in _grids(sampling, canvas))
+    count("decode_real_blocks", real)
+    count("decode_canvas_blocks", canvas_blocks)
+    shipped = (tuple(flat), tuple(o for o in overflow if o is not None), tuple(tables))
+    with phase("decode.put",
+               bytes=shipped_bytes + sum(t.nbytes for t in tables)):
+        (flat, shipped_over, tables), _spmd = _stage_inputs(shipped, None, len(images))
+    shipped_over = iter(shipped_over)
+    overflow = tuple(next(shipped_over) if o is not None else tuple(
+        _device_zeros((a.shape[0] // 2, a.shape[1]), np.uint32) for a in f)
+        for o, f in zip(overflow, flat))
+    out = _device_zeros((n,) + tuple(size) + (() if grayscale else (3,)),
+                        np.uint8)
+    for (sampling, canvas, n_pad, sub), table in zip(programs, tables):
+        nc = len(sampling)
+        with phase("decode.launch", canvas="%dx%d" % canvas, rows=n_pad) as span:
+            misses = _crop_program.cache_info().misses
+            program = _crop_program(sampling, canvas, n_pad, sub, tuple(size), grayscale)
+            if span is not None and _crop_program.cache_info().misses != misses:
+                span.set_metadata(new_variant=1)
+            out = program(flat[:nc], overflow[:nc], table, out)
+        count("decode_launches")
+    return out

@@ -1809,8 +1809,10 @@ class Reader:
                 "No row groups to read (empty dataset, over-filtering selector/predicate, or "
                 "an empty shard)"
             )
+        #: the shuffle seed; a loader's crop boxes draw under it unless given one
+        self.seed = seed if seed is not None else shard_seed
         self._plan = EpochPlan(items, num_epochs=num_epochs, shuffle=shuffle_row_groups,
-                               seed=seed if seed is not None else shard_seed,
+                               seed=self.seed,
                                with_epoch=True)
         self._num_items = len(items)
         self._io_options = IoOptions.normalize(io_options)

@@ -69,6 +69,15 @@ def set_phase_sink(sink):
     _stage_sink.sink = sink
 
 
+def count(name, n=1):
+    """Add ``n`` to the counter ``name`` (a ``PipelineStats`` field) of the
+    loader stage open on this thread, through ``sink.add_count``; nothing
+    where none is open. Code below the loader counts what it did this way."""
+    sink = getattr(_stage_sink, "sink", None)
+    if sink is not None:
+        sink.add_count(name, n)
+
+
 class phase:
     """A part of a loader stage: ``ptpu.<name>`` on the profiler's clock (its
     ``args`` as metadata; ``as`` gives the span, for ``set_metadata``, or None

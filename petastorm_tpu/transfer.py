@@ -64,6 +64,14 @@ def _replicated_like(sharding):
                      "not under %s" % type(sharding).__name__)
 
 
+def _row_size(staged):
+    """A staged row's image ``(height, width)``: a JPEG's coefficient planes or a
+    host-decoded array."""
+    if hasattr(staged, "components"):
+        return staged.height, staged.width
+    return tuple(staged.shape[:2])
+
+
 def _decode_sharding(s, local_rows):
     """Batch-axis sharding to SPMD-decode staged payloads under.
 
@@ -133,13 +141,14 @@ class DeviceTransfer:
     ``stats`` is the loader's ``PipelineStats`` and ``stage(name, batch=seq)``
     opens one of its stages; the rest are the loader's ``sharding``,
     ``staging=`` and resolved ``pack``, the reader's schema and device-decode
-    fields, and the validated ``device_decode_resize``. It owns the staging
+    fields, the validated ``device_decode_resize`` (a crop policy, or a dict of
+    them by field) and the seed its crop boxes are drawn under. It owns the staging
     pool (:meth:`close` frees it), the layout program per row count, the slab
     split program per slab layout and the once-per-loader unsharded-decode
     warning."""
 
     def __init__(self, stats, stage, sharding=None, staging=None, pack=None,
-                 schema=None, decode_fields=(), resize=None):
+                 schema=None, decode_fields=(), resize=None, seed=None):
         self.stats = stats
         self._stage = stage
         self.sharding = sharding
@@ -147,6 +156,8 @@ class DeviceTransfer:
         self.schema = schema
         self.decode_fields = decode_fields
         self.resize = resize
+        self.seed = seed
+        self._decoded = 0  # batches decode() took: their crop boxes' key
         self._staging_arg = staging
         self._staging = None
         self._staging_decided = False
@@ -247,11 +258,14 @@ class DeviceTransfer:
         """Finish two-stage codec decode on the device: staging-payload columns
         (e.g. the JPEG coefficient planes of ``decode_on_device=True`` readers)
         become device arrays, one batched codec dispatch per column, under the
-        column's batch-axis sharding when one resolves. Returns ``(batch
-        without them, {name: device array})``."""
+        column's batch-axis sharding when one resolves. Under a crop policy that
+        draws, the column's boxes (:meth:`boxes`, under the number of this call)
+        join the batch as ``<name>_box`` and ship with its other columns.
+        Returns ``(batch without the staged columns, {name: device array})``."""
         if not self.decode_fields:
             return batch, {}
         batch = dict(batch)
+        seq, self._decoded = self._decoded, self._decoded + 1
         decoded = {}
         counted = False  # a batch counts once, however many fields fell back
         for name in self.decode_fields:
@@ -268,23 +282,46 @@ class DeviceTransfer:
             base = column_sharding(self.sharding, name)
             spmd = _decode_sharding(base, len(staged)) if base is not None else None
             kwargs = {} if spmd is None else {"sharding": spmd}
-            rt = self.resize.get(name) if isinstance(self.resize, dict) else self.resize
-            if rt is not None:
-                kwargs["resize_to"] = tuple(rt)
+            policy = self.resize.get(name) if isinstance(self.resize, dict) \
+                else self.resize
+            # a batch already at the size, with no box to draw, decodes as
+            # without the policy: one layout keeps its program and its sharding
+            crop = policy is not None and (
+                policy.draws or any(_row_size(s) != policy.size for s in staged))
+            if crop:
+                # the canvas decode runs on one device, whatever the sharding
+                spmd = None
+                kwargs = {"resize_to": policy.size}
+                if policy.draws:
+                    kwargs["boxes"] = batch[name + "_box"] = self.boxes(
+                        staged, policy, seq)
             out = field.codec.device_decode_batch(field, staged, **kwargs)
-            if self._unsharded(name, base, spmd, len(staged)) and not counted:
+            if self._unsharded(name, base, spmd, len(staged), crop) and not counted:
                 counted = True
                 self.stats.decode_unsharded_batches += 1
             decoded[name] = self._reshard(name, out)
         return batch, decoded
 
-    def _unsharded(self, name, base, spmd, rows):
+    def boxes(self, staged, policy, seq):
+        """The crop boxes of decoded batch ``seq``'s staged rows under ``policy``
+        (:func:`petastorm_tpu.ops.crop.draw_boxes`), from each row's header size,
+        drawn under ``(seed, seq)``: the ``decode.crop`` phase."""
+        from petastorm_tpu.ops import crop
+        from petastorm_tpu.trace import count
+
+        with phase("decode.crop", rows=len(staged)):
+            boxes = crop.draw_boxes([_row_size(s) for s in staged], policy,
+                                    crop.generator(self.seed or 0, seq))
+        count("crop_boxes", len(boxes))
+        return boxes
+
+    def _unsharded(self, name, base, spmd, rows, crop=False):
         """True when the field's sharding cuts the batch axis across several
-        devices but its decode ran on one (the batch does not divide, or no
-        process-local mesh derives); warns once per loader. Correct output
-        either way, but on a pod host one chip then decodes for all of them.
-        (Sub-groups of mixed layouts can still fall back inside the codec
-        without being counted here.)"""
+        devices but its decode ran on one (the batch does not divide, no
+        process-local mesh derives, or a crop policy's canvas decode ran);
+        warns once per loader. Correct output either way, but on a pod host one
+        chip then decodes for all of them. (Sub-groups of mixed layouts can
+        still fall back inside the codec without being counted here.)"""
         want = batch_axis_shard_count(base)
         got = batch_axis_shard_count(spmd)
         if want <= 1 or (got > 1 and rows % got == 0):
@@ -299,11 +336,12 @@ class DeviceTransfer:
                 "unsharded_decode",
                 "Staged decode of field %r is running on a SINGLE device "
                 "although its sharding splits the batch axis %d ways "
-                "(batch rows=%d). Decode output is correct but unscaled; "
-                "make the per-process batch divisible by the batch-axis "
-                "shard count. (Warned once; see "
-                "PipelineStats.decode_unsharded_batches.)",
-                name, want, rows, once=False)
+                "(batch rows=%d). Decode output is correct but unscaled; %s "
+                "(Warned once; see PipelineStats.decode_unsharded_batches.)",
+                name, want, rows,
+                "the canvas decode of a device_decode_resize crop runs on one "
+                "device." if crop else "make the per-process batch divisible by "
+                "the batch-axis shard count.", once=False)
         return True
 
     def _reshard(self, name, out):

@@ -1,4 +1,6 @@
 """Two-stage JPEG decode vs the cv2 (libjpeg) oracle: entropy decode + Pallas IDCT."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -748,7 +750,10 @@ def test_split_pack_device_bitexact_and_sticky_growth():
     # a group containing a PROFILE-LESS row (per-image fallback decode) must not
     # forfeit the split: the batch-level specmax pass recovers it, bit-equal
     loner = j.entropy_decode_jpeg_fast(smooth_blobs[1])
-    assert loner.specmax is None
+    # a native per-image decode carries its own profile; a row without one (the
+    # pure-Python oracle's) is made here
+    assert loner.specmax is not None and loner.specmax.shape == (3, 64)
+    loner = dataclasses.replace(loner, specmax=None, kmax=None)
     with_loner = [smooth[0], loner, sharp[3]]
     with j._STICKY_KS_LOCK:
         j._STICKY_SPLIT.pop(layout, None)  # force a fresh split decision

@@ -289,8 +289,12 @@ def test_stats_populate_through_device_path(scalar_dataset):
                          "decode_pack_s", "decode_put_s", "decode_launch_s",
                          "h2d_stage_s", "h2d_put_s", "h2d_wait_s", "h2d_bytes",
                          "h2d_unwaited_outputs", "h2d_slab_puts",
-                         "pack_s", "pack_slots", "pack_pad_slots", "pack_chunks"}
+                         "pack_s", "pack_slots", "pack_pad_slots", "pack_chunks",
+                         "decode_batches", "decode_launches", "decode_real_blocks",
+                         "decode_canvas_blocks", "decode_host_rows", "crop_boxes"}
     assert snap["pack_s"] == 0 and snap["pack_chunks"] == 0  # no pack=: nothing packed
+    # no device decode: nothing launched, nothing cropped
+    assert snap["decode_batches"] == snap["decode_launches"] == snap["crop_boxes"] == 0
     assert snap["decode_unsharded_batches"] == 0  # no sharding configured → no fallback
     assert snap["h2d_unwaited_outputs"] == 0  # no device decode: no program outputs
     assert snap["h2d_slab_puts"] == 0  # the CPU backend stages nothing

@@ -161,6 +161,32 @@ def test_jpeg_stage2_compiles_spmd_over_dp4(tpu, topo):
     jpeg._batched_stage2.cache_clear()
 
 
+def test_jpeg_crop_resize_compiles_512_canvas_batch_128(tpu, one_chip):
+    """The original-size ImageNet cell's main bucket: 128 4:2:0 images in 512x512
+    canvases, split-packed coefficients as 32-bit words (a flat array and its
+    overflow a component), cropped and resized to 224x224 by HIGHEST matmuls,
+    within a few GB of one chip's 16."""
+    import jax.numpy as jnp
+
+    from petastorm_tpu.ops import jpeg
+
+    sampling = ((2, 2), (1, 1), (1, 1))
+    variant = (None, (False,) * 3, ((24, 40),) * 3)
+    jpeg._crop_program.cache_clear()
+    fn = jpeg._crop_program(sampling, (512, 512), 128, variant, (224, 224), False)
+    words = (24 * 3 // 2 // 4, (40 - 24) // 4, (64 - 40) // 2 // 4)
+    caps = (430080, 110592, 110592)
+    flat = tuple(tuple(_spec((c, w), jnp.uint32, one_chip) for w in words) for c in caps)
+    over = tuple(tuple(_spec((c // 2, w), jnp.uint32, one_chip) for w in words)
+                 for c in caps)
+    table = _spec((128, jpeg._TABLE_HEAD + 67 * 3), jnp.int32, one_chip)
+    acc = _spec((128, 224, 224, 3), jnp.uint8, one_chip)
+    compiled = _compile(fn, flat, over, table, acc)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+    jpeg._crop_program.cache_clear()
+
+
 @pytest.mark.parametrize("rows,pair", [(16384, False), (1000, True)])
 def test_slab_split_compiles_for_a_criteo_batch(tpu, one_chip, rows, pair):
     """The staged slab's split program at the Criteo cell's size (a b16384
