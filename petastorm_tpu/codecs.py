@@ -15,11 +15,13 @@ Capability parity with the reference codec set (petastorm/codecs.py: ``Dataframe
 from __future__ import annotations
 
 import io
+import time
 import zlib
 
 import numpy as np
 
 from petastorm_tpu import types as ptypes
+from petastorm_tpu.trace import tally
 
 
 class DataframeColumnCodec:
@@ -277,14 +279,22 @@ class CompressedImageCodec(DataframeColumnCodec):
 
         Streams the two-stage path cannot handle (lossless/arithmetic, CMYK, corrupt-for-us)
         fall back to the full host decode per row; the loader stacks those alongside
-        the device-decoded rows."""
+        the device-decoded rows. Counts one image and this thread's CPU time in the
+        process-wide stage-1 totals (``trace.tally``)."""
         if not self.device_decodable:
             raise NotImplementedError("on-device decode is only available for jpeg")
+        t0 = time.thread_time_ns()
+        try:
+            return self._stage_one(unischema_field, encoded)
+        finally:
+            tally(stage1_images=1, stage1_ns=time.thread_time_ns() - t0)
+
+    def _stage_one(self, unischema_field, encoded):
         from petastorm_tpu.errors import DecodeFieldError
         from petastorm_tpu.ops.jpeg import entropy_decode_jpeg_fast
 
         try:
-            return entropy_decode_jpeg_fast(bytes(encoded))
+            return entropy_decode_jpeg_fast(encoded)
         except ValueError as stage_err:
             try:
                 return self.decode(unischema_field, encoded)
@@ -337,11 +347,15 @@ class CompressedImageCodec(DataframeColumnCodec):
             for j, i in enumerate(idx):
                 out[i] = staged[j]
             return out
+        t0 = time.thread_time_ns()
         try:
             with profiler_span("ptpu.pool.stage1", rows=len(blobs)):
                 planes = entropy_decode_jpeg_batch(blobs)
         except (ValueError, RuntimeError):
             planes = [None] * len(idx)
+        # the rows it could not decode count where they are decoded again, one by one
+        tally(stage1_images=sum(p is not None for p in planes),
+              stage1_ns=time.thread_time_ns() - t0)
         for j, i in enumerate(idx):
             p = planes[j]
             out[i] = p if p is not None \

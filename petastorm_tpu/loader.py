@@ -38,7 +38,7 @@ from petastorm_tpu.io.lease import (LeasedBatch, attach_leases, count_copy,
                                     detach_slab_views, take_leases)
 from petastorm_tpu.parallel.mesh import batch_axis_shard_count
 from petastorm_tpu.shuffle import BatchedRandomShufflingBuffer
-from petastorm_tpu.trace import profiler_span, set_phase_sink
+from petastorm_tpu.trace import profiler_span, set_phase_sink, tallies
 from petastorm_tpu.transfer import (_PACK_TABLE, DeviceTransfer, _is_device_dtype,
                                     column_sharding)
 from petastorm_tpu.utils import stack_as_column
@@ -61,6 +61,10 @@ _PHASES = {"decode.pack": "decode_pack_s", "decode.put": "decode_put_s",
 #: the counters code below the loader adds to (``trace.count`` names)
 _COUNTS = ("decode_batches", "decode_launches", "decode_real_blocks",
            "decode_canvas_blocks", "decode_host_rows", "crop_boxes")
+
+#: the process-wide totals of the reader pool's stage-1 decode (``trace.tally``
+#: names) the loader folds in
+_TALLIES = ("stage1_images", "stage1_ns", "stage1_symbols", "stage1_fast_symbols")
 
 
 class PipelineStats:
@@ -109,6 +113,15 @@ class PipelineStats:
     sizes) and ``crop_boxes`` (crop
     boxes drawn under a ``random_resized`` or flipping policy).
 
+    The reader pool's stage-1 decode, from the process-wide ``trace.tally``
+    totals folded in at each reader delivery (so they count every reader of the
+    process, and only the pool's threads in this process: a process pool's
+    children count in their own): ``stage1_images`` (images entropy-decoded for
+    device decode), ``stage1_ns`` (the decoding threads' CPU time in those calls,
+    ``time.thread_time_ns``), ``stage1_symbols`` and ``stage1_fast_symbols``
+    (Huffman symbols decoded, and those one table lookup resolved with their
+    magnitude bits).
+
     Under ``DataLoader(pack=...)``: ``pack_s`` is producer time packing
     documents into rows (chunking, placement, the token copy); ``pack_slots``,
     ``pack_pad_slots`` and ``pack_chunks`` count the slots, the padding slots and
@@ -143,7 +156,7 @@ class PipelineStats:
                  "shm_slabs_in_flight", "shm_bytes", "shm_fallbacks",
                  "shm_acquire_wait_s") + tuple(_PHASES.values()) + (
                      "h2d_bytes", "h2d_unwaited_outputs", "h2d_slab_puts", "pack_s",
-                     "pack_slots", "pack_pad_slots", "pack_chunks") + _COUNTS
+                     "pack_slots", "pack_pad_slots", "pack_chunks") + _COUNTS + _TALLIES
 
     def __init__(self):
         self.reset()
@@ -164,6 +177,16 @@ class PipelineStats:
         """A ``trace.count`` below the loader: add ``n`` to counter ``name``."""
         if name in _COUNTS:
             setattr(self, name, getattr(self, name) + n)
+
+    def fold_tallies(self, seen):
+        """Add what the process-wide stage-1 totals (``trace.tallies``) grew by
+        since ``seen``, the caller's last reading, which is brought up to date."""
+        now = tallies()
+        for name in _TALLIES:
+            grown = now.get(name, 0) - seen.get(name, 0)
+            if grown:
+                setattr(self, name, getattr(self, name) + grown)
+        seen.update(now)
 
     def snapshot(self):
         return {field: round(getattr(self, field), 4) if field.endswith("_s")
@@ -1177,6 +1200,7 @@ class DataLoader:
         ckpt_cum = 0  # cumulative rows delivered by the reader this generation
         ckpt_deliveries = 0
         ckpt_next_snap = 1
+        tally_seen = tallies()  # stage-1 totals fold in per delivery
         # shm wire integration: gauges refresh per delivery
         wire_stats_fn = getattr(self.reader, "wire_stats", None)
         if wire_stats_fn is not None and not wire_stats_fn():
@@ -1209,6 +1233,7 @@ class DataLoader:
                         self._ckpt_record(ckpt_cum)
                     if wire_stats_fn is not None:
                         stats.update_wire(wire_stats_fn())
+                    stats.fold_tallies(tally_seen)
                     break
                 if self._stop.is_set():
                     return
@@ -1236,6 +1261,7 @@ class DataLoader:
                     columns = detach_slab_views(columns)
                 if wire_stats_fn is not None:
                     stats.update_wire(wire_stats_fn())
+                stats.fold_tallies(tally_seen)
                 if packer is not None:
                     try:  # the packer copies the tokens out of the delivery
                         if not self._pack_delivery(q, packer, columns, hb):

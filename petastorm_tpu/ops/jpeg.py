@@ -26,6 +26,8 @@ import threading
 
 import numpy as np
 
+from petastorm_tpu.trace import tally
+
 # -- zigzag order (JPEG spec, Figure A.6) ----------------------------------------------
 
 ZIGZAG = np.array([
@@ -412,28 +414,21 @@ def entropy_decode_jpeg_fast(data):
     falls back to the pure-Python oracle when the native build is unavailable.
 
     This is the data-plane entry point: ctypes releases the GIL so reader thread pools
-    run stage-1 decode truly in parallel. Raises ValueError on streams the two-stage
-    path cannot handle (lossless/arithmetic, CMYK, corrupt) — the codec layer catches that and
-    falls back to full host decode per stream."""
+    run stage-1 decode truly in parallel. One decode call fills arrays of the image's
+    own and gives the spectral profile and kmax a row-group decode would, so that a
+    batch of per-image rows (mixed sizes) still ships narrowed. Raises ValueError on
+    streams the two-stage path cannot handle (lossless/arithmetic, CMYK, corrupt) —
+    the codec layer catches that and falls back to full host decode per stream."""
     from petastorm_tpu.ops import native
 
     if native.native_available():
-        height, width, comps = native.jpeg_decode_coeffs_native(data)
-        # the spectral profile and kmax a row-group decode would give, so that a
-        # batch of per-image rows (mixed sizes) still ships narrowed
-        specmax = np.stack([native.jpeg_specmax_native(blocks.reshape(1, -1, 64))
-                            for blocks, _q, _h, _v in comps])
-        kmax = tuple(int(np.flatnonzero(row).max()) if row.any() else 0
-                     for row in specmax)
-        planes = JpegPlanes(
-            height=height,
-            width=width,
-            components=[JpegComponent(blocks, qtable, h, v)
-                        for blocks, qtable, h, v in comps],
-            kmax=kmax, specmax=specmax,
-        )
+        height, width, comps, kmax, specmax, symbols, fast = \
+            native.jpeg_decode_image_native(data)
+        tally(stage1_symbols=symbols, stage1_fast_symbols=fast)
+        planes = JpegPlanes(height, width,
+                            [JpegComponent(*c) for c in comps], kmax=kmax, specmax=specmax)
     else:
-        planes = entropy_decode_jpeg(data)
+        planes = entropy_decode_jpeg(bytes(data))
     if len(planes.components) not in (1, 3):
         # stage 2 models grayscale and YCbCr only; 2-component or Adobe CMYK streams
         # must not reach the jitted decoder (wrong colors / shape errors inside jit)
@@ -450,9 +445,11 @@ def entropy_decode_jpeg_batch(blobs):
     individually).
 
     One native call decodes every same-layout stream straight into stacked coefficient
-    buffers (no per-image ctypes overhead, no copies, GIL released throughout); each
-    returned ``JpegPlanes`` holds zero-copy views into those buffers plus a ``batch_ref``
-    so downstream batching can slice the parent arrays directly.
+    buffers (no per-image ctypes overhead, no copies, GIL released throughout) and
+    folds the row group's spectral range profile (shared by its rows; it drives the
+    split-pack transfer) into the same pass; each returned ``JpegPlanes`` holds
+    zero-copy views into those buffers plus a ``batch_ref`` so downstream batching can
+    slice the parent arrays directly.
 
     Raises RuntimeError when the native decoder is unavailable and ValueError when the
     first stream has no usable baseline layout (callers fall back to the per-image path).
@@ -461,20 +458,17 @@ def entropy_decode_jpeg_batch(blobs):
 
     if not native.native_available():
         raise RuntimeError("native jpeg decoder unavailable: %s" % native.native_error())
-    layout, coeffs, qtabs, kmax, status = native.jpeg_decode_coeffs_batch_native(blobs)
-    height, width, comps_layout = layout
+    one = native.jpeg_decode_coeffs_batch_native(blobs)
+    tally(stage1_symbols=one.symbols, stage1_fast_symbols=one.fast_symbols)
+    height, width, comps_layout = one.layout
     if len(comps_layout) not in (1, 3):
         raise ValueError(
             "Unsupported JPEG component count %d (expected 1 or 3)" % len(comps_layout)
         )
-    qtabs = qtabs.astype(np.int32)  # per-image contract dtype (one cast per row group)
-    # Spectral range profile, one native pass per component over the stacked buffers
-    # (memory-bound, GIL released; failed streams' slices are zeroed so they cannot
-    # inflate it). Shared across the group's rows — drives the split-pack transfer.
-    specmax = np.stack([native.jpeg_specmax_native(c) for c in coeffs])
+    coeffs, qtabs = one.coeffs, one.qtabs
     out = []
     for i in range(len(blobs)):
-        if status[i] != 0:
+        if one.status[i] != 0:
             out.append(None)
             continue
         comps = [
@@ -482,7 +476,7 @@ def entropy_decode_jpeg_batch(blobs):
             for c, (h, v, by, bx) in enumerate(comps_layout)
         ]
         out.append(JpegPlanes(height, width, comps, batch_ref=(coeffs, qtabs, i),
-                              kmax=kmax, specmax=specmax))
+                              kmax=one.kmax, specmax=one.specmax))
     return out
 
 

@@ -12,6 +12,7 @@ Set ``PETASTORM_TPU_DISABLE_NATIVE=1`` to force the Python fallbacks.
 """
 from __future__ import annotations
 
+import collections
 import ctypes
 import hashlib
 import logging
@@ -66,20 +67,6 @@ def _compile(sources, name):
     return out_path
 
 
-class _JpegCoeffs(ctypes.Structure):
-    _fields_ = [
-        ("height", ctypes.c_int32),
-        ("width", ctypes.c_int32),
-        ("ncomp", ctypes.c_int32),
-        ("h_samp", ctypes.c_int32 * 4),
-        ("v_samp", ctypes.c_int32 * 4),
-        ("blocks_y", ctypes.c_int32 * 4),
-        ("blocks_x", ctypes.c_int32 * 4),
-        ("blocks", ctypes.POINTER(ctypes.c_int16) * 4),
-        ("qtables", (ctypes.c_uint16 * 64) * 4),
-    ]
-
-
 class _JpegLayout(ctypes.Structure):
     _fields_ = [
         ("height", ctypes.c_int32),
@@ -105,11 +92,6 @@ def _load():
         try:
             path = _compile([os.path.join(_SRC_DIR, "jpeg_decoder.cpp")], "ptpu_native")
             lib = ctypes.CDLL(path)
-            lib.ptpu_jpeg_decode_coeffs.argtypes = [
-                ctypes.c_char_p, ctypes.c_int64, ctypes.POINTER(_JpegCoeffs)]
-            lib.ptpu_jpeg_decode_coeffs.restype = ctypes.c_int
-            lib.ptpu_jpeg_free_coeffs.argtypes = [ctypes.POINTER(_JpegCoeffs)]
-            lib.ptpu_jpeg_free_coeffs.restype = None
             lib.ptpu_jpeg_error_string.argtypes = [ctypes.c_int]
             lib.ptpu_jpeg_error_string.restype = ctypes.c_char_p
             lib.ptpu_jpeg_parse_layout.argtypes = [
@@ -118,9 +100,8 @@ def _load():
             lib.ptpu_jpeg_decode_batch.argtypes = [
                 ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_int64),
                 ctypes.c_int32, ctypes.POINTER(_JpegLayout),
-                ctypes.POINTER(ctypes.POINTER(ctypes.c_int16)),
-                ctypes.POINTER(ctypes.c_uint16), ctypes.POINTER(ctypes.c_int32),
-                ctypes.POINTER(ctypes.c_int32)]
+                ctypes.POINTER(ctypes.c_void_p), ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
             lib.ptpu_jpeg_decode_batch.restype = ctypes.c_int32
             lib.ptpu_jpeg_zigzag_truncate.argtypes = [
                 ctypes.POINTER(ctypes.c_int16), ctypes.POINTER(ctypes.c_int16),
@@ -167,47 +148,84 @@ def native_error():
 _VALUE_ERRORS = {-1, -2, -3, -4, -5, -6}
 
 
+def _parse_layout(lib, blob):
+    layout = _JpegLayout()
+    rc = lib.ptpu_jpeg_parse_layout(blob, len(blob), ctypes.byref(layout))
+    if rc != 0:
+        _raise(lib, rc)
+    return layout
+
+
+def _raise(lib, rc):
+    msg = lib.ptpu_jpeg_error_string(rc).decode()
+    if rc in _VALUE_ERRORS:
+        raise ValueError(msg)
+    raise RuntimeError(msg)
+
+
+def _layout_tuple(layout):
+    return (layout.height, layout.width,
+            tuple((layout.h_samp[c], layout.v_samp[c], layout.blocks_y[c],
+                   layout.blocks_x[c]) for c in range(layout.ncomp)))
+
+
 def jpeg_parse_layout_native(data):
     """JPEG bytes → layout tuple ``(height, width, ((h, v, by, bx), ...))`` from the
     frame header only (no entropy decode). ValueError on non-baseline/corrupt headers."""
     lib = _load()
     if lib is None:
         raise RuntimeError("native jpeg decoder unavailable: %s" % _LIB_ERR)
-    raw = bytes(data)
-    out = _JpegLayout()
-    rc = lib.ptpu_jpeg_parse_layout(raw, len(raw), ctypes.byref(out))
-    if rc != 0:
-        msg = lib.ptpu_jpeg_error_string(rc).decode()
-        if rc in _VALUE_ERRORS:
-            raise ValueError(msg)
-        raise RuntimeError(msg)
-    comps = tuple(
-        (out.h_samp[c], out.v_samp[c], out.blocks_y[c], out.blocks_x[c])
-        for c in range(out.ncomp)
-    )
-    return out.height, out.width, comps
+    return _layout_tuple(_parse_layout(lib, bytes(data)))
+
+
+#: One stage-1 call's result (:func:`jpeg_decode_coeffs_batch_native`).
+StageOne = collections.namedtuple(
+    "StageOne", "layout coeffs qtabs kmax status specmax symbols fast_symbols")
+
+
+def _decode(lib, blobs, layout, coeffs):
+    """``ptpu_jpeg_decode_batch`` of ``blobs`` into ``coeffs`` (per component, C-
+    contiguous int16 with room for every stream's blocks): the other outputs."""
+    import numpy as np
+
+    n, ncomp = len(blobs), layout.ncomp
+    qtabs = np.empty((n, ncomp, 64), dtype=np.int32)
+    kmax = np.empty(4, dtype=np.int32)
+    specmax = np.empty((ncomp, 64), dtype=np.int32)
+    symbols = np.empty(2, dtype=np.int64)
+    status = np.empty(n, dtype=np.int32)
+    lib.ptpu_jpeg_decode_batch(
+        (ctypes.c_char_p * n)(*blobs), (ctypes.c_int64 * n)(*map(len, blobs)), n,
+        ctypes.byref(layout), (ctypes.c_void_p * 4)(*[a.ctypes.data for a in coeffs]),
+        qtabs.ctypes.data, kmax.ctypes.data, specmax.ctypes.data, symbols.ctypes.data,
+        status.ctypes.data)
+    return qtabs, tuple(int(k) for k in kmax[:ncomp]), status, specmax, symbols
 
 
 def jpeg_decode_coeffs_batch_native(blobs):
     """Entropy-decode a whole row group of same-layout JPEGs in ONE native call.
 
     Decodes straight into stacked numpy buffers — no per-image ctypes round trip, no
-    buffer copies, GIL released for the entire batch (the per-image path spends ~2/3 of
-    its wall in Python wrapper overhead + ctypes→numpy copies on 1-core hosts).
+    buffer copies, GIL released for the entire batch.
 
-    Returns ``(layout, coeffs, qtabs, kmax, status)``:
+    Returns a :data:`StageOne`:
 
     - ``layout``: ``(height, width, ((h_samp, v_samp, blocks_y, blocks_x), ...))``
       parsed from the first stream
     - ``coeffs``: tuple of ``(n, blocks_y*blocks_x, 64)`` int16 arrays, one per component
-    - ``qtabs``: ``(n, ncomp, 64)`` uint16 natural-order quantization tables
-    - ``kmax``: per component, the max ZIGZAG index any stream wrote — every
-      coefficient beyond it is zero, so transfers may ship only the prefix
+    - ``qtabs``: ``(n, ncomp, 64)`` int32 natural-order quantization tables
+    - ``kmax``: per component, the highest ZIGZAG position any stream holds a nonzero
+      coefficient at — transfers may ship only the prefix
       (:func:`jpeg_zigzag_truncate_native`)
     - ``status``: ``(n,)`` int32 — 0 decoded; nonzero = that stream failed
       (lossless/arithmetic mode / corrupt / different layout; its slice is zeroed) and
       the caller must re-decode it individually (e.g. cv2 host fallback). Baseline and
       progressive streams both decode natively.
+    - ``specmax``: ``(ncomp, 64)`` int32, per zigzag position the largest |coefficient|
+      of the decoded streams (:func:`jpeg_specmax_native` over ``coeffs``), folded
+      into the decode
+    - ``symbols``, ``fast_symbols``: the Huffman symbols decoded, and those of them
+      one table lookup resolved with their magnitude bits
 
     Raises ValueError when the FIRST stream has no parseable baseline-or-progressive
     layout (caller falls back to per-image decode for the whole batch)."""
@@ -220,39 +238,42 @@ def jpeg_decode_coeffs_batch_native(blobs):
     n = len(blobs)
     if n == 0:
         raise ValueError("empty batch")
-    layout = _JpegLayout()
-    rc = lib.ptpu_jpeg_parse_layout(blobs[0], len(blobs[0]), ctypes.byref(layout))
-    if rc != 0:
-        msg = lib.ptpu_jpeg_error_string(rc).decode()
-        if rc in _VALUE_ERRORS:
-            raise ValueError(msg)
-        raise RuntimeError(msg)
-    ncomp = layout.ncomp
+    layout = _parse_layout(lib, blobs[0])
+    coeffs = tuple(
+        np.empty((n, layout.blocks_y[c] * layout.blocks_x[c], 64), dtype=np.int16)
+        for c in range(layout.ncomp))
+    qtabs, kmax, status, specmax, symbols = _decode(lib, blobs, layout, coeffs)
+    return StageOne(_layout_tuple(layout), coeffs, qtabs, kmax, status, specmax,
+                    int(symbols[0]), int(symbols[1]))
 
-    datas = (ctypes.c_char_p * n)(*blobs)
-    lens = (ctypes.c_int64 * n)(*[len(b) for b in blobs])
-    coeffs = []
-    block_ptrs = (ctypes.POINTER(ctypes.c_int16) * 4)()
-    for c in range(ncomp):
-        arr = np.empty((n, layout.blocks_y[c] * layout.blocks_x[c], 64), dtype=np.int16)
-        coeffs.append(arr)
-        block_ptrs[c] = arr.ctypes.data_as(ctypes.POINTER(ctypes.c_int16))
-    qtabs = np.empty((n, ncomp, 64), dtype=np.uint16)
-    kmax = np.zeros(4, dtype=np.int32)
-    status = np.empty(n, dtype=np.int32)
-    lib.ptpu_jpeg_decode_batch(
-        datas, lens, n, ctypes.byref(layout), block_ptrs,
-        qtabs.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)),
-        kmax.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        status.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-    )
-    layout_key = (
-        layout.height,
-        layout.width,
-        tuple((layout.h_samp[c], layout.v_samp[c], layout.blocks_y[c], layout.blocks_x[c])
-              for c in range(ncomp)),
-    )
-    return layout_key, tuple(coeffs), qtabs, tuple(int(k) for k in kmax[:ncomp]), status
+
+def jpeg_decode_image_native(data):
+    """One JPEG's stage 1: its frame header, then one GIL-released decode straight
+    into arrays of its own.
+
+    Returns ``(height, width, comps, kmax, specmax, symbols, fast_symbols)``:
+    ``comps`` is ``[(blocks, qtable, h_samp, v_samp), ...]`` with ``blocks`` a
+    ``(blocks_y, blocks_x, 64)`` int16 natural-order array and ``qtable`` a ``(64,)``
+    int32 natural-order row of one ``(ncomp, 64)`` array; the rest as
+    :func:`jpeg_decode_coeffs_batch_native` gives them. Raises ValueError on
+    malformed/unsupported streams (same contract as the Python oracle) and
+    RuntimeError when the native library is unavailable."""
+    import numpy as np
+
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native jpeg decoder unavailable: %s" % _LIB_ERR)
+    blob = bytes(data)
+    layout = _parse_layout(lib, blob)
+    coeffs = [np.empty((layout.blocks_y[c], layout.blocks_x[c], 64), dtype=np.int16)
+              for c in range(layout.ncomp)]
+    qtabs, kmax, status, specmax, symbols = _decode(lib, [blob], layout, coeffs)
+    if status[0] != 0:
+        _raise(lib, int(status[0]))
+    comps = [(coeffs[c], qtabs[0, c], layout.h_samp[c], layout.v_samp[c])
+             for c in range(layout.ncomp)]
+    return (layout.height, layout.width, comps, kmax, specmax, int(symbols[0]),
+            int(symbols[1]))
 
 
 def jpeg_zigzag_truncate_native(src, k):
@@ -438,41 +459,6 @@ def jpeg_pack_runs_native(runs, tiers, threads=1):
         rows.ctypes.data, n, ncomp, nblocks.ctypes.data, spec.ctypes.data,
         (ctypes.c_void_p * (3 * ncomp))(*ptrs), status.ctypes.data, int(threads))
     return [None if st else out for out, st in zip(outs, status)]
-
-
-def jpeg_decode_coeffs_native(data):
-    """JPEG bytes → (height, width, [(blocks, qtable, h_samp, v_samp), ...]) via C++.
-
-    ``blocks``: (blocks_y, blocks_x, 64) int16 natural-order quantized coefficients (a
-    copy owned by numpy); ``qtable``: (64,) int32 natural order. Raises ValueError on
-    malformed/unsupported streams (same contract as the Python oracle) and RuntimeError
-    when the native library is unavailable.
-    """
-    import numpy as np
-
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native jpeg decoder unavailable: %s" % _LIB_ERR)
-    raw = bytes(data)
-    out = _JpegCoeffs()
-    rc = lib.ptpu_jpeg_decode_coeffs(raw, len(raw), ctypes.byref(out))
-    if rc != 0:
-        msg = lib.ptpu_jpeg_error_string(rc).decode()
-        if rc in _VALUE_ERRORS:
-            raise ValueError(msg)
-        raise RuntimeError(msg)
-    try:
-        comps = []
-        for c in range(out.ncomp):
-            by, bx = out.blocks_y[c], out.blocks_x[c]
-            n = by * bx * 64
-            blocks = np.ctypeslib.as_array(out.blocks[c], shape=(n,)).copy()
-            blocks = blocks.reshape(by, bx, 64)
-            qtable = np.asarray(out.qtables[c], dtype=np.int32).copy()
-            comps.append((blocks, qtable, out.h_samp[c], out.v_samp[c]))
-        return out.height, out.width, comps
-    finally:
-        lib.ptpu_jpeg_free_coeffs(ctypes.byref(out))
 
 
 _PACK_LIB = None

@@ -17,6 +17,7 @@
 // interleaved DC scans, per-component AC scans, EOB runs), 1..4 components, restart
 // intervals, 0xFF00 byte stuffing. Rejects lossless/arithmetic/hierarchical modes.
 
+#include <algorithm>
 #include <atomic>
 #include <cstdint>
 #include <cstdlib>
@@ -34,7 +35,6 @@ enum {
   PTPU_JPEG_NOT_8BIT = -4,
   PTPU_JPEG_BAD_COMPONENTS = -5,
   PTPU_JPEG_NO_SCAN = -6,
-  PTPU_JPEG_OOM = -7,
   PTPU_JPEG_LAYOUT_MISMATCH = -8,
 };
 
@@ -47,83 +47,16 @@ const int kZigzagToNatural[64] = {
     35, 42, 49, 56, 57, 50, 43, 36, 29, 22, 15, 23, 30, 37, 44, 51,
     58, 59, 52, 45, 38, 31, 39, 46, 53, 60, 61, 54, 47, 55, 62, 63};
 
-struct BitReader {
+// The entropy-coded bytes and the read position. The bit buffer itself lives in the
+// scan loops' locals: the low ``cnt`` bits of ``buf`` are the next bits, MSB first.
+struct BitSrc {
   const uint8_t* data;
   int64_t len;
   int64_t pos;
-  uint64_t buf;
-  int cnt;
   bool at_marker;  // hit 0xFF <marker>: stop consuming, pad zero bits (spec allows)
 
-  void init(const uint8_t* d, int64_t l, int64_t p) {
-    data = d;
-    len = l;
-    pos = p;
-    buf = 0;
-    cnt = 0;
-    at_marker = false;
-  }
-
-  void fill() {
-    // Fast path: append 6-8 bytes at once when none is 0xFF (the overwhelmingly common
-    // case mid-scan; byte stuffing and markers take the per-byte path below).
-    if (!at_marker && pos + 8 <= len) {
-      uint64_t chunk;
-      memcpy(&chunk, data + pos, 8);
-      uint64_t t = ~chunk;  // a 0xFF byte becomes 0x00 in t
-      if (!((t - 0x0101010101010101ULL) & ~t & 0x8080808080808080ULL)) {
-        uint64_t be = __builtin_bswap64(chunk);
-        if (cnt == 0) {  // whole-word load (shift-by-64 below would be UB)
-          buf = be;
-          cnt = 64;
-          pos += 8;
-          return;
-        }
-        int take = (64 - cnt) >> 3;
-        buf = (buf << (8 * take)) | (be >> (64 - 8 * take));
-        cnt += 8 * take;
-        pos += take;
-        return;
-      }
-    }
-    while (cnt <= 56) {
-      uint8_t b = 0;
-      if (!at_marker && pos < len) {
-        b = data[pos];
-        if (b == 0xFF) {
-          uint8_t nxt = (pos + 1 < len) ? data[pos + 1] : 0xD9;
-          if (nxt == 0x00) {
-            pos += 2;  // byte-stuffed literal 0xFF
-          } else {
-            b = 0;  // real marker (RSTn/EOI): freeze pos, feed zeros
-            at_marker = true;
-          }
-        } else {
-          pos += 1;
-        }
-      }
-      buf = (buf << 8) | b;
-      cnt += 8;
-    }
-  }
-
-  // One refill guard covers a full (code ≤16 bits, value ≤11 bits) coefficient read.
-  inline void ensure28() {
-    if (cnt < 28) fill();
-  }
-
-  inline uint32_t peek16_raw() { return (uint32_t)((buf >> (cnt - 16)) & 0xFFFF); }
-
-  // Consume n (≥1) already-buffered bits.
-  inline int take(int n) {
-    cnt -= n;
-    return (int)((buf >> cnt) & ((1u << n) - 1));
-  }
-
-  // Skip to just past the next RSTn marker; reset bit state.
+  // Skip to just past the next RSTn marker.
   void align_restart() {
-    buf = 0;
-    cnt = 0;
     at_marker = false;
     while (pos + 1 < len) {
       if (data[pos] == 0xFF && data[pos + 1] >= 0xD0 && data[pos + 1] <= 0xD7) {
@@ -135,23 +68,131 @@ struct BitReader {
   }
 };
 
+struct Bits {
+  uint64_t buf;
+  int cnt;
+};
+
+// Byte-at-a-time refill to more than 56 bits: byte stuffing, and zeros once a marker
+// (RSTn/EOI) or the end of the data is met. Out of line: the fast path below covers
+// all but the bytes around a 0xFF.
+__attribute__((noinline)) Bits refill_slow(BitSrc& s, Bits b) {
+  while (b.cnt <= 56) {
+    uint8_t byte = 0;
+    if (!s.at_marker && s.pos < s.len) {
+      byte = s.data[s.pos];
+      if (byte == 0xFF) {
+        uint8_t nxt = (s.pos + 1 < s.len) ? s.data[s.pos + 1] : 0xD9;
+        if (nxt == 0x00) {
+          s.pos += 2;  // byte-stuffed literal 0xFF
+        } else {
+          byte = 0;  // real marker: freeze pos, feed zeros
+          s.at_marker = true;
+        }
+      } else {
+        s.pos += 1;
+      }
+    }
+    b.buf = (b.buf << 8) | byte;
+    b.cnt += 8;
+  }
+  return b;
+}
+
+// Refill a buffer holding fewer than 32 bits to at least 56: 4-7 bytes in one load
+// when none of the next 8 is 0xFF (the overwhelmingly common case mid-scan; a marker
+// freezes ``pos`` on its 0xFF, so this never reads past one).
+inline __attribute__((always_inline)) void refill(BitSrc& s, uint64_t& buf, int& cnt) {
+  if (s.pos + 8 <= s.len) {
+    uint64_t chunk;
+    memcpy(&chunk, s.data + s.pos, 8);
+    uint64_t t = ~chunk;  // a 0xFF byte becomes 0x00 in t
+    if (!((t - 0x0101010101010101ULL) & ~t & 0x8080808080808080ULL)) {
+      int take = (63 - cnt) >> 3;
+      buf = (buf << (8 * take)) | (__builtin_bswap64(chunk) >> (64 - 8 * take));
+      cnt += 8 * take;
+      s.pos += take;
+      return;
+    }
+  }
+  Bits b = refill_slow(s, Bits{buf, cnt});
+  buf = b.buf;
+  cnt = b.cnt;
+}
+
+// The progressive scans' bit reader: the same buffer as a struct.
+struct BitReader {
+  BitSrc src;
+  uint64_t buf;
+  int cnt;
+
+  void init(const uint8_t* d, int64_t l, int64_t p) {
+    src = BitSrc{d, l, p, false};
+    buf = 0;
+    cnt = 0;
+  }
+
+  // One refill guard covers a full (code ≤16 bits, value ≤11 bits) coefficient read.
+  inline void ensure28() {
+    if (cnt < 28) refill(src, buf, cnt);
+  }
+
+  inline uint32_t peek16_raw() { return (uint32_t)((buf >> (cnt - 16)) & 0xFFFF); }
+
+  // Consume n (≥1) already-buffered bits.
+  inline int take(int n) {
+    cnt -= n;
+    return (int)((buf >> cnt) & ((1u << n) - 1));
+  }
+
+  void align_restart() {
+    buf = 0;
+    cnt = 0;
+    src.align_restart();
+  }
+};
+
+// JPEG EXTEND: map t-bit (t ≥ 1) magnitude to signed value, without a branch on the
+// sign (it cannot be predicted): v - 2^t + 1 where v < 2^(t-1).
+inline int extend(int v, int t) {
+  return v + (((v - (1 << (t - 1))) >> 31) & (int)((~0u << t) + 1));
+}
+
 // Two-level Huffman decode: a 10-bit first-level LUT (2KiB, L1-resident — a flat
 // 16-bit table is 128KiB/table and both costs a per-image build and misses L1 on the
 // random peek pattern) plus the canonical mincode/maxcode/valptr fallback for the rare
 // codes longer than 10 bits. LUT entry = (code_length << 8) | symbol; 0 = fallback.
+//
+// In front of it, ``fast``: one lookup of the next kFastBits bits resolves a code AND
+// its magnitude bits whenever both fit (libjpeg-turbo's jdhuff.c and stb_image's
+// fast_ac do the same). Entry = (value << 16) | (s << 12) | (run << 8) | bits, with
+// ``value`` already EXTENDed, ``s`` its magnitude bits and ``bits`` the code length
+// plus s; value 0 is a symbol with no magnitude (DC category 0, EOB, ZRL). 0 = take
+// the two-level path.
 struct HuffTable {
   static const int kLutBits = 10;
+  static const int kFastBits = 10;
   uint16_t lut[1 << kLutBits];
+  int32_t fast[1 << kFastBits];
   int32_t maxcode[17];  // per length: largest code value, or -1
   int32_t mincode[17];
   int32_t valptr[17];
   uint8_t symbols[256];
+  // the DHT counts and symbols it was built from: the threads of a reader pool decode
+  // stream after stream with the same tables (every cv2/libjpeg default-table
+  // encode), which then cost a compare, not a build
+  uint8_t spec[16 + 256];
+  int spec_len;
   bool present;
 
-  void build(const uint8_t* counts, const uint8_t* syms) {
+  // ``ac``: symbols are (run << 4) | size with size ≤ 10 (else DC categories ≤ 11).
+  void build(const uint8_t* counts, const uint8_t* syms, int total, bool ac) {
+    present = true;
+    if (spec_len == 16 + total && !memcmp(spec, counts, 16) &&
+        !memcmp(spec + 16, syms, total))
+      return;
     memset(lut, 0, sizeof(lut));
-    int total = 0;
-    for (int i = 0; i < 16; i++) total += counts[i];
+    memset(fast, 0, sizeof(fast));
     memcpy(symbols, syms, total);
     uint32_t code = 0;
     int k = 0;
@@ -159,15 +200,15 @@ struct HuffTable {
       if (counts[length - 1]) {
         valptr[length] = k;
         mincode[length] = (int32_t)code;
-        for (int i = 0; i < counts[length - 1]; i++) {
-          if (length <= kLutBits) {
-            uint32_t first = code << (kLutBits - length);
-            uint32_t n = 1u << (kLutBits - length);
-            uint16_t v = (uint16_t)((length << 8) | syms[k]);
-            for (uint32_t j = 0; j < n; j++) lut[first + j] = v;
-          }
-          code++;
-          k++;
+        for (int i = 0; i < counts[length - 1]; i++, code++, k++) {
+          // an over-subscribed (corrupt) table runs past its code space: such codes
+          // are left to the canonical path, which never reads outside ``symbols``
+          if (length > kLutBits || code >= (1u << length)) continue;
+          uint32_t first = code << (kLutBits - length);
+          uint32_t n = 1u << (kLutBits - length);
+          uint16_t v = (uint16_t)((length << 8) | syms[k]);
+          for (uint32_t j = 0; j < n; j++) lut[first + j] = v;
+          add_fast(code, length, syms[k], ac);
         }
         maxcode[length] = (int32_t)code - 1;
       } else {
@@ -175,7 +216,25 @@ struct HuffTable {
       }
       code <<= 1;
     }
-    present = true;
+    memcpy(spec, counts, 16);
+    memcpy(spec + 16, syms, total);
+    spec_len = 16 + total;
+  }
+
+  void add_fast(uint32_t code, int length, uint8_t sym, bool ac) {
+    if (length > kFastBits) return;
+    int run = ac ? sym >> 4 : 0;
+    int s = ac ? sym & 15 : sym;
+    if (s > (ac ? 10 : 11)) return;  // corrupt: the two-level path reports it
+    if (length + s > kFastBits) return;
+    int free_bits = kFastBits - length;
+    uint32_t first = code << free_bits;
+    for (uint32_t j = 0; j < (1u << free_bits); j++) {
+      int value = 0;
+      if (s) value = extend((int)(j >> (free_bits - s)), s);
+      fast[first + j] = (int32_t)(((uint32_t)value << 16) | (uint32_t)(s << 12) |
+                                  (uint32_t)(run << 8) | (uint32_t)(length + s));
+    }
   }
 
   // 16-bit peek → (length << 8) | symbol, or 0 on invalid code.
@@ -190,11 +249,6 @@ struct HuffTable {
     return 0;
   }
 };
-
-// JPEG EXTEND: map t-bit magnitude to signed value.
-inline int extend(int v, int t) {
-  return (v >= (1 << (t - 1))) ? v : v - (1 << t) + 1;
-}
 
 inline uint16_t be16(const uint8_t* p) { return (uint16_t)((p[0] << 8) | p[1]); }
 
@@ -223,7 +277,6 @@ struct ProgScanArgs {
   int width, height, hmax, vmax, mcus_x, mcus_y;
   int16_t* const* blocks;   // per frame component c: padded grid
   const int* out_bx;        // per frame component: padded blocks_x (row stride)
-  int32_t* kmax;            // nullable: per frame component, max zigzag index written
 };
 
 inline int prog_find_next_marker(const uint8_t* data, int64_t len, int64_t from,
@@ -359,7 +412,6 @@ int decode_progressive_scan(const ProgScanArgs& a, int64_t* end_pos) {
                 k += r;
                 if (k > a.Se) return PTPU_JPEG_CORRUPT;
                 blk[kZigzagToNatural[k]] = (int16_t)(extend(br.take(s), s) * p1);
-                if (a.kmax && k > a.kmax[c]) a.kmax[c] = k;
                 k++;
               }
             }
@@ -406,10 +458,7 @@ int decode_progressive_scan(const ProgScanArgs& a, int64_t* end_pos) {
                 }
                 k++;
               }
-              if (s && k <= a.Se) {
-                blk[kZigzagToNatural[k]] = (int16_t)newval;
-                if (a.kmax && k > a.kmax[c]) a.kmax[c] = k;
-              }
+              if (s && k <= a.Se) blk[kZigzagToNatural[k]] = (int16_t)newval;
               k++;
             }
           }
@@ -431,7 +480,7 @@ int decode_progressive_scan(const ProgScanArgs& a, int64_t* end_pos) {
       }
     }
   }
-  return prog_find_next_marker(a.data, a.len, br.pos > a.start ? br.pos : a.start,
+  return prog_find_next_marker(a.data, a.len, br.src.pos > a.start ? br.src.pos : a.start,
                                end_pos);
 }
 
@@ -548,21 +597,154 @@ int32_t pack_row(const PackComp a, int64_t i) {
                             a.out2 + i * nb * ((k - a.k2) / 2));
 }
 
+// -- baseline scan --------------------------------------------------------------
+
+// What one stream's decode gives besides its coefficients and quantization tables.
+struct StreamStats {
+  int32_t specmax[4][64];  // per component and ZIGZAG position: the largest |value|
+  int64_t symbols;         // Huffman symbols decoded
+  int64_t fast_symbols;    // ... of them resolved, magnitude bits included, by
+                           // HuffTable::fast's one lookup
+};
+
+// One baseline block, DC then AC, into ``blk`` (zeroed here), folding each value's
+// magnitude into the component's zigzag profile ``sm``. The bit buffer is the
+// caller's locals. False on an invalid code or magnitude category.
+inline __attribute__((always_inline)) bool decode_block(
+    BitSrc& src, uint64_t& buf, int& cnt, const HuffTable& dc, const HuffTable& ac,
+    int& pred, int16_t* blk, int32_t* sm, int64_t& nsym, int64_t& nfast) {
+  const int kFast = HuffTable::kFastBits;
+  const uint64_t kMask = (1u << kFast) - 1;
+  memset(blk, 0, 64 * sizeof(int16_t));
+  // DC: code ≤16 + magnitude ≤11 bits
+  if (cnt < 28) refill(src, buf, cnt);
+  nsym++;
+  int32_t fe = dc.fast[(buf >> (cnt - kFast)) & kMask];
+  if (fe) {
+    nfast++;
+    cnt -= fe & 31;
+    pred += fe >> 16;
+  } else {
+    uint32_t e = dc.decode((uint32_t)(buf >> (cnt - 16)) & 0xFFFF);
+    if (!e) return false;
+    cnt -= e >> 8;
+    int t = e & 0xFF;
+    if (t > 11) return false;  // 8-bit baseline DC category ≤ 11; larger → corrupt DHT
+    if (t) {
+      cnt -= t;
+      pred += extend((int)(buf >> cnt) & ((1 << t) - 1), t);
+    }
+  }
+  int16_t d = (int16_t)pred;
+  blk[0] = d;
+  sm[0] = std::max(sm[0], (int32_t)(d < 0 ? -d : d));
+  // AC: code ≤16 + magnitude ≤10 bits
+  int k = 1;
+  while (k < 64) {
+    if (cnt < 26) refill(src, buf, cnt);
+    nsym++;
+    fe = ac.fast[(buf >> (cnt - kFast)) & kMask];
+    int v;
+    if (fe) {
+      nfast++;
+      cnt -= fe & 31;
+      v = fe >> 16;
+      int r = (fe >> 8) & 15;
+      if (v == 0) {  // EOB, or ZRL (16 zeros)
+        if (r != 15) break;
+        k += 16;
+        continue;
+      }
+      k += r;
+      if (k > 63) {  // run past the block: its magnitude bits stay unread
+        cnt += (fe >> 12) & 15;
+        break;
+      }
+    } else {
+      uint32_t e = ac.decode((uint32_t)(buf >> (cnt - 16)) & 0xFFFF);
+      if (!e) return false;
+      cnt -= e >> 8;
+      int r = (e >> 4) & 15, s = e & 15;
+      if (s == 0) {
+        if (r != 15) break;
+        k += 16;
+        continue;
+      }
+      if (s > 10) return false;  // 8-bit baseline AC size ≤ 10
+      k += r;
+      if (k > 63) break;
+      cnt -= s;
+      v = extend((int)(buf >> cnt) & ((1 << s) - 1), s);
+    }
+    blk[kZigzagToNatural[k]] = (int16_t)v;
+    sm[k] = std::max(sm[k], v < 0 ? -v : v);
+    k++;
+  }
+  return true;
+}
+
+// The single interleaved scan of a baseline frame, every block of the padded MCU
+// grid (so each is zeroed and written here: the buffers need no clearing first).
+int decode_baseline_scan(BitSrc src, const JComp* comps, int ncomp,
+                         const HuffTable* huff_dc, const HuffTable* huff_ac,
+                         int restart_interval, int mcus_x, int mcus_y,
+                         int16_t* const* blocks, const int32_t* blocks_x,
+                         StreamStats* st) {
+  uint64_t buf = 0;
+  int cnt = 0;
+  int pred[4] = {0, 0, 0, 0};
+  int64_t nsym = 0, nfast = 0;
+  int to_restart = restart_interval;
+  for (int my = 0; my < mcus_y; my++) {
+    for (int mx = 0; mx < mcus_x; mx++) {
+      if (restart_interval) {
+        if (to_restart == 0) {
+          buf = 0;
+          cnt = 0;
+          src.align_restart();
+          pred[0] = pred[1] = pred[2] = pred[3] = 0;
+          to_restart = restart_interval;
+        }
+        to_restart--;
+      }
+      for (int c = 0; c < ncomp; c++) {
+        const JComp& comp = comps[c];
+        const HuffTable& dc = huff_dc[comp.dc_tbl];
+        const HuffTable& ac = huff_ac[comp.ac_tbl];
+        for (int v = 0; v < comp.v; v++) {
+          int16_t* blk = blocks[c] + ((size_t)(my * comp.v + v) * blocks_x[c] +
+                                      (size_t)mx * comp.h) * 64;
+          for (int hh = 0; hh < comp.h; hh++, blk += 64) {
+            if (!decode_block(src, buf, cnt, dc, ac, pred[c], blk, st->specmax[c],
+                              nsym, nfast)) {
+              st->symbols += nsym;
+              st->fast_symbols += nfast;
+              return PTPU_JPEG_CORRUPT;
+            }
+          }
+        }
+      }
+    }
+  }
+  st->symbols += nsym;
+  st->fast_symbols += nfast;
+  return PTPU_JPEG_OK;
+}
+
+// Per-zigzag-position max |coefficient| of nblocks natural-order blocks, into out.
+void fold_specmax(const int16_t* src, int64_t nblocks, int32_t* out) {
+  for (int64_t b = 0; b < nblocks; b++) {
+    const int16_t* s = src + b * 64;
+    for (int32_t j = 0; j < 64; j++) {
+      int32_t v = s[kZigzagToNatural[j]];
+      out[j] = std::max(out[j], v < 0 ? -v : v);
+    }
+  }
+}
+
 }  // namespace
 
 extern "C" {
-
-typedef struct {
-  int32_t height;
-  int32_t width;
-  int32_t ncomp;
-  int32_t h_samp[4];
-  int32_t v_samp[4];
-  int32_t blocks_y[4];
-  int32_t blocks_x[4];
-  int16_t* blocks[4];      // malloc'ed: blocks_y*blocks_x*64 int16, natural order
-  uint16_t qtables[4][64]; // natural order
-} PtpuJpegCoeffs;
 
 // Decode layout: everything that shapes the stacked coefficient buffers (the batch
 // API requires every stream in a batch to share one).
@@ -576,14 +758,6 @@ typedef struct {
   int32_t blocks_x[4];
 } PtpuJpegLayout;
 
-void ptpu_jpeg_free_coeffs(PtpuJpegCoeffs* out) {
-  if (!out) return;
-  for (int i = 0; i < 4; i++) {
-    free(out->blocks[i]);
-    out->blocks[i] = nullptr;
-  }
-}
-
 const char* ptpu_jpeg_error_string(int code) {
   switch (code) {
     case PTPU_JPEG_OK: return "ok";
@@ -594,30 +768,26 @@ const char* ptpu_jpeg_error_string(int code) {
     case PTPU_JPEG_NOT_8BIT: return "Only 8-bit baseline JPEG supported";
     case PTPU_JPEG_BAD_COMPONENTS: return "Unsupported component count/sampling";
     case PTPU_JPEG_NO_SCAN: return "No SOS marker found";
-    case PTPU_JPEG_OOM: return "Out of memory";
     case PTPU_JPEG_LAYOUT_MISMATCH:
       return "JPEG layout differs from the batch layout";
     default: return "Unknown error";
   }
 }
 
-// Core decoder. When ``dst``/``qdst`` are given (batch mode) the coefficient blocks are
-// written into the caller's buffers (dst[c]: blocks_y*blocks_x*64 int16 each, qdst:
-// ncomp*64 uint16 natural order) after verifying the stream's layout equals ``expect``;
-// nothing is allocated and nothing must be freed. Otherwise blocks are malloc'ed into
-// ``out`` (ptpu_jpeg_free_coeffs frees them). ``kmax`` (nullable, per component) is
-// raised to the largest ZIGZAG index this stream writes a coefficient at — free to
-// track during entropy decode, and it lets the caller ship only the nonzero zigzag
-// prefix to the device.
-static int decode_impl(const uint8_t* data, int64_t len, PtpuJpegCoeffs* out,
-                       const PtpuJpegLayout* expect, int16_t* const* dst,
-                       uint16_t* qdst, int32_t* kmax) {
-  memset(out, 0, sizeof(*out));
+// Core decoder: one stream into the caller's buffers (dst[c]: blocks_y*blocks_x*64
+// int16 each, natural order; qdst: ncomp*64 int32 natural order) after verifying its
+// layout equals ``expect``. ``st`` gets the stream's zigzag profile (``specmax``, the
+// largest |value| at each position: the highest nonzero one is how far the caller
+// must ship, and the profile picks the bit widths it ships in) and its symbol counts.
+static int decode_impl(const uint8_t* data, int64_t len, const PtpuJpegLayout* expect,
+                       int16_t* const* dst, int32_t* qdst, StreamStats* st) {
+  memset(st, 0, sizeof(*st));
   if (len < 4 || data[0] != 0xFF || data[1] != 0xD8) return PTPU_JPEG_NOT_JPEG;
 
   int32_t qt_zz[4][64];  // DQT tables in zigzag order as parsed
   bool qt_present[4] = {false, false, false, false};
-  static thread_local HuffTable huff_dc[4], huff_ac[4];  // ~10KiB; off-stack, re-entrant
+  // ~50KiB; off-stack, re-entrant, and kept from stream to stream (HuffTable::build)
+  static thread_local HuffTable huff_dc[4], huff_ac[4];
   for (int i = 0; i < 4; i++) {
     huff_dc[i].present = false;
     huff_ac[i].present = false;
@@ -742,9 +912,9 @@ static int decode_impl(const uint8_t* data, int64_t len, PtpuJpegCoeffs* out,
           goto done;
         }
         if (tc == 0)
-          huff_dc[th].build(counts, seg + s + 17);
+          huff_dc[th].build(counts, seg + s + 17, total, false);
         else
-          huff_ac[th].build(counts, seg + s + 17);
+          huff_ac[th].build(counts, seg + s + 17, total, true);
         s += 17 + total;
       }
     } else if (marker == 0xDD) {  // DRI
@@ -798,48 +968,31 @@ static int decode_impl(const uint8_t* data, int64_t len, PtpuJpegCoeffs* out,
       int Al = seg[3 + 2 * ns] & 0xF;
 
       if (!allocated) {
-        // first scan: verify layout, set up (or adopt) coefficient storage
+        // first scan: verify the layout; progressive scans add to the coefficients,
+        // so their buffers start at zero (a baseline scan writes every block whole)
         for (int c = 0; c < ncomp; c++) {
           if (!qt_present[comps[c].tq]) {
             rc = PTPU_JPEG_CORRUPT;
             goto done;
           }
         }
-        out->height = height;
-        out->width = width;
-        out->ncomp = ncomp;
-        if (expect && (height != expect->height || width != expect->width ||
-                       ncomp != expect->ncomp)) {
+        if (height != expect->height || width != expect->width ||
+            ncomp != expect->ncomp) {
           rc = PTPU_JPEG_LAYOUT_MISMATCH;
           goto done;
         }
         for (int c = 0; c < ncomp; c++) {
           int bx = mcus_x * comps[c].h;
           int by = mcus_y * comps[c].v;
-          out->h_samp[c] = comps[c].h;
-          out->v_samp[c] = comps[c].v;
-          out->blocks_y[c] = by;
-          out->blocks_x[c] = bx;
-          if (expect && (comps[c].h != expect->h_samp[c] ||
-                         comps[c].v != expect->v_samp[c] ||
-                         by != expect->blocks_y[c] || bx != expect->blocks_x[c])) {
+          if (comps[c].h != expect->h_samp[c] || comps[c].v != expect->v_samp[c] ||
+              by != expect->blocks_y[c] || bx != expect->blocks_x[c]) {
             rc = PTPU_JPEG_LAYOUT_MISMATCH;
             goto done;
           }
-          if (dst) {
-            out->blocks[c] = dst[c];
-            memset(dst[c], 0, (size_t)by * bx * 64 * sizeof(int16_t));
-          } else {
-            out->blocks[c] = (int16_t*)calloc((size_t)by * bx * 64, sizeof(int16_t));
-            if (!out->blocks[c]) {
-              rc = PTPU_JPEG_OOM;
-              goto done;
-            }
-          }
+          if (progressive) memset(dst[c], 0, (size_t)by * bx * 64 * sizeof(int16_t));
           const int32_t* zz = qt_zz[comps[c].tq];
-          uint16_t* qout = qdst ? qdst + (size_t)c * 64 : out->qtables[c];
-          for (int k = 0; k < 64; k++)
-            qout[kZigzagToNatural[k]] = (uint16_t)zz[k];
+          int32_t* qout = qdst + (size_t)c * 64;
+          for (int k = 0; k < 64; k++) qout[kZigzagToNatural[k]] = (uint16_t)zz[k];
         }
         allocated = true;
       }
@@ -878,9 +1031,8 @@ static int decode_impl(const uint8_t* data, int64_t len, PtpuJpegCoeffs* out,
         pargs.vmax = vmax;
         pargs.mcus_x = mcus_x;
         pargs.mcus_y = mcus_y;
-        pargs.blocks = out->blocks;
-        pargs.out_bx = out->blocks_x;
-        pargs.kmax = kmax;
+        pargs.blocks = dst;
+        pargs.out_bx = expect->blocks_x;
         int64_t next_pos = 0;
         rc = decode_progressive_scan(pargs, &next_pos);
         if (rc != PTPU_JPEG_OK) goto done;
@@ -904,89 +1056,25 @@ static int decode_impl(const uint8_t* data, int64_t len, PtpuJpegCoeffs* out,
         }
       }
 
-      BitReader br;
-      br.init(data, len, pos + seglen);
-      int pred[4] = {0, 0, 0, 0};
-      int mcu_count = 0;
-      for (int my = 0; my < mcus_y; my++) {
-        for (int mx = 0; mx < mcus_x; mx++) {
-          if (restart_interval && mcu_count && mcu_count % restart_interval == 0) {
-            br.align_restart();
-            pred[0] = pred[1] = pred[2] = pred[3] = 0;
-          }
-          for (int c = 0; c < ncomp; c++) {
-            const HuffTable& dc_tab = huff_dc[comps[c].dc_tbl];
-            const HuffTable& ac_tab = huff_ac[comps[c].ac_tbl];
-            for (int v = 0; v < comps[c].v; v++) {
-              for (int hh = 0; hh < comps[c].h; hh++) {
-                int brow = my * comps[c].v + v;
-                int bcol = mx * comps[c].h + hh;
-                int16_t* blk =
-                    out->blocks[c] + ((size_t)brow * out->blocks_x[c] + bcol) * 64;
-                // DC (code ≤16 + magnitude ≤11 bits: one refill guard covers both)
-                br.ensure28();
-                uint32_t e = dc_tab.decode(br.peek16_raw());
-                if (!e) {
-                  rc = PTPU_JPEG_CORRUPT;
-                  goto done;
-                }
-                br.cnt -= e >> 8;
-                int t = e & 0xFF;
-                if (t > 11) {  // 8-bit baseline DC category ≤ 11; larger → corrupt DHT
-                  rc = PTPU_JPEG_CORRUPT;
-                  goto done;
-                }
-                if (t) pred[c] += extend(br.take(t), t);
-                blk[0] = (int16_t)pred[c];
-                // AC
-                int k = 1;
-                while (k < 64) {
-                  br.ensure28();
-                  e = ac_tab.decode(br.peek16_raw());
-                  if (!e) {
-                    rc = PTPU_JPEG_CORRUPT;
-                    goto done;
-                  }
-                  br.cnt -= e >> 8;
-                  int r = (e & 0xFF) >> 4, s = e & 0xF;
-                  if (s == 0) {
-                    if (r == 15) {
-                      k += 16;
-                      continue;
-                    }
-                    break;  // EOB
-                  }
-                  if (s > 10) {  // 8-bit baseline AC size ≤ 10; also keeps the 28-bit
-                    rc = PTPU_JPEG_CORRUPT;  // ensure28 window sufficient (16+10 < 28)
-                    goto done;
-                  }
-                  k += r;
-                  if (k > 63) break;
-                  blk[kZigzagToNatural[k]] = (int16_t)extend(br.take(s), s);
-                  if (kmax && k > kmax[c]) kmax[c] = k;
-                  k++;
-                }
-              }
-            }
-          }
-          mcu_count++;
-        }
-      }
-      rc = PTPU_JPEG_OK;
+      rc = decode_baseline_scan(BitSrc{data, len, pos + seglen, false}, comps, ncomp,
+                                huff_dc, huff_ac, restart_interval, mcus_x, mcus_y,
+                                dst, expect->blocks_x, st);
       goto done;
     }
     pos += seglen;
   }
-  // progressive streams succeed at EOI (or end of data) once any scan landed
-  if (progressive && allocated && scans_done > 0) rc = PTPU_JPEG_OK;
+  // progressive streams succeed at EOI (or end of data) once any scan landed; their
+  // profile is read off the finished coefficients (a refinement may change a value
+  // after its first scan)
+  if (progressive && allocated && scans_done > 0) {
+    rc = PTPU_JPEG_OK;
+    for (int c = 0; c < ncomp; c++)
+      fold_specmax(dst[c], (int64_t)expect->blocks_y[c] * expect->blocks_x[c],
+                   st->specmax[c]);
+  }
 
 done:
-  if (rc != PTPU_JPEG_OK && !dst) ptpu_jpeg_free_coeffs(out);
   return rc;
-}
-
-int ptpu_jpeg_decode_coeffs(const uint8_t* data, int64_t len, PtpuJpegCoeffs* out) {
-  return decode_impl(data, len, out, nullptr, nullptr, nullptr, nullptr);
 }
 
 // Parse only as far as the frame header; fills the decode layout without touching the
@@ -1048,40 +1136,54 @@ int ptpu_jpeg_parse_layout(const uint8_t* data, int64_t len, PtpuJpegLayout* out
 // Batched decode: n streams (stream i = datas[i][0..lens[i])), all expected to share
 // ``expect``'s layout, written into caller-allocated stacked buffers:
 //   out_blocks[c] : (n, blocks_y[c]*blocks_x[c], 64) int16, C-contiguous
-//   out_qtabs     : (n, ncomp, 64) uint16, natural order
-//   out_kmax      : per component (size 4), max ZIGZAG index any stream wrote —
-//                   coefficients at zigzag positions > out_kmax[c] are all zero, so
-//                   the caller may ship only the prefix (ptpu_jpeg_zigzag_truncate)
-// status[i] = PTPU_JPEG_OK or the stream's error code (its slice is left zeroed; the
-// caller re-decodes failed rows individually). Returns the number of failed streams.
-// One call decodes a whole row group with the GIL released.
+//   out_qtabs     : (n, ncomp, 64) int32, natural order
+//   out_kmax      : per component (size 4), the highest ZIGZAG position any stream
+//                   holds a nonzero coefficient at — the caller may ship only the
+//                   prefix up to it (ptpu_jpeg_zigzag_truncate)
+//   out_specmax   : (ncomp, 64) int32, per zigzag position the largest |coefficient|
+//                   of any stream (what ptpu_jpeg_specmax gives over out_blocks)
+//   out_symbols   : (2,) int64, the Huffman symbols decoded and those of them that
+//                   one lookup resolved, magnitude bits included
+// status[i] = PTPU_JPEG_OK or the stream's error code (its slice is left zeroed and
+// left out of kmax and specmax; the caller re-decodes failed rows individually).
+// Returns the number of failed streams. One call decodes a whole row group, or one
+// image (n = 1), with the GIL released.
 int ptpu_jpeg_decode_batch(const uint8_t* const* datas, const int64_t* lens, int32_t n,
                            const PtpuJpegLayout* expect, int16_t* const* out_blocks,
-                           uint16_t* out_qtabs, int32_t* out_kmax, int32_t* status) {
+                           int32_t* out_qtabs, int32_t* out_kmax, int32_t* out_specmax,
+                           int64_t* out_symbols, int32_t* status) {
+  const int ncomp = expect->ncomp < 4 ? expect->ncomp : 4;
   size_t stride[4];
-  for (int c = 0; c < expect->ncomp && c < 4; c++)
+  for (int c = 0; c < ncomp; c++)
     stride[c] = (size_t)expect->blocks_y[c] * expect->blocks_x[c] * 64;
-  for (int c = 0; c < 4; c++) out_kmax[c] = 0;
+  memset(out_specmax, 0, (size_t)ncomp * 64 * sizeof(int32_t));
+  out_symbols[0] = out_symbols[1] = 0;
   int failures = 0;
+  StreamStats st;
   for (int32_t i = 0; i < n; i++) {
     int16_t* dst[4] = {nullptr, nullptr, nullptr, nullptr};
-    for (int c = 0; c < expect->ncomp && c < 4; c++)
-      dst[c] = out_blocks[c] + (size_t)i * stride[c];
-    PtpuJpegCoeffs tmp;
-    int32_t kmax_local[4] = {0, 0, 0, 0};
-    int rc = decode_impl(datas[i], lens[i], &tmp, expect, dst,
-                         out_qtabs + (size_t)i * expect->ncomp * 64, kmax_local);
+    for (int c = 0; c < ncomp; c++) dst[c] = out_blocks[c] + (size_t)i * stride[c];
+    int rc = decode_impl(datas[i], lens[i], expect, dst,
+                         out_qtabs + (size_t)i * expect->ncomp * 64, &st);
     status[i] = rc;
+    out_symbols[0] += st.symbols;
+    out_symbols[1] += st.fast_symbols;
     if (rc != PTPU_JPEG_OK) {
       failures++;
-      for (int c = 0; c < expect->ncomp && c < 4; c++)
-        memset(dst[c], 0, stride[c] * sizeof(int16_t));
+      for (int c = 0; c < ncomp; c++) memset(dst[c], 0, stride[c] * sizeof(int16_t));
     } else {
-      // merge only successful streams: a corrupt stream's partial garbage writes are
-      // zeroed above and must not inflate the row group's kmax
-      for (int c = 0; c < expect->ncomp && c < 4; c++)
-        if (kmax_local[c] > out_kmax[c]) out_kmax[c] = kmax_local[c];
+      for (int c = 0; c < ncomp; c++)
+        for (int j = 0; j < 64; j++)
+          out_specmax[c * 64 + j] = std::max(out_specmax[c * 64 + j], st.specmax[c][j]);
     }
+  }
+  for (int c = 0; c < 4; c++) {
+    out_kmax[c] = 0;
+    for (int j = 63; c < ncomp && j > 0; j--)
+      if (out_specmax[c * 64 + j]) {
+        out_kmax[c] = j;
+        break;
+      }
   }
   return failures;
 }

@@ -78,6 +78,28 @@ def count(name, n=1):
         sink.add_count(name, n)
 
 
+#: process-wide totals of work done on threads where no loader stage is open (the
+#: reader pool's stage-1 decode): name -> total since the process started
+_tallies = {}
+_tally_lock = threading.Lock()
+
+
+def tally(**counts):
+    """Add each ``name=n`` to the process-wide totals :func:`tallies` reads. For
+    work on the reader pool's threads, which :func:`count` cannot reach: every
+    loader of the process folds what the totals grew by into its
+    ``PipelineStats`` as it goes."""
+    with _tally_lock:
+        for name, n in counts.items():
+            _tallies[name] = _tallies.get(name, 0) + n
+
+
+def tallies():
+    """A copy of the process-wide totals :func:`tally` adds to."""
+    with _tally_lock:
+        return dict(_tallies)
+
+
 class phase:
     """A part of a loader stage: ``ptpu.<name>`` on the profiler's clock (its
     ``args`` as metadata; ``as`` gives the span, for ``set_metadata``, or None

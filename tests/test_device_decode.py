@@ -323,7 +323,7 @@ def test_native_rejects_corrupt_category_codes():
     for j in range(total):
         data[i + 21 + j] = 200
     with pytest.raises(ValueError):
-        native.jpeg_decode_coeffs_native(bytes(data))
+        native.jpeg_decode_image_native(bytes(data))
 
 
 def test_cache_key_distinguishes_device_payloads():
@@ -847,3 +847,27 @@ def test_weighted_sampling_device_decode_with_resize(tmp_path):
             assert arr.shape[1:] == (32, 32, 3)
             seen += len(arr)
     assert seen == 8
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["row_group", "per_image"])
+def test_stage1_counters_fold_into_pipeline_stats(jpeg_dataset, tmp_path, mixed):
+    """The reader pool's stage-1 decode counts into the loader's ``PipelineStats``
+    through the process-wide totals: every staged image once, the decoding threads'
+    CPU time, the Huffman symbols; ``reset()`` clears them. A dummy pool decodes
+    inside the loader's reads, so nothing is staged before the loader starts."""
+    if mixed:  # several sizes in a row group: stage 1 goes image by image
+        url, _, _ = _mixed_size_store(tmp_path, [(32, 48), (64, 40), (48, 48)] * 4)
+        kwargs, rows = {"device_decode_resize": (32, 32)}, 12
+    else:  # one layout: the row group's one batched call
+        url, kwargs, rows = jpeg_dataset.url, {}, 24
+    reader = make_batch_reader(url, decode_on_device=True, num_epochs=1,
+                               shuffle_row_groups=False, reader_pool_type="dummy")
+    with DataLoader(reader, batch_size=4, **kwargs) as loader:
+        delivered = sum(len(np.asarray(b["id"])) for b in loader)
+    stats = loader.stats
+    assert delivered == rows and stats.stage1_images == rows
+    assert stats.stage1_ns > 0
+    assert stats.stage1_symbols >= stats.stage1_fast_symbols > 0
+    assert stats.snapshot()["stage1_images"] == rows
+    stats.reset()
+    assert stats.stage1_images == stats.stage1_ns == stats.stage1_symbols == 0

@@ -24,6 +24,47 @@ def _roundtrip(img, quality=90):
     return data, ref
 
 
+def _raw_cell_images(qualities, size, seed=5, per_quality=1):
+    """Images of the raw ImageNet cell's model (``bench/writers/jpeg_raw_store``: a
+    blurred noise field over a gradient, a hard-edged patch and fine texture, 4:2:0)
+    with both sides drawn from ``size``; one JPEG a quality and index."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "bench", "writers",
+                        "jpeg_raw_store.py")
+    spec = importlib.util.spec_from_file_location("jpeg_raw_store", path)
+    writer = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(writer)
+    return [writer._image({"size_mixture": [{"share": 1.0, "both": list(size)}],
+                           "jpeg_quality": q}, seed, i)
+            for q in qualities for i in range(per_quality)]
+
+
+def _extreme_gray_q100():
+    """Grayscale q100: 8x8 blocks alternating 0/255 (DC differences of category 11)
+    over 1-pixel stripes (AC values of size 10)."""
+    img = np.zeros((64, 64), np.uint8)
+    by, bx = np.indices((4, 8))
+    img[:32] = np.kron((by + bx) % 2 * 255, np.ones((8, 8))).astype(np.uint8)
+    img[32:, ::2] = 255
+    ok, enc = cv2.imencode(".jpg", img, [cv2.IMWRITE_JPEG_QUALITY, 100])
+    assert ok
+    return enc.tobytes()
+
+
+def _assert_profile_is_the_separate_passes(planes):
+    """A stage-1 result's folded ``specmax`` and ``kmax`` equal what
+    ``jpeg_specmax_native`` and ``flatnonzero`` give over its decoded blocks."""
+    from petastorm_tpu.ops import native
+
+    for c, comp in enumerate(planes.components):
+        want = native.jpeg_specmax_native(
+            np.ascontiguousarray(comp.blocks, dtype=np.int16).reshape(1, -1, 64))
+        np.testing.assert_array_equal(planes.specmax[c], want)
+        assert planes.kmax[c] == (np.flatnonzero(want).max() if want.any() else 0)
+
+
 def test_gradient_image_close_to_libjpeg():
     gx = np.tile(np.linspace(0, 255, 64)[None, :], (48, 1))
     gy = np.tile(np.linspace(0, 255, 48)[:, None], (1, 64))
@@ -114,16 +155,38 @@ def test_native_stage1_bit_exact_vs_python_oracle():
     ok, enc = cv2.imencode(".jpg", rng.randint(0, 256, (40, 56), dtype=np.uint8),
                            [cv2.IMWRITE_JPEG_QUALITY, 95])
     streams.append(enc.tobytes())
+    # the raw cell's noisy originals at q90 and q95 (at its smallest sizes: the
+    # oracle reads bit by bit), one with restart intervals; 4:4:4 and 4:2:2; the
+    # largest DC categories and AC sizes baseline allows
+    streams += _raw_cell_images((90, 95), (150, 164))
+    img = cv2.imdecode(np.frombuffer(streams[-1], np.uint8), cv2.IMREAD_COLOR)
+    for opts in ([cv2.IMWRITE_JPEG_QUALITY, 90, cv2.IMWRITE_JPEG_RST_INTERVAL, 3],
+                 [cv2.IMWRITE_JPEG_QUALITY, 92, cv2.IMWRITE_JPEG_SAMPLING_FACTOR,
+                  cv2.IMWRITE_JPEG_SAMPLING_FACTOR_444],
+                 [cv2.IMWRITE_JPEG_QUALITY, 92, cv2.IMWRITE_JPEG_SAMPLING_FACTOR,
+                  cv2.IMWRITE_JPEG_SAMPLING_FACTOR_422]):
+        ok, enc = cv2.imencode(".jpg", img[:64, :80], opts)
+        assert ok
+        streams.append(enc.tobytes())
+    streams.append(_extreme_gray_q100())
 
     for data in streams:
         py = entropy_decode_jpeg(data)
         nat = entropy_decode_jpeg_fast(data)
+        _assert_profile_is_the_separate_passes(nat)
         assert (py.height, py.width) == (nat.height, nat.width)
         assert len(py.components) == len(nat.components)
         for pc, nc in zip(py.components, nat.components):
             assert (pc.h_samp, pc.v_samp) == (nc.h_samp, nc.v_samp)
             np.testing.assert_array_equal(pc.blocks, nc.blocks.astype(np.int32))
             np.testing.assert_array_equal(pc.qtable, nc.qtable)
+    # the cases reach what the one-lookup path must hand on or decode whole: 0xFF00
+    # stuffing, DC differences of category 11 (grayscale: one block an MCU, in
+    # raster order) and AC values of size 10
+    assert all(b"\xff\x00" in data for data in streams[5:7])
+    blocks = entropy_decode_jpeg_fast(streams[-1]).components[0].blocks.reshape(-1, 64)
+    assert np.abs(np.diff(blocks[:, 0].astype(np.int32), prepend=0)).max() >= 1024
+    assert np.abs(blocks[:, 1:]).max() >= 512
 
 
 def test_native_stage1_rejects_bad_streams():
@@ -132,12 +195,12 @@ def test_native_stage1_rejects_bad_streams():
     if not native.native_available():
         pytest.skip("native toolchain unavailable: %s" % native.native_error())
     with pytest.raises(ValueError, match="SOI"):
-        native.jpeg_decode_coeffs_native(b"\x00\x01\x02\x03")
+        native.jpeg_decode_image_native(b"\x00\x01\x02\x03")
     # lossless (SOF3) stays unsupported
     lossless = (b"\xff\xd8\xff\xc3\x00\x0b\x08\x00\x10\x00\x10\x01\x01\x11\x00"
                 b"\xff\xd9")
     with pytest.raises(ValueError, match="[Uu]nsupported"):
-        native.jpeg_decode_coeffs_native(lossless)
+        native.jpeg_decode_image_native(lossless)
 
 
 def test_native_progressive_matches_cv2():
@@ -160,8 +223,15 @@ def test_native_progressive_matches_cv2():
                        cv2.IMWRITE_JPEG_RST_INTERVAL, 2]),
         ((48, 48), [cv2.IMWRITE_JPEG_QUALITY, 90, cv2.IMWRITE_JPEG_PROGRESSIVE, 1]),
     ]
-    for shape, opts in cases:
-        img = rng.randint(0, 256, shape, dtype=np.uint8)
+    cases = [(rng.randint(0, 256, shape, dtype=np.uint8), opts) for shape, opts in cases]
+    # the raw cell's noisy originals, re-encoded progressive at q95 (4:2:0)
+    raw = cv2.imdecode(np.frombuffer(_raw_cell_images((95,), (150, 164))[0], np.uint8),
+                       cv2.IMREAD_COLOR)
+    cases += [(raw, [cv2.IMWRITE_JPEG_QUALITY, 95, cv2.IMWRITE_JPEG_PROGRESSIVE, 1]),
+              (raw, [cv2.IMWRITE_JPEG_QUALITY, 95, cv2.IMWRITE_JPEG_PROGRESSIVE, 1,
+                     cv2.IMWRITE_JPEG_OPTIMIZE, 1, cv2.IMWRITE_JPEG_RST_INTERVAL, 5])]
+    for img, opts in cases:
+        shape = img.shape
         ok, enc = cv2.imencode(".jpg", img, opts)
         assert ok
         data = enc.tobytes()
@@ -169,7 +239,9 @@ def test_native_progressive_matches_cv2():
         ref = cv2.imdecode(np.frombuffer(data, np.uint8), flag)
         if ref.ndim == 2:
             ref = np.stack([ref] * 3, -1)
-        ours = np.asarray(decode_jpeg_device_stage(entropy_decode_jpeg_fast(data)))
+        planes = entropy_decode_jpeg_fast(data)
+        _assert_profile_is_the_separate_passes(planes)
+        ours = np.asarray(decode_jpeg_device_stage(planes))
         # our stage-2 output is RGB; cv2 color reads BGR
         if len(shape) == 3:
             ours = ours[:, :, ::-1]
@@ -215,11 +287,11 @@ def test_native_stage1_throughput_beats_python():
     img = rng.randint(0, 256, (128, 128, 3), dtype=np.uint8)
     ok, enc = cv2.imencode(".jpg", img, [cv2.IMWRITE_JPEG_QUALITY, 85])
     data = enc.tobytes()
-    native.jpeg_decode_coeffs_native(data)  # warm (build cached)
+    native.jpeg_decode_image_native(data)  # warm (build cached)
     t_native = float("inf")
     for _ in range(5):  # min-of-N: one scheduler hiccup must not fail the suite
         t0 = time.perf_counter()
-        native.jpeg_decode_coeffs_native(data)
+        native.jpeg_decode_image_native(data)
         t_native = min(t_native, time.perf_counter() - t0)
     t0 = time.perf_counter()
     entropy_decode_jpeg(data)
@@ -317,18 +389,31 @@ def test_rowgroup_batched_stage1_matches_per_image():
                                [cv2.IMWRITE_JPEG_QUALITY, quality])
         assert ok
         blobs.append(enc.tobytes())
-    batch = entropy_decode_jpeg_batch(blobs)
-    assert all(p is not None for p in batch)
-    for p, blob in zip(batch, blobs):
-        ref = entropy_decode_jpeg_fast(blob)
-        assert (p.height, p.width) == (ref.height, ref.width)
-        assert p.batch_ref is not None
-        for pc, rc in zip(p.components, ref.components):
-            assert (pc.h_samp, pc.v_samp) == (rc.h_samp, rc.v_samp)
-            np.testing.assert_array_equal(pc.blocks, rc.blocks)
-            np.testing.assert_array_equal(pc.qtable, rc.qtable)
-            # views into the stacked parent, not copies
-            assert pc.blocks.base is not None
+    # and a row group of the raw cell's noisy originals at one of its sizes, q90/q95
+    for group in (blobs, _raw_cell_images((90, 95), (375, 375), per_quality=2)):
+        batch = entropy_decode_jpeg_batch(group)
+        assert all(p is not None for p in batch)
+        for p, blob in zip(batch, group):
+            ref = entropy_decode_jpeg_fast(blob)
+            _assert_profile_is_the_separate_passes(ref)
+            assert (p.height, p.width) == (ref.height, ref.width)
+            assert p.batch_ref is not None
+            for pc, rc in zip(p.components, ref.components):
+                assert (pc.h_samp, pc.v_samp) == (rc.h_samp, rc.v_samp)
+                np.testing.assert_array_equal(pc.blocks, rc.blocks)
+                np.testing.assert_array_equal(pc.qtable, rc.qtable)
+                # views into the stacked parent, not copies
+                assert pc.blocks.base is not None
+        # the row group's folded profile: the separate pass over its stacked buffers,
+        # and the elementwise max of its images' own
+        coeffs = batch[0].batch_ref[0]
+        for c, stacked in enumerate(coeffs):
+            want = native.jpeg_specmax_native(stacked)
+            np.testing.assert_array_equal(batch[0].specmax[c], want)
+            assert batch[0].kmax[c] == (np.flatnonzero(want).max() if want.any() else 0)
+        np.testing.assert_array_equal(
+            batch[0].specmax,
+            np.maximum.reduce([entropy_decode_jpeg_fast(b).specmax for b in group]))
 
 
 def test_rowgroup_batched_stage1_bad_rows_are_none():
@@ -441,7 +526,7 @@ def test_second_sof_rejected_not_crash():
     assert data.endswith(b"\xff\xd9")
     evil = data[:-2] + bytes(big_sof) + data[sof:len(data)]  # 2nd SOF + scans + EOI
     with pytest.raises(ValueError):
-        native.jpeg_decode_coeffs_native(evil)
+        native.jpeg_decode_image_native(evil)
 
 
 def test_progressive_coefficients_bit_exact_vs_baseline():
@@ -455,8 +540,10 @@ def test_progressive_coefficients_bit_exact_vs_baseline():
     from petastorm_tpu.ops.jpeg import entropy_decode_jpeg_fast
 
     rng = np.random.RandomState(41)
-    for shape in ((64, 80, 3), (17, 19, 3), (48, 48)):
-        img = rng.randint(0, 256, shape, dtype=np.uint8)
+    raw = cv2.imdecode(np.frombuffer(_raw_cell_images((90,), (150, 164))[0], np.uint8),
+                       cv2.IMREAD_COLOR)
+    for img in [rng.randint(0, 256, shape, dtype=np.uint8)
+                for shape in ((64, 80, 3), (17, 19, 3), (48, 48))] + [raw]:
         ok, b = cv2.imencode(".jpg", img, [cv2.IMWRITE_JPEG_QUALITY, 88])
         ok, p = cv2.imencode(".jpg", img, [cv2.IMWRITE_JPEG_QUALITY, 88,
                                            cv2.IMWRITE_JPEG_PROGRESSIVE, 1])
@@ -477,13 +564,21 @@ def test_truncated_streams_never_crash():
         pytest.skip("native toolchain unavailable: %s" % native.native_error())
     rng = np.random.RandomState(42)
     img = rng.randint(0, 256, (32, 32, 3), dtype=np.uint8)
+    streams = []
     for opts in ([cv2.IMWRITE_JPEG_QUALITY, 90],
                  [cv2.IMWRITE_JPEG_QUALITY, 90, cv2.IMWRITE_JPEG_PROGRESSIVE, 1]):
         ok, enc = cv2.imencode(".jpg", img, opts)
-        data = enc.tobytes()
-        for cut in range(2, len(data), 23):
+        streams.append((enc.tobytes(), 23))
+    # a raw-cell original: cuts inside long runs of one-lookup codes
+    streams.append((_raw_cell_images((95,), (150, 164))[0], 331))
+    for data, step in streams:
+        for cut in range(2, len(data), step):
             try:
-                native.jpeg_decode_coeffs_native(data[:cut])
+                native.jpeg_decode_image_native(data[:cut])
+            except (ValueError, RuntimeError):
+                pass
+            try:
+                native.jpeg_decode_coeffs_batch_native([data, data[:cut]])
             except (ValueError, RuntimeError):
                 pass
 
@@ -516,6 +611,13 @@ def test_kmax_bound_and_truncated_decode_bit_equal():
         nz = np.where((arr != 0).any(axis=(0, 1))[ZIGZAG])[0]
         true_kmax = int(nz[-1]) if len(nz) else 0
         assert batch[0].kmax[c] >= true_kmax
+        # folded into the decode, it is the highest nonzero position itself, and
+        # the profile is the separate pass's
+        assert batch[0].kmax[c] == true_kmax
+        np.testing.assert_array_equal(batch[0].specmax[c],
+                                      native.jpeg_specmax_native(arr))
+    for blob in blobs:
+        _assert_profile_is_the_separate_passes(entropy_decode_jpeg_fast(blob))
 
     ks = _truncation_ks(batch)
     assert ks is not None  # smooth data must actually exercise the packed path
@@ -544,6 +646,36 @@ def test_kmax_survives_detach_and_pickle():
     assert p.kmax is not None
     assert p.detach().kmax == p.kmax
     assert pickle.loads(pickle.dumps(p)).kmax == p.kmax
+
+
+def test_per_image_stage1_arrays_own_their_memory():
+    """The per-image stage 1 decodes into arrays of its own: no native buffer is
+    freed under them when the call returns, and they survive ``detach()``, pickling
+    and the next decodes of the thread unchanged."""
+    from petastorm_tpu.ops import native
+
+    if not native.native_available():
+        pytest.skip("native toolchain unavailable: %s" % native.native_error())
+    import gc
+    import pickle
+
+    from petastorm_tpu.ops.jpeg import entropy_decode_jpeg_fast
+
+    blobs = _raw_cell_images((90, 95), (150, 164))
+    p = entropy_decode_jpeg_fast(blobs[0])
+    want = [(c.blocks.copy(), c.qtable.copy()) for c in p.components]
+    for c in p.components:
+        assert c.blocks.flags.owndata and c.blocks.dtype == np.int16
+        assert isinstance(c.qtable.base, np.ndarray) and c.qtable.base.flags.owndata
+    for blob in blobs * 3:  # the thread's next decodes reuse nothing of p's
+        entropy_decode_jpeg_fast(blob)
+    gc.collect()
+    for got in (p, p.detach(), pickle.loads(pickle.dumps(p))):
+        assert got.kmax == p.kmax
+        np.testing.assert_array_equal(got.specmax, p.specmax)
+        for c, (blocks, qtable) in zip(got.components, want):
+            np.testing.assert_array_equal(c.blocks, blocks)
+            np.testing.assert_array_equal(c.qtable, qtable)
 
 
 def test_truncated_decode_with_progressive_streams():

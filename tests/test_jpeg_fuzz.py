@@ -112,7 +112,7 @@ def test_fuzz_native_decoder_never_crashes():
                 pass
             try:
                 layout, coeffs, qtabs, kmax, status = \
-                    native.jpeg_decode_coeffs_batch_native([mut])
+                    native.jpeg_decode_coeffs_batch_native([mut])[:5]
                 if int(status[0]) != 0:
                     rejected += 1
                 for c in coeffs:
@@ -143,7 +143,7 @@ def test_fuzz_length_field_edge_cases():
                 except (ValueError, RuntimeError):
                     pass
                 try:
-                    _, _, _, _, status = native.jpeg_decode_coeffs_batch_native([mut])
+                    status = native.jpeg_decode_coeffs_batch_native([mut]).status
                 except (ValueError, RuntimeError):
                     pass
 
@@ -156,13 +156,13 @@ def test_fuzz_sandwich_no_cross_slice_writes():
     rng = np.random.RandomState(7)
     for stream in (seeds[0], seeds[1]):  # baseline and progressive layouts
         ref_layout, ref_coeffs, ref_qtabs, _, ref_status = \
-            native.jpeg_decode_coeffs_batch_native([stream, stream])
+            native.jpeg_decode_coeffs_batch_native([stream, stream])[:5]
         assert (np.asarray(ref_status) == 0).all()
         checked = 0
         for mut in _mutants(stream, rng, 60):
             try:
                 layout, coeffs, qtabs, kmax, status = \
-                    native.jpeg_decode_coeffs_batch_native([stream, mut, stream])
+                    native.jpeg_decode_coeffs_batch_native([stream, mut, stream])[:5]
             except (ValueError, RuntimeError):
                 continue  # whole-batch rejection is legal when the mutant poisons
             checked += 1

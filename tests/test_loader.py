@@ -291,7 +291,9 @@ def test_stats_populate_through_device_path(scalar_dataset):
                          "h2d_unwaited_outputs", "h2d_slab_puts",
                          "pack_s", "pack_slots", "pack_pad_slots", "pack_chunks",
                          "decode_batches", "decode_launches", "decode_real_blocks",
-                         "decode_canvas_blocks", "decode_host_rows", "crop_boxes"}
+                         "decode_canvas_blocks", "decode_host_rows", "crop_boxes",
+                         "stage1_images", "stage1_ns", "stage1_symbols",
+                         "stage1_fast_symbols"}
     assert snap["pack_s"] == 0 and snap["pack_chunks"] == 0  # no pack=: nothing packed
     # no device decode: nothing launched, nothing cropped
     assert snap["decode_batches"] == snap["decode_launches"] == snap["crop_boxes"] == 0
